@@ -96,7 +96,13 @@ def spawn(
                          "collector's /fleet document)")
     os.makedirs(db_root, exist_ok=True)
     procs = []
-    env = dict(os.environ, **(extra_env or {}))
+    # A chip belongs to one process.  The sidecar gets the caller's
+    # environment (whatever JAX_PLATFORMS it names, or none: the
+    # accelerator); every other child is pinned to the CPU backend so
+    # that a daemon's calibration probe or profiler endpoint can never
+    # race the sidecar for the device.
+    sidecar_env = dict(os.environ, **(extra_env or {}))
+    env = dict(sidecar_env, JAX_PLATFORMS="cpu")
     if verify_sidecar == "auto" or verify_sidecar.startswith("auto:"):
         # "auto" → a mode-0600 Unix socket under db_root (a TCP port
         # could be squatted by another local user after a sidecar
@@ -114,7 +120,7 @@ def spawn(
                     sys.executable, "-m", "bftkv_tpu.cmd.verify_sidecar",
                     "--listen", verify_sidecar,
                 ],
-                env=env,
+                env=sidecar_env,
             )
         )
     sidecar_stats = ""
@@ -136,7 +142,7 @@ def spawn(
                 f"{api_base + len(homes) + len(gw_homes or [])}"
             )
             cmd += ["--stats", sidecar_stats]
-        procs.append(subprocess.Popen(cmd, env=env))
+        procs.append(subprocess.Popen(cmd, env=sidecar_env))
     for i, home in enumerate(homes):
         name = os.path.basename(home)
         cmd = [

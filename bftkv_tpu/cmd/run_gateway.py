@@ -181,11 +181,6 @@ def main(argv: list[str] | None = None) -> int:
                     help="shared-secret file for HMAC sidecar frames")
     args = ap.parse_args(argv)
 
-    if os.environ.get("JAX_PLATFORMS", "").strip() == "cpu":
-        from bftkv_tpu.hostcpu import force_cpu
-
-        force_cpu(1)
-
     if args.sidecar:
         from bftkv_tpu.ops import dispatch
 

@@ -86,6 +86,7 @@ import hashlib
 import hmac
 import io
 import json
+import logging
 import os
 import socket
 import socketserver
@@ -99,6 +100,17 @@ from bftkv_tpu.admission import AdmissionQueue
 from bftkv_tpu.metrics import registry as metrics
 from bftkv_tpu.packet import read_chunk, write_chunk
 from bftkv_tpu import flags
+
+_log = logging.getLogger("bftkv_tpu.sidecar")
+
+#: JAX's own persistent-cache events, as the sidecar counts them: a
+#: program loaded from the cache, and a program that had to be compiled
+#: (a "miss" is a compile long enough to be worth an entry —
+#: jax_persistent_cache_min_compile_time_secs).
+_CACHE_EVENT_NAMES = {
+    "/jax/compilation_cache/cache_hits": "loaded",
+    "/jax/compilation_cache/cache_misses": "compiled",
+}
 
 __all__ = [
     "serve",
@@ -391,6 +403,20 @@ class SidecarService:
         )
         self.max_keys = flags.get_int("BFTKV_SIDECAR_MAX_KEYS")
         self._t0 = time.monotonic()
+        import jax
+
+        devs = jax.devices()
+        self._device = {
+            "platform": devs[0].platform,
+            "kind": devs[0].device_kind,
+            "count": len(devs),
+        }
+        # On a device backend, starting MEANS compiling: every program
+        # a flush can launch is built (or loaded from the compile
+        # cache) here, before the caller binds a socket.  Runs before
+        # the recalibration loop exists, so its compile-laden round
+        # trips can never price the crossover.
+        self.warmup = self._warm()
         # Online recalibration (ISSUE 19): the boot verdict above used
         # to be forever — nothing ever called calibration(force=True)
         # again, so an accelerator attached (or un-wedged) mid-run
@@ -407,6 +433,124 @@ class SidecarService:
                 target=self._recal_loop, args=(period,), daemon=True
             )
             self._recal_thread.start()
+
+    def _warm(self) -> dict:
+        """Launch one full batch of every bucket shape the dispatchers
+        can emit, through the dispatchers themselves — collector, flush
+        worker, staging ring, donated launch, async completion — and
+        check each result against the host.
+
+        The first launch of a shape compiles it (12–23 s each on a v5e
+        host; a 30 s tenant channel timeout would turn that into silent
+        host fallback), so a sidecar that is listening is a sidecar whose
+        programs are built.  Shapes: verify buckets are the powers of
+        two from 256 to ``max_batch``; a sign flush of n signatures is
+        2n CRT-half rows, buckets 64 … 2·``max_batch``, and its fault
+        check rides the verify buckets; the modexp dispatcher's
+        1024-bit launches share the sign programs (other modulus
+        widths, and flushes mixing more than 64 distinct moduli, still
+        compile on first use).  A wrong result or a device error
+        raises: a sidecar that cannot launch does not start.
+
+        Nothing to do on a CPU backend — calibration pins host there
+        and no flush ever launches."""
+        self._cache_events = {"loaded": 0, "compiled": 0}
+        if self._cal["prefer_host"]:
+            return {"shapes": [], "seconds": 0.0}
+        from bftkv_tpu import ops
+        from bftkv_tpu.crypto import rsa as rsamod
+        from bftkv_tpu.ops import dispatch
+
+        import jax
+
+        # Listening for the life of the service (stop() unregisters):
+        # the counts restart after the warm-up, so a later compile is a
+        # compile inside some tenant's request.
+        jax.monitoring.register_event_listener(self._on_jax_event)
+
+        def buckets(lo: int, hi: int) -> list[int]:
+            b, out = lo, []
+            while b < hi:
+                out.append(b)
+                b *= 2
+            return out + [hi]
+
+        t_start = time.monotonic()
+        key = rsamod.generate(2048)
+        msg = b"bftkv-sidecar-warmup"
+        sig = rsamod.sign(msg, key)
+        forged = sig[:-1] + bytes([sig[-1] ^ 1])
+        shapes: list[dict] = []
+
+        def timed(role: str, n: int, fn) -> None:
+            t0 = time.monotonic()
+            fn(n)
+            dt = round(time.monotonic() - t0, 3)
+            shapes.append({"role": role, "items": n, "seconds": dt})
+            _log.info("warm-up: %s x%d in %.1f s", role, n, dt)
+
+        def warm_verify(n: int) -> None:
+            ok = self.verify.submit(
+                [(msg, sig, key.public)] * (n - 1)
+                + [(msg, forged, key.public)]
+            )
+            if not (all(ok[:-1]) and not ok[-1]):
+                raise RuntimeError(
+                    f"sidecar warm-up: verify batch of {n} returned "
+                    "wrong verdicts"
+                )
+
+        def warm_sign(n: int) -> None:
+            if self.sign.submit([(msg, key)] * n) != [sig] * n:
+                raise RuntimeError(
+                    f"sidecar warm-up: sign batch of {n} returned a "
+                    "wrong signature"
+                )
+
+        def warm_modexp(n: int) -> None:
+            items = [(i + 2, key.d % (key.p - 1), key.p) for i in range(n)]
+            if self.modexp.submit(items) != [pow(*it) for it in items]:
+                raise RuntimeError(
+                    f"sidecar warm-up: modexp batch of {n} returned a "
+                    "wrong residue"
+                )
+
+        v_lo = max(256, self.verify.verifier.host_threshold)
+        for n in buckets(min(v_lo, self.verify.max_batch),
+                         self.verify.max_batch):
+            timed("verify", n, warm_verify)
+        s_lo = max(32, self.sign.signer.host_threshold)
+        for n in buckets(min(s_lo, self.sign.max_batch),
+                         self.sign.max_batch):
+            timed("sign", n, warm_sign)
+        m = min(max(64, self.modexp.device_threshold),
+                self.modexp.max_batch)
+        timed("modexp", m, warm_modexp)
+        # Warm-up is not traffic.  Its round trips included compilation
+        # (or a cache load) and say nothing about what a launch costs;
+        # its items are not any tenant's.  The observed-RTT series and
+        # the registry restart from zero — no listener exists yet, so
+        # nothing of a tenant's can be lost — and ``shapes`` above is
+        # the record of what ran.
+        dispatch.forget_launch_rtt()
+        metrics.reset()
+        counts = self._cache_events
+        self._cache_events = dict.fromkeys(counts, 0)
+        return {
+            "shapes": shapes,
+            "seconds": round(time.monotonic() - t_start, 3),
+            "compile_cache": {
+                "dir": ops.compile_cache_dir(),
+                **counts,
+                # Warm = this start loaded its programs, compiled none.
+                "warm": counts["compiled"] == 0 and counts["loaded"] > 0,
+            },
+        }
+
+    def _on_jax_event(self, event: str, **_kw) -> None:
+        name = _CACHE_EVENT_NAMES.get(event)
+        if name is not None:
+            self._cache_events[name] += 1
 
     def apply_calibration(self, cal: dict) -> None:
         """(Re-)point the dispatchers' host/device thresholds at a
@@ -472,6 +616,10 @@ class SidecarService:
         self.verify.stop()
         self.sign.stop()
         self.modexp.stop()
+        if self.warmup["shapes"]:
+            import jax
+
+            jax.monitoring.unregister_event_listener(self._on_jax_event)
 
     def stats(self) -> dict:
         """The ``/metrics``-style stats frame (OP_STATS and the stats
@@ -496,7 +644,21 @@ class SidecarService:
             }
 
         from bftkv_tpu.ops import devbuf, dispatch
+        from bftkv_tpu.ops import rns
 
+        def launched(name: str) -> dict:
+            # What actually rode a device launch (a flush below the
+            # crossover runs on host inside the dispatcher).
+            return {
+                "items": snap.get(f"{name}.device", 0),
+                "launches": snap.get(f"{name}.device_batch.count", 0),
+                "max_items_per_launch": int(
+                    metrics.percentile(f"{name}.device_batch", 1.0) or 0
+                ),
+                "host_items": snap.get(f"{name}.host", 0),
+            }
+
+        pallas = rns.pallas_status()
         rtt = dispatch.observed_launch_rtt()
         return {
             "uptime_s": round(time.monotonic() - self._t0, 1),
@@ -516,15 +678,36 @@ class SidecarService:
                 "modexp": disp("modexpdispatch"),
             },
             "device_plane": {
+                "device": self._device,
                 "calibration": {
                     k: self._cal.get(k)
                     for k in (
                         "backend",
+                        "host_verify_s",
+                        "device_rtt_s",
                         "verify_crossover",
                         "prefer_host",
                         "source",
                     )
                 },
+                "launched": {
+                    "verify": launched("verify"),
+                    "sign": launched("sign"),
+                },
+                # Which RNS chain served each role: the fused Pallas
+                # chain once one completed, else the XLA chain.  A
+                # retreat from one to the other is never quiet.
+                "kernels": {
+                    "verify": "pallas" if pallas["verify"] == "ok" else "xla",
+                    "sign": "pallas" if pallas["pow"] == "ok" else "xla",
+                    "pallas_status": pallas,
+                    "pallas_fallbacks": snap.get("rns.pallas_fallback", 0),
+                    "sign_rns_fallbacks": snap.get("sign.rns_fallback", 0),
+                },
+                "warmup": self.warmup,
+                # Programs compiled AFTER the warm-up, i.e. inside some
+                # tenant's request: a shape the warm-up did not cover.
+                "compiled_since_warmup": self._cache_events["compiled"],
                 "launch_rtt_s": None if rtt is None else round(rtt, 6),
                 "recalibrations": snap.get("sidecar.recalibrations", 0),
                 "buffer_rings": devbuf.stats(),
@@ -822,6 +1005,14 @@ def serve(
     service).  ``stats`` optionally serves /info + /metrics + /trace
     on an HTTP port for the fleet collector (``role=sidecar``).
     """
+    # The service comes first: on a device backend constructing it
+    # compiles every launchable program (SidecarService._warm), and no
+    # socket exists until that is done — a tenant that dials early is
+    # refused at once and runs its own host crypto, instead of hanging
+    # on a listener that cannot answer yet.
+    service = SidecarService(
+        max_batch=max_batch, max_wait=max_wait, admission=admission
+    )
     if listen.startswith("unix:"):
         path = listen[len("unix:"):]
         try:
@@ -840,9 +1031,7 @@ def serve(
     else:
         host, _, port = listen.rpartition(":")
         srv = _Server((host or "127.0.0.1", int(port)), _Handler)
-    srv.service = SidecarService(
-        max_batch=max_batch, max_wait=max_wait, admission=admission
-    )
+    srv.service = service
     #: Back-compat alias: v1 handling and existing embedders address
     #: the verify dispatcher as ``srv.dispatcher``.
     srv.dispatcher = srv.service.verify
@@ -891,6 +1080,12 @@ def main(argv: list[str] | None = None) -> int:
                     help="member name reported on the stats /info")
     args = ap.parse_args(argv)
     secret = load_secret(args.secret_file) if args.secret_file else None
+    # Warm-up progress (one line per compiled shape) goes to stdout
+    # beside the "listening" line.
+    h = logging.StreamHandler(sys.stdout)
+    h.setFormatter(logging.Formatter("crypto-sidecar: %(message)s"))
+    _log.addHandler(h)
+    _log.setLevel(logging.INFO)
     srv, t = serve(
         args.listen,
         max_batch=args.max_batch,

@@ -268,17 +268,15 @@ _flag("BFTKV_DISPATCH_DEVBUF_RING", "4", "int",
 _flag("BFTKV_TPU_MIN_MODEXP_BATCH", "4", "int",
       "Smallest batch worth a device modexp launch.")
 _flag("BFTKV_RNS_POW_BACKEND", "auto", "str",
-      "`pallas` forces the Pallas RNS pow kernel, `xla` the lowered "
-      "one; `auto` proves Pallas on TPU first.")
+      "`pallas` forces the fused Pallas RNS pow chain, `xla` the "
+      "lowered one; `auto` picks by platform and device count "
+      "(today: always `xla`).")
 _flag("BFTKV_RNS_VERIFY_BACKEND", "auto", "str",
       "Same switch for the RNS verify kernel.")
 _flag("BFTKV_PALLAS_TILE_POW", "256", "int",
       "Pallas pow kernel batch tile (power of two ≥ 8).")
 _flag("BFTKV_PALLAS_TILE_VERIFY", "128", "int",
       "Pallas verify kernel batch tile (power of two ≥ 8).")
-_flag("BFTKV_COMPILE_CACHE", None, "str",
-      "XLA compile-cache directory (unset: ~/.cache/jax_bftkv; empty "
-      "value disables).")
 
 _begin("Storage")
 _flag("BFTKV_PLAIN_FSYNC", None, "switch",

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import http.client
 import socket
+import sys
 import threading
 import urllib.parse
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -50,6 +51,18 @@ def _is_timeout(e: Exception) -> bool:
         return True
     reason = getattr(e, "reason", None)
     return isinstance(reason, (TimeoutError, socket.timeout))
+
+
+class _QuietHangupServer(ThreadingHTTPServer):
+    """A peer that drops a pooled keep-alive connection (a client
+    process exiting resets every socket it held) is not an error:
+    keep the stock traceback for everything else, so that a traceback
+    in a daemon's log always means something."""
+
+    def handle_error(self, request, client_address):
+        if isinstance(sys.exc_info()[1], ConnectionError):
+            return
+        super().handle_error(request, client_address)
 
 
 class _Handler(BaseHTTPRequestHandler):
@@ -280,7 +293,7 @@ class TrHTTP:
         certificate address)."""
         host, _, port = addr.rpartition(":")
         self.link_id = addr  # this node's side of every link
-        self._server = ThreadingHTTPServer(
+        self._server = _QuietHangupServer(
             (host or "127.0.0.1", int(port)), _Handler
         )
         self._server.owner_handler = self._dispatch(o)

@@ -8,6 +8,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 
 import itertools
+import os
 
 from bftkv_tpu import topology
 from bftkv_tpu.protocol.client import Client
@@ -16,8 +17,15 @@ from bftkv_tpu.storage.memkv import MemStorage
 from bftkv_tpu.transport.http import TrHTTP
 from bftkv_tpu.transport.loopback import LoopbackNet, TrLoopback
 
-# Each HTTP cluster gets a disjoint port range so tests never collide.
-_port_block = itertools.count(16001, 100)
+# Each HTTP cluster gets a disjoint port range so tests never collide —
+# within a process by counting blocks of 100, across xdist workers
+# (every worker process counts from its own start) by a per-worker
+# range of ten blocks: gw0 10001…, gw1 11001…, up to 16999.  The test
+# files with ports of their own (test_cmd, test_visual, test_sidecar*,
+# test_byzantine_fullstack, test_device_plane) sit in 17001…19999 and
+# chip_smoke.py asks the kernel above 22100.
+_worker = int(os.environ.get("PYTEST_XDIST_WORKER", "gw0").lstrip("gw") or 0)
+_port_block = itertools.count(10001 + 1000 * (_worker % 7), 100)
 
 
 @dataclass

@@ -1,0 +1,185 @@
+"""Ask the chip's compiler before the chip.
+
+Every program ``chip_smoke.py`` launches, at the shape it launches it,
+handed to the TPU compiler for a *described* v5e — no chip attached
+(on-chip-measurement guide §2, rehearsal 3).  What Mosaic or XLA:TPU
+refuses here (a misaligned slice, too much VMEM, a program that cannot
+be partitioned) would otherwise cost a chip run to find.  Nothing
+executes: a compile that passes is not a chip run.
+
+One batch size per kernel, not a sweep: the XLA chains take ~10 s
+each, the fused Pallas chains 20-30 s.
+
+The topology is described inside a module-scoped fixture — never at
+import — so that every xdist worker collects the same tests and only
+the worker that runs this file loads libtpu.
+"""
+
+import os
+import warnings
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from bftkv_tpu.ops import pallas_rns, rns
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_compile_cache():
+    # A TPU executable compiled without a chip is written to the
+    # persistent cache but cannot be read back: keep these out of it.
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _key_shapes(ctx, place, kpad: int = 64):
+    """Shapes of ``rns.stack_key_rows`` for ``kpad`` unique moduli."""
+    k = ctx.k
+    return tuple(
+        place((kpad, w)) for w in (2 * k, 1, k, 2 * k, 2 * k, 1)
+    )
+
+
+def _operands(kind: str, rows: int, place, place_t, place_key):
+    """ShapeDtypeStructs for one flush of ``rows`` rows: what
+    ``rsa._verify_rns`` / ``rns.power_mod_rns`` hand the jitted chain."""
+    if kind == "verify":
+        return (
+            place((rows, 2 * rns.DIGITS), jnp.uint8),
+            place((rows, 2 * rns.DIGITS), jnp.uint8),
+            place((rows,), jnp.int32),
+            _key_shapes(rns.context(), place_key),
+        )
+    # RSA-2048 CRT halves: 64 digits, 1024-bit exponents → 256 nibbles.
+    return (
+        place((rows, 128), jnp.uint8),
+        place_t((256, rows), jnp.uint8),
+        place((rows,), jnp.int32),
+        _key_shapes(rns.context(64, 1024), place_key),
+    )
+
+
+def _on(sharding):
+    return lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=sharding
+    )
+
+
+def _compiled_ok(compiled, *, kernel: bool = False) -> None:
+    ma = compiled.memory_analysis()
+    # One v5e chip holds 16 GB; these programs are nowhere near it.
+    assert ma.temp_size_in_bytes + ma.argument_size_in_bytes < 2 << 30
+    if kernel:
+        assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize(
+    "kind,rows",
+    [
+        ("verify", 256),   # the floor bucket: any flush of 16..256 items
+        ("verify", 4096),  # the sidecar's max_batch, warm-up's largest
+        ("pow", 512),      # 256 share signs = 512 CRT-half rows
+        ("pow", 2048),     # four servers' 256-sign batches coalesced
+    ],
+)
+def test_xla_chain_compiles_for_one_chip(topo, kind, rows):
+    one = _on(SingleDeviceSharding(topo.devices[0]))
+    fn = (
+        rns._jitted_verify_gather() if kind == "verify"
+        else rns._jitted_pow(64, 1024, True)  # donated, as on a device
+    )
+    with warnings.catch_warnings():
+        # ``_jitted_pow`` donates uint8 operands that no f32 output
+        # can alias; JAX says so once per lowering.
+        warnings.filterwarnings("ignore", message="Some donated buffers")
+        compiled = fn.lower(*_operands(kind, rows, one, one, one)).compile()
+    _compiled_ok(compiled)
+
+
+def test_pallas_verify_chain_compiles_at_its_tile(topo):
+    one = _on(SingleDeviceSharding(topo.devices[0]))
+    tile = pallas_rns.TILE_VERIFY
+    pc = pallas_rns._pad_consts(rns.DIGITS, 2048)
+    rows = 1024
+    row = lambda w: one((rows, w))
+    run = pallas_rns._verify_call(rns.DIGITS, 2048, tile, False)
+    compiled = run.lower(
+        row(2 * rns.DIGITS), row(2 * rns.DIGITS),
+        row(pc.kpad), row(pc.kpad), row(1), row(pc.kpad),
+        row(pc.kpad), row(pc.kpad),
+        row(pc.kpad), row(pc.kpad), row(1),
+    ).compile()
+    _compiled_ok(compiled, kernel=True)
+
+
+def test_pallas_pow_chain_compiles_at_its_tile(topo):
+    one = _on(SingleDeviceSharding(topo.devices[0]))
+    tile = pallas_rns.TILE_POW
+    pc = pallas_rns._pad_consts(64, 1024)  # the RSA-2048 CRT context
+    rows = 512
+    row = lambda w: one((rows, w))
+    run = pallas_rns._pow_call(64, 1024, tile, False)
+    compiled = run.lower(
+        row(128), one((256, rows)),
+        row(pc.kpad), row(pc.kpad), row(1), row(pc.kpad),
+        row(pc.kpad), row(pc.kpad), row(1),
+    ).compile()
+    _compiled_ok(compiled, kernel=True)
+
+
+@pytest.mark.parametrize(
+    "kind,rows", [("verify", 4096), ("pow", 2048)]
+)
+def test_sharded_chain_compiles_for_four_chips(topo, monkeypatch, kind, rows):
+    """``chip_smoke.py --chips 4``: the flush a multi-device sidecar
+    launches, on a ``batch`` mesh over the four described chips.  The
+    production builders read the mesh from ``jax.devices()`` (CPU
+    here), so the test hands them the described one."""
+    mesh = Mesh(np.array(topo.devices), ("batch",))
+    assert mesh.devices.size == 4
+    builder = (
+        rns._jitted_verify_gather_sharded if kind == "verify"
+        else rns._jitted_pow_sharded
+    )
+    monkeypatch.setattr(rns, "_mesh", lambda: mesh)
+    builder.cache_clear()
+    try:
+        fn = builder() if kind == "verify" else builder(64, 1024)
+        compiled = fn.lower(*_operands(
+            kind, rows,
+            _on(NamedSharding(mesh, P("batch"))),
+            _on(NamedSharding(mesh, P(None, "batch"))),
+            _on(NamedSharding(mesh, P())),
+        )).compile()
+    finally:
+        builder.cache_clear()  # never leave a TPU-mesh program cached
+    _compiled_ok(compiled)
+    # Data-parallel over the batch: each chip gets a quarter, and
+    # nothing crosses chips.
+    (out,) = jax.tree_util.tree_leaves(compiled.output_shardings)
+    assert out.shard_shape((rows, 1))[0] == rows // 4
+    text = compiled.as_text()
+    assert "all-reduce" not in text and "all-gather" not in text

@@ -261,3 +261,76 @@ def test_daemon_api_missing_variable(cluster):
             f"http://127.0.0.1:{API_BASE}/read/smoke/none", timeout=60
         )
     assert ei.value.code == 404
+
+
+def test_only_the_sidecar_may_see_the_chip(monkeypatch, tmp_path):
+    """A chip belongs to one process: ``spawn`` hands the sidecar the
+    caller's environment and pins every other child — daemons,
+    gateways, collector, autopilot — to the CPU backend, so that no
+    calibration probe or profiler endpoint races the sidecar for it."""
+    from bftkv_tpu.cmd import run_cluster
+
+    started = []
+
+    class FakePopen:
+        def __init__(self, cmd, env=None, **kw):
+            self.args = cmd
+            started.append((cmd[2], env))
+
+    monkeypatch.setattr(run_cluster.subprocess, "Popen", FakePopen)
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    homes = [str(tmp_path / n) for n in ("a01", "a02", "rw01")]
+    run_cluster.spawn(
+        homes, str(tmp_path / "dbs"), api_base=17900, sidecar="auto",
+        fleet=17990, autopilot=True, gw_homes=[str(tmp_path / "gw01")],
+        extra_env={"BFTKV_TEST_MARK": "1"},
+    )
+    by_module: dict = {}
+    for module, env in started:
+        by_module.setdefault(module, []).append(env)
+    assert sorted(by_module) == [
+        "bftkv_tpu.autopilot", "bftkv_tpu.cmd.bftkv", "bftkv_tpu.cmd.fleet",
+        "bftkv_tpu.cmd.run_gateway", "bftkv_tpu.cmd.verify_sidecar",
+    ]
+    (sidecar_env,) = by_module.pop("bftkv_tpu.cmd.verify_sidecar")
+    assert "JAX_PLATFORMS" not in sidecar_env  # the caller's: the chip
+    assert sidecar_env["BFTKV_TEST_MARK"] == "1"
+    assert len(by_module["bftkv_tpu.cmd.bftkv"]) == 3
+    for envs in by_module.values():
+        for env in envs:
+            assert env["JAX_PLATFORMS"] == "cpu"
+            assert env["BFTKV_TEST_MARK"] == "1"
+    # The legacy verify-only sidecar owns the chip the same way.
+    started.clear()
+    run_cluster.spawn(homes, str(tmp_path / "dbs"), verify_sidecar="auto")
+    assert "JAX_PLATFORMS" not in dict(started)[
+        "bftkv_tpu.cmd.verify_sidecar"
+    ]
+    assert dict(started)["bftkv_tpu.cmd.bftkv"]["JAX_PLATFORMS"] == "cpu"
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_has_one_place(monkeypatch, tmp_path, from_env):
+    """``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself and the
+    program touches nothing.  Unset: one fixed directory inside the
+    checkout — never under ``~``, ``/tmp``, a pid or a time."""
+    import jax
+
+    from bftkv_tpu import ops
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        if from_env:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            ops.enable_compile_cache()
+            assert jax.config.jax_compilation_cache_dir is None
+            assert ops.compile_cache_dir() == str(tmp_path)
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            ops.enable_compile_cache()
+            fixed = os.path.join(REPO, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == fixed
+            assert ops.compile_cache_dir() == ops.COMPILE_CACHE_DIR == fixed
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

@@ -121,59 +121,68 @@ def test_rns_padding_rows_never_verify(keys):
     assert ok.shape == (1,) and ok[0]
 
 
-def test_pallas_auto_gated_on_per_chain_proof(monkeypatch, tmp_path):
-    """Auto mode routes through a fused Pallas chain only on a single
-    real TPU chip AND after that chain has a proven-completion marker;
-    a verify-only proof must not arm the pow chain (r5 code review)."""
-    monkeypatch.setattr(rns.jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(rns.jax, "devices", lambda: ["chip0"])
-    monkeypatch.setattr(
-        rns, "_pallas_proven_path",
-        lambda which: str(tmp_path / f"marker_{which}"),
-    )
-    rns._pallas_proven.cache_clear()
-    try:
-        # No marker: auto never selects pallas, even on "tpu".
-        assert rns._use_pallas("BFTKV_RNS_POW_BACKEND") is False
-        assert rns._use_pallas("BFTKV_RNS_VERIFY_BACKEND") is False
-        # A verify proof arms verify only.
-        (tmp_path / "marker_verify").touch()
-        rns._pallas_proven.cache_clear()
-        assert rns._use_pallas("BFTKV_RNS_VERIFY_BACKEND") is True
-        assert rns._use_pallas("BFTKV_RNS_POW_BACKEND") is False
-        # Forced modes ignore the marker in both directions.
-        monkeypatch.setenv("BFTKV_RNS_VERIFY_BACKEND", "xla")
-        assert rns._use_pallas("BFTKV_RNS_VERIFY_BACKEND") is False
-        monkeypatch.setenv("BFTKV_RNS_POW_BACKEND", "pallas")
-        assert rns._use_pallas("BFTKV_RNS_POW_BACKEND") is True
-        # Multi-chip pools stay on the sharded XLA path in auto.
-        monkeypatch.delenv("BFTKV_RNS_VERIFY_BACKEND")
-        monkeypatch.setattr(rns.jax, "devices", lambda: ["c0", "c1"])
-        (tmp_path / "marker_pow").touch()
-        rns._pallas_proven.cache_clear()
-        assert rns._use_pallas("BFTKV_RNS_VERIFY_BACKEND") is False
-    finally:
-        rns._pallas_proven.cache_clear()
+@pytest.mark.parametrize(
+    "platform,n_devices",
+    [("tpu", 1), ("tpu", 4), ("cpu", 1)],
+)
+def test_auto_backend_goes_by_platform_and_device_count(
+    monkeypatch, platform, n_devices
+):
+    """``auto`` resolves from what the process observes — platform and
+    device count — and from nothing outside the checkout.  Until a
+    chip measurement judges the fused chains (ROADMAP S4) every case
+    resolves to the XLA chains, what a fresh machine always ran: off
+    TPU the fused chains would be interpreted, on several chips the
+    sharded XLA path spreads the batch."""
+    seen = []
+    real = rns._auto_backend
+
+    def spy(p, n):
+        seen.append((p, n))
+        return real(p, n)
+
+    monkeypatch.setattr(rns.jax, "default_backend", lambda: platform)
+    monkeypatch.setattr(rns.jax, "devices", lambda: ["chip"] * n_devices)
+    monkeypatch.setattr(rns, "_auto_backend", spy)
+    monkeypatch.delenv("BFTKV_RNS_POW_BACKEND", raising=False)
+    monkeypatch.delenv("BFTKV_RNS_VERIFY_BACKEND", raising=False)
+    assert rns._use_pallas("BFTKV_RNS_POW_BACKEND") is False
+    assert rns._use_pallas("BFTKV_RNS_VERIFY_BACKEND") is False
+    assert seen == [(platform, n_devices)] * 2
 
 
-def test_pallas_mark_proven_no_marker_off_tpu(monkeypatch, tmp_path):
-    """Status flips to ok everywhere, but the cross-process marker is
-    only written where it was actually proven: on a real TPU backend."""
+@pytest.mark.parametrize(
+    "mode,want", [("pallas", True), ("xla", False)]
+)
+def test_forced_backend_never_consults_auto(monkeypatch, mode, want):
     monkeypatch.setattr(
-        rns, "_pallas_proven_path",
-        lambda which: str(tmp_path / f"marker_{which}"),
+        rns, "_auto_backend",
+        lambda p, n: pytest.fail("auto consulted for a forced backend"),
     )
-    monkeypatch.setattr(rns, "_PALLAS_STATUS", {"pow": "unused", "verify": "unused"})
-    rns._pallas_mark_proven("pow")  # backend is cpu under the test env
-    assert rns.pallas_status()["pow"] == "ok"
-    assert not (tmp_path / "marker_pow").exists()
-    try:
-        monkeypatch.setattr(rns.jax, "default_backend", lambda: "tpu")
-        rns._pallas_mark_proven("verify")
-        assert (tmp_path / "marker_verify").exists()
-        # Early return: a second call must not touch the path again.
-        (tmp_path / "marker_verify").unlink()
-        rns._pallas_mark_proven("verify")
-        assert not (tmp_path / "marker_verify").exists()
-    finally:
-        rns._pallas_proven.cache_clear()
+    for env in ("BFTKV_RNS_POW_BACKEND", "BFTKV_RNS_VERIFY_BACKEND"):
+        monkeypatch.setenv(env, mode)
+        assert rns._use_pallas(env) is want
+
+
+def test_pallas_retreat_is_counted_and_named(monkeypatch):
+    """A fused chain that raises falls back to the XLA chain with the
+    right answer — and a status string and a counter that the smoke
+    reads as fatal."""
+    from bftkv_tpu.metrics import registry as metrics
+    from bftkv_tpu.ops import pallas_rns
+
+    def boom(*a, **kw):
+        raise RuntimeError("mosaic says no")
+
+    monkeypatch.setattr(pallas_rns, "pow_pallas", boom)
+    monkeypatch.setattr(
+        rns, "_PALLAS_STATUS", {"pow": "unused", "verify": "unused"}
+    )
+    monkeypatch.setenv("BFTKV_RNS_POW_BACKEND", "pallas")
+    before = metrics.snapshot().get("rns.pallas_fallback", 0)
+    m = (1 << 511) + 111
+    assert rns.power_mod_rns([5], [65537], [m], n_bits=512) == [
+        pow(5, 65537, m)
+    ]
+    assert rns.pallas_status()["pow"] == "fallback: RuntimeError"
+    assert metrics.snapshot()["rns.pallas_fallback"] == before + 1
