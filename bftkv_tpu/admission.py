@@ -11,6 +11,11 @@ unbounded work onto a resource that is already the bottleneck.
 Grew out of the gateway's admission control (DESIGN.md §14.4); the
 sidecar reuses it verbatim with ``metric="sidecar.shed"`` so both
 tiers shed with identical semantics (DESIGN.md §17.4).
+
+The sidecar's instance also keeps the one process-wide waiting
+interval the device trace may show: ``sidecar.empty``, the time during
+which no request is admitted or waiting — the chip then waits for work
+that has not arrived (counter ``sidecar.empty.seconds``; DESIGN.md §7).
 """
 
 from __future__ import annotations
@@ -18,6 +23,7 @@ from __future__ import annotations
 import threading
 import time
 
+from bftkv_tpu import trace
 from bftkv_tpu.metrics import registry as metrics
 
 __all__ = ["AdmissionQueue"]
@@ -56,6 +62,12 @@ class AdmissionQueue:
         #: shared by every gateway/sidecar in one process, so /info
         #: must not report tier-wide totals as this instance's own.
         self.shed = 0
+        #: Since when nothing is admitted or waiting (None while
+        #: something is), and that interval's profiler annotation.
+        #: Kept for the tier ``sidecar`` only: a gateway's empty time
+        #: says nothing about a device.
+        self._empty_since: float | None = time.monotonic()
+        self._empty_ann = None
 
     def _publish(self) -> None:
         """Capacity-plane gauges (caller holds ``_cv``; the metrics
@@ -65,6 +77,27 @@ class AdmissionQueue:
         metrics.gauge("admission.waiting", float(self._waiting), labels=lab)
         metrics.gauge("admission.limit", float(self.max_inflight), labels=lab)
         metrics.gauge("admission.queue_limit", float(self.max_queue), labels=lab)
+        if self.tier == "sidecar":
+            self._note_empty()
+
+    def _note_empty(self) -> None:
+        """The 0 → 1 and 1 → 0 transitions of in-flight + waiting
+        (caller holds ``_cv``).  The interval may begin on one handler
+        thread and end on another, so it is a profiler annotation and a
+        counter, not a span."""
+        empty = self._inflight + self._waiting == 0
+        if empty == (self._empty_since is not None):
+            return
+        now = time.monotonic()
+        if empty:
+            self._empty_since = now
+            self._empty_ann = trace.annotate("sidecar.empty")
+            return
+        metrics.incr("sidecar.empty.seconds", now - self._empty_since)
+        self._empty_since = None
+        ann, self._empty_ann = self._empty_ann, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
 
     def acquire(self, op: str) -> bool:
         """True = admitted (caller MUST release); False = shed."""

@@ -187,40 +187,12 @@ class _ApiHandler(BaseHTTPRequestHandler):
                     self._reply(200, f.read(), "text/html")
             elif path.startswith("/debug/profile"):
                 # TPU/XLA trace capture (stands in for the reference's
-                # pprof endpoint, cmd/bftkv/main.go:20,253): collects a
-                # jax profiler trace viewable in TensorBoard/Perfetto.
-                # The output location is confined to a fixed root — the
-                # API may be exposed beyond localhost.
-                import re as _re
-                import tempfile as _tf
-                import time as _time
-                import urllib.parse as _up
+                # pprof endpoint, cmd/bftkv/main.go:20,253), confined
+                # to a fixed root; the sidecar's stats port serves the
+                # same helper.
+                from bftkv_tpu import ops
 
-                q = _up.parse_qs(_up.urlparse(path).query)
-                try:
-                    seconds = float(q.get("seconds", ["2"])[0])
-                except ValueError:
-                    seconds = 2.0
-                if not (seconds >= 0.0):  # also catches NaN
-                    seconds = 0.0
-                seconds = min(seconds, 30.0)
-                name = _re.sub(
-                    r"[^A-Za-z0-9_.-]", "_", q.get("name", ["trace"])[0]
-                )[:64]
-                # "", "." and ".." survive the character filter but
-                # escape (or collapse into) the confinement root.
-                if name in ("", ".", ".."):
-                    name = "trace"
-                outdir = os.path.join(
-                    _tf.gettempdir(), "bftkv-profile", name
-                )
-                import jax
-
-                jax.profiler.start_trace(outdir)
-                try:
-                    _time.sleep(seconds)
-                finally:
-                    jax.profiler.stop_trace()
+                outdir = ops.capture_profile(path)
                 self._reply(
                     200,
                     f"trace captured to {outdir}\n".encode(),

@@ -96,6 +96,7 @@ import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
+from bftkv_tpu import trace
 from bftkv_tpu.admission import AdmissionQueue
 from bftkv_tpu.metrics import registry as metrics
 from bftkv_tpu.packet import read_chunk, write_chunk
@@ -110,6 +111,18 @@ _log = logging.getLogger("bftkv_tpu.sidecar")
 _CACHE_EVENT_NAMES = {
     "/jax/compilation_cache/cache_hits": "loaded",
     "/jax/compilation_cache/cache_misses": "compiled",
+}
+
+#: JAX's duration events (``jax.monitoring``, names as in JAX 0.9's
+#: ``jax/_src/dispatch.py`` and ``compiler.py``) that split a warm-up
+#: shape's seconds.  ``backend`` covers the whole of "compile or load
+#: from the persistent cache"; ``load`` is the cache read inside it, so
+#: what was compiled is their difference.
+_DURATION_EVENT_NAMES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "load",
 }
 
 __all__ = [
@@ -340,6 +353,13 @@ def _chunks(payload: bytes, count: int) -> list:
 # -- the service ------------------------------------------------------------
 
 
+def _phase_sum(snap: dict, name: str) -> float:
+    """Seconds a phase histogram holds, over its ``op`` label sets."""
+    return sum(
+        v for k, v in snap.items() if k.startswith(name + ".sum")
+    )
+
+
 class SidecarService:
     """Dispatchers + admission + stats for one sidecar process.
 
@@ -395,12 +415,6 @@ class SidecarService:
         ).start()
         self._cal: dict = {}
         self.apply_calibration(cal)
-        self.admission = admission or AdmissionQueue(
-            max_inflight=flags.get_int("BFTKV_SIDECAR_MAX_INFLIGHT"),
-            max_queue=flags.get_int("BFTKV_SIDECAR_MAX_QUEUE"),
-            max_wait=flags.get_float("BFTKV_SIDECAR_MAX_WAIT"),
-            metric="sidecar.shed",
-        )
         self.max_keys = flags.get_int("BFTKV_SIDECAR_MAX_KEYS")
         self._t0 = time.monotonic()
         import jax
@@ -417,6 +431,14 @@ class SidecarService:
         # the recalibration loop exists, so its compile-laden round
         # trips can never price the crossover.
         self.warmup = self._warm()
+        # After the warm-up, which passes no admission: the queue's
+        # "empty since" is then the moment the service can first serve.
+        self.admission = admission or AdmissionQueue(
+            max_inflight=flags.get_int("BFTKV_SIDECAR_MAX_INFLIGHT"),
+            max_queue=flags.get_int("BFTKV_SIDECAR_MAX_QUEUE"),
+            max_wait=flags.get_float("BFTKV_SIDECAR_MAX_WAIT"),
+            metric="sidecar.shed",
+        )
         # Online recalibration (ISSUE 19): the boot verdict above used
         # to be forever — nothing ever called calibration(force=True)
         # again, so an accelerator attached (or un-wedged) mid-run
@@ -455,6 +477,7 @@ class SidecarService:
         Nothing to do on a CPU backend — calibration pins host there
         and no flush ever launches."""
         self._cache_events = {"loaded": 0, "compiled": 0}
+        self._durations = dict.fromkeys(_DURATION_EVENT_NAMES.values(), 0.0)
         if self._cal["prefer_host"]:
             return {"shapes": [], "seconds": 0.0}
         from bftkv_tpu import ops
@@ -467,6 +490,14 @@ class SidecarService:
         # the counts restart after the warm-up, so a later compile is a
         # compile inside some tenant's request.
         jax.monitoring.register_event_listener(self._on_jax_event)
+        jax.monitoring.register_event_duration_secs_listener(
+            self._on_jax_duration
+        )
+        # This process holds the device: its leaf phase spans go to the
+        # profiler too, on the clock of the device's own events
+        # (trace.BRIDGED; whoever captures — /debug/profile on the
+        # stats port — then sees what the host did in each idle gap).
+        trace.set_bridge(jax.profiler.TraceAnnotation)
 
         def buckets(lo: int, hi: int) -> list[int]:
             b, out = lo, []
@@ -481,13 +512,37 @@ class SidecarService:
         sig = rsamod.sign(msg, key)
         forged = sig[:-1] + bytes([sig[-1] ^ 1])
         shapes: list[dict] = []
+        fetched = _phase_sum(metrics.snapshot(), "flush.fetch")
 
         def timed(role: str, n: int, fn) -> None:
+            # The shapes run one at a time (submit blocks), so every
+            # JAX duration event and phase observation between t0 and
+            # the return is this shape's.  A kind with no event is 0.
+            nonlocal fetched
+            self._durations = dict.fromkeys(self._durations, 0.0)
             t0 = time.monotonic()
             fn(n)
             dt = round(time.monotonic() - t0, 3)
-            shapes.append({"role": role, "items": n, "seconds": dt})
-            _log.info("warm-up: %s x%d in %.1f s", role, n, dt)
+            d = self._durations
+            before, fetched = fetched, _phase_sum(
+                metrics.snapshot(), "flush.fetch"
+            )
+            shape = {
+                "role": role, "items": n, "seconds": dt,
+                "trace_s": round(d["trace"], 3),
+                "lower_s": round(d["lower"], 3),
+                "load_s": round(d["load"], 3),
+                "compile_s": round(max(0.0, d["backend"] - d["load"]), 3),
+                # blocked on the device until the result was back
+                "run_s": round(fetched - before, 3),
+            }
+            shapes.append(shape)
+            _log.info(
+                "warm-up: %s x%d in %.1f s (trace %.1f, lower %.1f, "
+                "load %.1f, compile %.1f, run %.1f)",
+                role, n, dt, shape["trace_s"], shape["lower_s"],
+                shape["load_s"], shape["compile_s"], shape["run_s"],
+            )
 
         def warm_verify(n: int) -> None:
             ok = self.verify.submit(
@@ -551,6 +606,11 @@ class SidecarService:
         name = _CACHE_EVENT_NAMES.get(event)
         if name is not None:
             self._cache_events[name] += 1
+
+    def _on_jax_duration(self, event: str, duration: float, **_kw) -> None:
+        name = _DURATION_EVENT_NAMES.get(event)
+        if name is not None:
+            self._durations[name] += duration
 
     def apply_calibration(self, cal: dict) -> None:
         """(Re-)point the dispatchers' host/device thresholds at a
@@ -620,6 +680,10 @@ class SidecarService:
             import jax
 
             jax.monitoring.unregister_event_listener(self._on_jax_event)
+            jax.monitoring.unregister_event_duration_listener(
+                self._on_jax_duration
+            )
+            trace.set_bridge(None)
 
     def stats(self) -> dict:
         """The ``/metrics``-style stats frame (OP_STATS and the stats
@@ -744,19 +808,31 @@ class _Handler(socketserver.BaseRequestHandler):
                     ):
                         return
                     body = body[:-TAG_LEN]
+                opname = None
                 if body[:4] == MAGIC and len(body) >= 5:
                     status, payload = self._handle_v2(
                         body[4], body[5:], conn_keys, next_handle
                     )
                     out = bytes([status]) + payload
+                    opname = _OP_NAMES.get(body[4])
                 else:
                     out = self._handle_v1(body)
-                tag = b"" if secret is None or not out else response_tag(
-                    secret, body, out
-                )
-                sock.sendall(struct.pack(">I", len(out) + len(tag)) + out + tag)
+                if opname is None:  # control frames, v1: no phase
+                    self._send(sock, secret, body, out)
+                else:
+                    # second interval of sidecar.reply: authenticate
+                    # and send (the first, encode, is in _handle_v2)
+                    with trace.leaf("sidecar.reply", opname, bytes=len(out)):
+                        self._send(sock, secret, body, out)
         except (ConnectionError, OSError):
             return
+
+    @staticmethod
+    def _send(sock, secret, body: bytes, out: bytes) -> None:
+        tag = b"" if secret is None or not out else response_tag(
+            secret, body, out
+        )
+        sock.sendall(struct.pack(">I", len(out) + len(tag)) + out + tag)
 
     def _handle_v1(self, body: bytes) -> bytes:
         """Legacy verify frames, bit-compatible with old clients."""
@@ -827,19 +903,25 @@ class _Handler(socketserver.BaseRequestHandler):
             return ST_SHED, b""
         try:
             metrics.incr("sidecar.ops", labels={"op": opname})
+            # sidecar.decode runs inside the admission slot: where it
+            # sits in a capture shows what that costs the queue.
+            decode = trace.leaf("sidecar.decode", opname, bytes=len(payload))
             if op == OP_VERIFY:
                 try:
-                    items = decode_request(payload)
+                    with decode:
+                        items = decode_request(payload)
                 except Exception:
                     return ST_ERR, b""
                 metrics.incr(
                     "sidecar.items", len(items), labels={"op": opname}
                 )
                 ok = self.server.dispatcher.verify(items)
-                return ST_OK, bytes(bool(b) for b in ok)
+                with trace.leaf("sidecar.reply", opname, items=len(items)):
+                    return ST_OK, bytes(bool(b) for b in ok)
             if op == OP_SIGN:
                 try:
-                    pairs = decode_sign_request(payload)
+                    with decode:
+                        pairs = decode_sign_request(payload)
                 except Exception:
                     return ST_ERR, b""
                 if any(h not in conn_keys for h, _m in pairs):
@@ -853,23 +935,26 @@ class _Handler(socketserver.BaseRequestHandler):
                 sigs = svc.sign.submit(
                     [(m, conn_keys[h]) for h, m in pairs]
                 )
-                buf = io.BytesIO()
-                for sig in sigs:
-                    write_chunk(buf, sig)
-                return ST_OK, buf.getvalue()
+                with trace.leaf("sidecar.reply", opname, items=len(pairs)):
+                    buf = io.BytesIO()
+                    for sig in sigs:
+                        write_chunk(buf, sig)
+                    return ST_OK, buf.getvalue()
             # OP_MODEXP
             try:
-                items = decode_modexp_request(payload)
+                with decode:
+                    items = decode_modexp_request(payload)
             except Exception:
                 return ST_ERR, b""
             metrics.incr(
                 "sidecar.items", len(items), labels={"op": opname}
             )
             vals = svc.modexp.submit(items)
-            buf = io.BytesIO()
-            for v in vals:
-                write_chunk(buf, _int_bytes(v))
-            return ST_OK, buf.getvalue()
+            with trace.leaf("sidecar.reply", opname, items=len(items)):
+                buf = io.BytesIO()
+                for v in vals:
+                    write_chunk(buf, _int_bytes(v))
+                return ST_OK, buf.getvalue()
         except Exception:
             # Internal failure: the status byte IS the signal — the
             # tenant falls back to local crypto and opens its breaker.
@@ -962,6 +1047,19 @@ class _StatsHandler(BaseHTTPRequestHandler):
                 self._reply(
                     200,
                     json.dumps(doc, sort_keys=True, default=str).encode(),
+                )
+            elif path.startswith("/debug/profile"):
+                # The daemon API's endpoint (cmd/bftkv.py), where it can
+                # see a device: in a --sidecar deployment only this
+                # process holds the chip.  Same helper, same
+                # confinement of the output directory.
+                from bftkv_tpu import ops
+
+                outdir = ops.capture_profile(path)
+                self._reply(
+                    200,
+                    f"trace captured to {outdir}\n".encode(),
+                    "text/plain",
                 )
             elif path == "/recalibrate":
                 # Devtools hook (ISSUE 19 satellite): force a fresh

@@ -56,6 +56,7 @@ from bftkv_tpu.cmd.verify_sidecar import (
     ST_REFUSED,
     ST_SHED,
     TAG_LEN,
+    _OP_NAMES,
     _chunks,
     encode_modexp_request,
     encode_op,
@@ -78,7 +79,6 @@ __all__ = [
     "RemoteSignerDomain",
     "RemoteModexpDomain",
 ]
-
 
 class SidecarChannel:
     """One persistent connection + breaker, shared by the domains.
@@ -150,17 +150,30 @@ class SidecarChannel:
         now open); otherwise the authenticated ``(status, payload)``."""
         if self.tripped():
             return None
-        if trace.capture() is not None:
-            # Inside a request trace, the shared-service round trip is
-            # its own budget phase — a slow write queueing behind
-            # another tenant's batch shows up HERE, not as mystery
-            # "server" time (DESIGN.md §18).
-            with trace.span(
+        t0 = time.perf_counter()
+        try:
+            if trace.capture() is not None:
+                # Inside a request trace, the shared-service round trip
+                # is its own budget phase — a slow write queueing behind
+                # another tenant's batch shows up HERE, not as mystery
+                # "server" time (DESIGN.md §18).
+                with trace.span(
+                    "sidecar.call",
+                    attrs={"op": op, "bytes": len(payload)},
+                ):
+                    return self._request(op, payload)
+            return self._request(op, payload)
+        finally:
+            # The tenant's side of the round trip, wait for the channel
+            # lock included, trace or no trace: the same interval the
+            # sidecar splits into admission, decode, dispatch and reply.
+            metrics.observe(
                 "sidecar.call",
-                attrs={"op": op, "bytes": len(payload)},
-            ):
-                return self._request(op, payload)
-        return self._request(op, payload)
+                time.perf_counter() - t0,
+                # the sidecar's own names; REGISTER and STATS frames
+                # pass no admission there and are "control" here
+                labels={"op": _OP_NAMES.get(op, "control")},
+            )
 
     def _request(self, op: int, payload: bytes) -> tuple[int, bytes] | None:
         body = encode_op(op, payload)

@@ -9,6 +9,7 @@ reference gets from Go's crypto/rsa (crypto/threshold/rsa/rsa.go:345-378).
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import logging
 from collections import OrderedDict
@@ -16,6 +17,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from bftkv_tpu import trace
 from bftkv_tpu.errors import ERR_INVALID_SIGNATURE
 from bftkv_tpu.metrics import registry as metrics
 from bftkv_tpu.ops import bigint, limb
@@ -467,7 +469,9 @@ class SignerDomain:
             exps += [dp, dq]
             mods += [key.p, key.q]
         try:
-            vals = rns_ops.power_mod_rns(bases, exps, mods, n_bits=w * 16)
+            vals = rns_ops.power_mod_rns(
+                bases, exps, mods, n_bits=w * 16, op="sign"
+            )
         except Exception:
             # Unexpected kernel failure (the *expected* "can't take this
             # key" signal is vals None): degrade to the limb path, but
@@ -481,11 +485,14 @@ class SignerDomain:
         metrics.incr("sign.device", len(group))
         metrics.observe("sign.device_batch", len(group))
         sigs: list[tuple[int, object, int]] = []  # (item idx, key, s)
-        for j, (i, key, m, _domp, _domq, _dp, _dq, qinv) in enumerate(group):
-            m1, m2 = vals[2 * j], vals[2 * j + 1]
-            h = (qinv * (m1 - m2)) % key.p
-            s = m2 + h * key.q
-            sigs.append((i, key, s))
+        with trace.leaf("flush.unpack", "sign", items=len(group)):
+            for j, (i, key, m, _domp, _domq, _dp, _dq, qinv) in enumerate(
+                group
+            ):
+                m1, m2 = vals[2 * j], vals[2 * j + 1]
+                h = (qinv * (m1 - m2)) % key.p
+                s = m2 + h * key.q
+                sigs.append((i, key, s))
         # Fault check (Boneh–DeMillo–Lipton): one silently wrong CRT
         # half would let any observer factor the modulus via
         # gcd(s^e − em, n).  Verify every output before release — one
@@ -523,35 +530,39 @@ class SignerDomain:
         dig_em: list[np.ndarray] = []
         device_pos: list[int] = []
         ok = [False] * len(sigs)
-        for pos, ((_i, key, s), em) in enumerate(zip(sigs, ems)):
-            kr = ctx.key_rows(key.n) if key.e == F4 else None
-            if kr is None:
-                ok[pos] = pow(s, key.e, key.n) == em
-                continue
-            u = unique.get(key.n)
-            if u is None:
-                u = unique[key.n] = len(urows)
-                urows.append(kr)
-            idxs.append(u)
-            dig_s.append(limb.int_to_limbs(s, 128))
-            dig_em.append(limb.int_to_limbs(em, 128))
-            device_pos.append(pos)
-        if device_pos:
-            k = len(device_pos)
-            padded = max(256, 1 << (k - 1).bit_length())
-            idxs += [0] * (padded - k)
-            dig_s += [np.zeros(128, dtype=np.uint32)] * (padded - k)
-            dig_em += [dig_em[0]] * (padded - k)
-            kpad = max(64, 1 << (len(urows) - 1).bit_length())
-            urows += [urows[0]] * (kpad - len(urows))
-            good = np.asarray(
-                rns_ops.verify_e65537_rns_indexed(
-                    np.stack(dig_s),
-                    np.stack(dig_em),
-                    idxs,
-                    rns_ops.stack_key_rows(urows),
+        # The check is a verify launch of its own — stage, launch,
+        # fetch, unpack — under the op of the sign it polices.
+        with trace.leaf("flush.stage", "sign", items=len(sigs)) as stage:
+            for pos, ((_i, key, s), em) in enumerate(zip(sigs, ems)):
+                kr = ctx.key_rows(key.n) if key.e == F4 else None
+                if kr is None:
+                    ok[pos] = pow(s, key.e, key.n) == em
+                    continue
+                u = unique.get(key.n)
+                if u is None:
+                    u = unique[key.n] = len(urows)
+                    urows.append(kr)
+                idxs.append(u)
+                dig_s.append(limb.int_to_limbs(s, 128))
+                dig_em.append(limb.int_to_limbs(em, 128))
+                device_pos.append(pos)
+            if device_pos:
+                k = len(device_pos)
+                padded = max(256, 1 << (k - 1).bit_length())
+                stage.attrs["bucket"] = padded
+                idxs += [0] * (padded - k)
+                dig_s += [np.zeros(128, dtype=np.uint32)] * (padded - k)
+                dig_em += [dig_em[0]] * (padded - k)
+                kpad = max(64, 1 << (len(urows) - 1).bit_length())
+                urows += [urows[0]] * (kpad - len(urows))
+                staged = _stage_verify_operands(
+                    dig_s, dig_em, idxs, urows
                 )
-            )[:k]
+        if device_pos:
+            with trace.leaf("flush.launch", "sign", items=k, bucket=padded):
+                dev = rns_ops.verify_e65537_rns_indexed(*staged)
+            with trace.leaf("flush.fetch", "sign", items=k):
+                good = np.asarray(dev)[:k]
             for pos, g in zip(device_pos, good):
                 ok[pos] = bool(g)
             # The device check shares MXU/VPU machinery with the sign it
@@ -584,20 +595,23 @@ class SignerDomain:
         if len(items) < self.host_threshold:
             host_idx = list(range(len(items)))
         else:
-            for i, (message, key) in enumerate(items):
-                lp = limb.nlimbs_for_bits(key.p.bit_length())
-                lq = limb.nlimbs_for_bits(key.q.bit_length())
-                w = max(lp, lq)
-                domp = self._dom(key.p, w)
-                domq = self._dom(key.q, w)
-                if domp is None or domq is None:
-                    host_idx.append(i)
-                    continue
-                m = emsa_pkcs1v15_sha256(message, key.size_bytes)
-                dp, dq, qinv = self._crt_params(key)
-                by_width.setdefault(w, []).append(
-                    (i, key, m, domp, domq, dp, dq, qinv)
-                )
+            # per-item encodings and CRT constants: staging of the
+            # launches below, the first interval of their flush.stage
+            with trace.leaf("flush.stage", "sign", items=len(items)):
+                for i, (message, key) in enumerate(items):
+                    lp = limb.nlimbs_for_bits(key.p.bit_length())
+                    lq = limb.nlimbs_for_bits(key.q.bit_length())
+                    w = max(lp, lq)
+                    domp = self._dom(key.p, w)
+                    domq = self._dom(key.q, w)
+                    if domp is None or domq is None:
+                        host_idx.append(i)
+                        continue
+                    m = emsa_pkcs1v15_sha256(message, key.size_bytes)
+                    dp, dq, qinv = self._crt_params(key)
+                    by_width.setdefault(w, []).append(
+                        (i, key, m, domp, domq, dp, dq, qinv)
+                    )
         for i in host_idx:
             out[i] = sign(items[i][0], items[i][1])
         from bftkv_tpu.ops import rsa as rsa_ops
@@ -815,27 +829,40 @@ class VerifierDomain:
         device_items: list[tuple[bytes, bytes, PublicKey]] = []
         ec_idx: list[int] = []
         ec_items: list = []
-        for i, (message, sig_bytes, key) in enumerate(items):
-            if certmod.is_ec(key):
-                # ECDSA P-256 identity keys: batched device verify via
-                # ops.ec (two scalar mults per item in one launch).
-                ec_idx.append(i)
-                ec_items.append((message, sig_bytes, key))
-                continue
-            # 512-bit floor keeps the PKCS#1 encoding well-defined.
-            if (
-                key.e == F4
-                and key.n.bit_length() >= 512
-                and self._dom(key.n) is not None
-            ):
-                device_idx.append(i)
-                device_items.append((message, sig_bytes, key))
-            else:
-                # Host oracle for odd exponents; fails closed on junk keys.
-                try:
-                    out[i] = key.n > 0 and verify_host(message, sig_bytes, key)
-                except Exception:
-                    out[i] = False
+        # The per-item tier split is the first interval of the launch's
+        # flush.stage where the batch is bound for the RNS chain (the
+        # second, in _verify_rns, builds the operands).
+        to_device = self.backend == "rns" and not self._stay_on_host(
+            len(items)
+        )
+        with (
+            trace.leaf("flush.stage", "verify", items=len(items))
+            if to_device else contextlib.nullcontext()
+        ):
+            for i, (message, sig_bytes, key) in enumerate(items):
+                if certmod.is_ec(key):
+                    # ECDSA P-256 identity keys: batched device verify
+                    # via ops.ec (two scalar mults per item, one launch).
+                    ec_idx.append(i)
+                    ec_items.append((message, sig_bytes, key))
+                    continue
+                # 512-bit floor keeps the PKCS#1 encoding well-defined.
+                if (
+                    key.e == F4
+                    and key.n.bit_length() >= 512
+                    and self._dom(key.n) is not None
+                ):
+                    device_idx.append(i)
+                    device_items.append((message, sig_bytes, key))
+                else:
+                    # Host oracle for odd exponents; fails closed on
+                    # junk keys.
+                    try:
+                        out[i] = key.n > 0 and verify_host(
+                            message, sig_bytes, key
+                        )
+                    except Exception:
+                        out[i] = False
         if ec_items:
             from bftkv_tpu.crypto import ecdsa as _ecdsa
 
@@ -902,56 +929,76 @@ class VerifierDomain:
         unique: dict[int, int] = {}
         urows: list = []
         idxs, digit_rows, em_rows, keep_idx = [], [], [], []
-        for j, (message, sig_bytes, key) in zip(device_idx, device_items):
-            kr = ctx.key_rows(key.n)
-            s = int.from_bytes(sig_bytes, "big")
-            if kr is None or s >= key.n:
-                # Hostile modulus (or oversized sig): host oracle,
-                # failing closed on junk.
-                metrics.incr("verify.host")
-                try:
-                    out[j] = s < key.n and verify_host(
-                        message, sig_bytes, key
+        with trace.leaf(
+            "flush.stage", "verify", items=len(device_items)
+        ) as sp:
+            for j, (message, sig_bytes, key) in zip(device_idx, device_items):
+                kr = ctx.key_rows(key.n)
+                s = int.from_bytes(sig_bytes, "big")
+                if kr is None or s >= key.n:
+                    # Hostile modulus (or oversized sig): host oracle,
+                    # failing closed on junk.
+                    metrics.incr("verify.host")
+                    try:
+                        out[j] = s < key.n and verify_host(
+                            message, sig_bytes, key
+                        )
+                    except Exception:
+                        out[j] = False
+                    continue
+                u = unique.get(key.n)
+                if u is None:
+                    u = unique[key.n] = len(urows)
+                    urows.append(kr)
+                idxs.append(u)
+                digit_rows.append(limb.int_to_limbs(s, 128))
+                em_rows.append(
+                    limb.int_to_limbs(
+                        emsa_pkcs1v15_sha256(message, key.size_bytes), 128
                     )
-                except Exception:
-                    out[j] = False
-                continue
-            u = unique.get(key.n)
-            if u is None:
-                u = unique[key.n] = len(urows)
-                urows.append(kr)
-            idxs.append(u)
-            digit_rows.append(limb.int_to_limbs(s, 128))
-            em_rows.append(
-                limb.int_to_limbs(
-                    emsa_pkcs1v15_sha256(message, key.size_bytes), 128
                 )
+                keep_idx.append(j)
+            if not idxs:
+                return
+            k = len(idxs)
+            metrics.incr("verify.device", k)
+            metrics.observe("verify.device_batch", k)
+            # Power-of-two buckets (floor 256), padding with row 0's key
+            # and sig digits of 0 — 0^e never equals a PKCS#1 encoding.
+            padded = max(256, 1 << (k - 1).bit_length())
+            sp.attrs["bucket"] = padded
+            for _ in range(padded - k):
+                idxs.append(0)
+                digit_rows.append(np.zeros(128, dtype=np.uint32))
+                em_rows.append(em_rows[0])
+            # The unique-key axis is padded to a fixed floor of 64 (64
+            # rows ≈ 800 KB of transfer — noise) so the (T, K) shape pair
+            # is a function of T alone in any realistic cluster; a flush
+            # with more distinct keys escalates to the next power of two
+            # and pays one recompile.
+            kpad = max(64, 1 << (len(urows) - 1).bit_length())
+            urows += [urows[0]] * (kpad - len(urows))
+            staged = _stage_verify_operands(
+                digit_rows, em_rows, idxs, urows
             )
-            keep_idx.append(j)
-        if not idxs:
-            return
-        k = len(idxs)
-        metrics.incr("verify.device", k)
-        metrics.observe("verify.device_batch", k)
-        # Power-of-two buckets (floor 256), padding with row 0's key and
-        # sig digits of 0 — 0^e never equals a PKCS#1 encoding.
-        padded = max(256, 1 << (k - 1).bit_length())
-        for _ in range(padded - k):
-            idxs.append(0)
-            digit_rows.append(np.zeros(128, dtype=np.uint32))
-            em_rows.append(em_rows[0])
-        # The unique-key axis is padded to a fixed floor of 64 (64 rows
-        # ≈ 800 KB of transfer — noise) so the (T, K) shape pair is a
-        # function of T alone in any realistic cluster; a flush with
-        # more distinct keys escalates to the next power of two and
-        # pays one recompile.
-        kpad = max(64, 1 << (len(urows) - 1).bit_length())
-        urows += [urows[0]] * (kpad - len(urows))
-        unique_rows = rns.stack_key_rows(urows)
-        with metrics.timer("verify.launch"):
-            ok = np.asarray(
-                rns.verify_e65537_rns_indexed(
-                    np.stack(digit_rows), np.stack(em_rows), idxs, unique_rows
-                )
-            )[:k]
-        out[np.asarray(keep_idx)] = ok
+        with trace.leaf("flush.launch", "verify", items=k, bucket=padded):
+            dev = rns.verify_e65537_rns_indexed(*staged)
+        with trace.leaf("flush.fetch", "verify", items=k):
+            ok = np.asarray(dev)[:k]
+        with trace.leaf("flush.unpack", "verify", items=k):
+            out[np.asarray(keep_idx)] = ok
+
+
+def _stage_verify_operands(digit_rows, em_rows, idxs, urows) -> tuple:
+    """The last step of a verify launch's staging: operand rows to the
+    arrays ``rns.verify_e65537_rns_indexed`` hands the device as they
+    are (uint8 halves, int32 key index, stacked unique key rows), so
+    that the call itself is the launch and nothing else."""
+    from bftkv_tpu.ops import rns
+
+    return (
+        rns.digits_to_halves_u8(np.stack(digit_rows)),
+        rns.digits_to_halves_u8(np.stack(em_rows)),
+        np.asarray(idxs, dtype=np.int32),
+        rns.stack_key_rows(urows),
+    )

@@ -198,7 +198,9 @@ class Metrics:
                 totals[k] += v
         return dict(totals)
 
-    def incr(self, name: str, n: int = 1, labels: dict | None = None) -> None:
+    def incr(
+        self, name: str, n: int | float = 1, labels: dict | None = None
+    ) -> None:
         self._local_counters()[_key(name, labels)] += n
 
     def gauge(

@@ -51,3 +51,49 @@ def enable_compile_cache() -> None:
 
     if jax.config.jax_compilation_cache_dir != COMPILE_CACHE_DIR:
         jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+
+
+def capture_profile(path: str) -> str:
+    """``/debug/profile?seconds=N&name=…`` — of the daemon API and of
+    the sidecar's stats port: one jax profiler capture (TensorBoard /
+    Perfetto / ``jax.profiler.ProfileData``) of the ``seconds`` asked
+    for, at most 30.  Returns the directory it was written to, which is
+    confined to ``<tmp>/bftkv-profile/<name>``: the port may be exposed
+    beyond localhost, so a caller names a capture and never a path.
+
+    Where the process bridges its own phase spans to the profiler (the
+    sidecar: :func:`bftkv_tpu.trace.set_bridge`), Python's function
+    tracer stays off: its events would outnumber, and out-cover, the
+    spans that say what the host did while the device waited."""
+    import re
+    import tempfile
+    import time
+    import urllib.parse
+
+    import jax
+
+    from bftkv_tpu import trace
+
+    q = urllib.parse.parse_qs(urllib.parse.urlparse(path).query)
+    try:
+        seconds = float(q.get("seconds", ["2"])[0])
+    except ValueError:
+        seconds = 2.0
+    if not (seconds >= 0.0):  # also catches NaN
+        seconds = 0.0
+    seconds = min(seconds, 30.0)
+    name = re.sub(r"[^A-Za-z0-9_.-]", "_", q.get("name", ["trace"])[0])[:64]
+    # "", "." and ".." survive the character filter but escape (or
+    # collapse into) the confinement root.
+    if name in ("", ".", ".."):
+        name = "trace"
+    outdir = _os.path.join(tempfile.gettempdir(), "bftkv-profile", name)
+    opts = jax.profiler.ProfileOptions()
+    if trace.bridged():
+        opts.python_tracer_level = 0
+    jax.profiler.start_trace(outdir, profiler_options=opts)
+    try:
+        time.sleep(seconds)
+    finally:
+        jax.profiler.stop_trace()
+    return outdir

@@ -104,6 +104,12 @@ def forget_launch_rtt() -> None:
         _LAUNCH_RTT_EWMA = None
 
 
+def dispatch_rtt_probe(x):
+    """The trivial device program :func:`calibration` times (named for
+    the device trace, like every program the sidecar can launch)."""
+    return x * 2 + 1
+
+
 def calibration(force: bool = False) -> dict:
     """Measured host-verify cost vs device launch RTT, once per process.
 
@@ -183,7 +189,7 @@ def calibration(force: bool = False) -> dict:
             if rtt is None:
                 import jax.numpy as jnp
 
-                f = jax.jit(lambda x: x * 2 + 1)
+                f = jax.jit(dispatch_rtt_probe)
                 x = jax.device_put(jnp.zeros((256, 128), jnp.uint32))
                 jax.block_until_ready(f(x))  # compile outside the timing
                 t0 = time.perf_counter()
@@ -228,6 +234,10 @@ class _BatchDispatcher:
 
     #: metrics prefix; subclasses override.
     name = "dispatch"
+
+    #: What this pool serves: the ``op`` label of its phase spans and
+    #: histograms (``dispatch.linger``, ``flush.*``; DESIGN.md §7).
+    op = "verify"
 
     #: Flushes in flight at once (``BFTKV_DISPATCH_PIPELINE`` overrides).
     #: A flush is [host assembly | device round trip | scatter]; with a
@@ -466,32 +476,39 @@ class _BatchDispatcher:
                     return
                 # Wait for more work up to max_wait after the first
                 # pending item, unless the batch target is already met.
-                deadline = time.monotonic() + self.max_wait
-                while (
-                    self._running
-                    and self._queued_items < self.max_batch
-                    and (remaining := deadline - time.monotonic()) > 0
-                ):
-                    self._cv.wait(timeout=remaining)
-                # Bounded pop: whole pending entries up to ``max_batch``
-                # items (always at least one).  Draining the queue
-                # unboundedly would merge every queued caller's batch
-                # into one flush and make EACH wait for ALL — the
-                # head-of-line latency no chunking inside the flush can
-                # undo (results scatter only when the whole flush
-                # returns).  The remainder flushes on the next loop
-                # iteration, so a burst still coalesces into
-                # max_batch-sized launches.
-                batch = []
-                taken = 0
-                while self._queue and (
-                    not batch
-                    or taken + len(self._queue[0].items) <= self.max_batch
-                ):
-                    p = self._queue.pop(0)
-                    batch.append(p)
-                    taken += len(p.items)
-                self._queued_items -= taken
+                # ``dispatch.linger`` is that window, first pending
+                # entry seen to batch popped (the wait on an EMPTY
+                # queue above is no phase of any launch).
+                with trace.leaf("dispatch.linger", self.op) as sp:
+                    deadline = time.monotonic() + self.max_wait
+                    while (
+                        self._running
+                        and self._queued_items < self.max_batch
+                        and (remaining := deadline - time.monotonic()) > 0
+                    ):
+                        self._cv.wait(timeout=remaining)
+                    # Bounded pop: whole pending entries up to
+                    # ``max_batch`` items (always at least one).
+                    # Draining the queue unboundedly would merge every
+                    # queued caller's batch into one flush and make EACH
+                    # wait for ALL — the head-of-line latency no
+                    # chunking inside the flush can undo (results
+                    # scatter only when the whole flush returns).  The
+                    # remainder flushes on the next loop iteration, so a
+                    # burst still coalesces into max_batch-sized
+                    # launches.
+                    batch = []
+                    taken = 0
+                    while self._queue and (
+                        not batch
+                        or taken + len(self._queue[0].items)
+                        <= self.max_batch
+                    ):
+                        p = self._queue.pop(0)
+                        batch.append(p)
+                        taken += len(p.items)
+                    self._queued_items -= taken
+                    sp.attrs["items"] = taken
             if self.pipeline == 1:
                 self._flush(batch)
             else:
@@ -611,11 +628,16 @@ class _BatchDispatcher:
                 throughput = len(flat) / dt
                 sp.attrs["items_per_s"] = round(throughput, 1)
                 metrics.gauge(f"{self.name}.throughput", throughput)
-        off = 0
-        for p in batch:
-            p.result = out[off : off + len(p.items)]
-            off += len(p.items)
-            p.event.set()
+        self._scatter(batch, out)
+
+    def _scatter(self, batch: list[_Pending], out) -> None:
+        """Results to the callers' futures."""
+        with trace.leaf("flush.scatter", self.op, items=len(out)):
+            off = 0
+            for p in batch:
+                p.result = out[off : off + len(p.items)]
+                off += len(p.items)
+                p.event.set()
 
     def _completion_drain(self, completions) -> None:
         # Finalizes async launches strictly FIFO: block on the device
@@ -642,11 +664,7 @@ class _BatchDispatcher:
             if dt > 0:
                 metrics.gauge(f"{self.name}.throughput", n_items / dt)
             note_launch_rtt(dt)
-            off = 0
-            for p in batch:
-                p.result = out[off : off + len(p.items)]
-                off += len(p.items)
-                p.event.set()
+            self._scatter(batch, out)
 
 
 class VerifyDispatcher(_BatchDispatcher):
@@ -718,6 +736,7 @@ class SignDispatcher(_BatchDispatcher):
     """
 
     name = "signdispatch"
+    op = "sign"
 
     #: A sign launch costs ~115 ms regardless of batch, so waiting
     #: 20 ms to fill it is cheap: measured at 16 replicas, 2 ms flushes
@@ -834,6 +853,7 @@ class ModexpDispatcher(_BatchDispatcher):
     """
 
     name = "modexpdispatch"
+    op = "modexp"
 
     def __init__(
         self,

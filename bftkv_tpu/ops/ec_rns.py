@@ -437,7 +437,7 @@ def _bcast(c, t: int):
 def _scalar_mult_fn():
     eng = _engine()
 
-    def run(Xb, Xq, Xr, Yb, Yq, Yr, Zb, Zq, Zr, nibbles_t):
+    def ec_rns_scalar_mult(Xb, Xq, Xr, Yb, Yq, Yr, Zb, Zq, Zr, nibbles_t):
         P = ((Xb, Xq, Xr), (Yb, Yq, Yr), (Zb, Zq, Zr))
         t = Xb.shape[1]
         one_m = _bcast(eng.one_m, t)
@@ -471,7 +471,7 @@ def _scalar_mult_fn():
         acc, _ = lax.scan(body, ident, nibbles_t)
         return acc
 
-    return jax.jit(run)
+    return jax.jit(ec_rns_scalar_mult)
 
 
 def _nibbles(scalars: list[int]) -> np.ndarray:

@@ -173,6 +173,7 @@ def verify_e65537(sig, em, n, nprime, r2, *, interpret: bool = False):
     spec = pl.BlockSpec((TILE, L), lambda i: (i, 0), memory_space=pltpu.VMEM)
     diff = pl.pallas_call(
         _verify_kernel,
+        name="mont_verify_chain",
         out_shape=jax.ShapeDtypeStruct((batch, L), jnp.uint32),
         grid=(grid,),
         in_specs=[spec] * 5,

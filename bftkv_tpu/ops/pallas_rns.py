@@ -347,7 +347,7 @@ def _pow_prep(k: int, kpad: int):
     """
 
     @jax.jit
-    def prep(idx, ukey):
+    def rns_pow_prep(idx, ukey):
         n_all, n_r, neg_ninv_b, _ninv, m2_all, m2_r = tuple(
             u[idx] for u in ukey
         )
@@ -358,7 +358,7 @@ def _pow_prep(k: int, kpad: int):
             pad(m2_all[:, :k]), pad(m2_all[:, k:]), m2_r,
         )
 
-    return prep
+    return rns_pow_prep
 
 
 @functools.lru_cache(maxsize=8)
@@ -366,7 +366,7 @@ def _verify_prep(k: int, kpad: int):
     """Jitted gather/pad prologue for the verify chain (see _pow_prep)."""
 
     @jax.jit
-    def prep(idx, ukey):
+    def rns_verify_prep(idx, ukey):
         n_all, n_r, neg_ninv_b, ninv_all, m2_all, m2_r = tuple(
             u[idx] for u in ukey
         )
@@ -378,7 +378,7 @@ def _verify_prep(k: int, kpad: int):
             pad(m2_all[:, :k]), pad(m2_all[:, k:]), m2_r,
         )
 
-    return prep
+    return rns_verify_prep
 
 
 @functools.lru_cache(maxsize=8)
@@ -391,7 +391,7 @@ def _pow_call(digits: int, n_bits: int, tile: int, interpret: bool):
     )
 
     @jax.jit
-    def run(base_h, nib_t, nb, nq, nr, ninvb, m2b, m2q, m2r):
+    def rns_pow_pallas(base_h, nib_t, nb, nq, nr, ninvb, m2b, m2q, m2r):
         batch = base_h.shape[0]
         grid = batch // tile
         row = lambda width: pl.BlockSpec(
@@ -402,6 +402,7 @@ def _pow_call(digits: int, n_bits: int, tile: int, interpret: bool):
         )
         return pl.pallas_call(
             kernel,
+            name="rns_pow_chain",
             out_shape=jax.ShapeDtypeStruct((batch, kpad), jnp.float32),
             grid=(grid,),
             in_specs=[
@@ -418,7 +419,7 @@ def _pow_call(digits: int, n_bits: int, tile: int, interpret: bool):
             interpret=interpret,
         )(base_h, nib_t, nb, nq, nr, ninvb, m2b, m2q, m2r, *consts)
 
-    return run
+    return rns_pow_pallas
 
 
 def pow_pallas(
@@ -505,7 +506,9 @@ def _verify_call(digits: int, n_bits: int, tile: int, interpret: bool):
     )
 
     @jax.jit
-    def run(sig_h, em_h, nb, nq, nr, ninvb, ninv_b, ninv_q, m2b, m2q, m2r):
+    def rns_verify_pallas(
+        sig_h, em_h, nb, nq, nr, ninvb, ninv_b, ninv_q, m2b, m2q, m2r
+    ):
         batch = sig_h.shape[0]
         grid = batch // tile
         row = lambda width: pl.BlockSpec(
@@ -516,6 +519,7 @@ def _verify_call(digits: int, n_bits: int, tile: int, interpret: bool):
         )
         out = pl.pallas_call(
             kernel,
+            name="rns_verify_chain",
             out_shape=jax.ShapeDtypeStruct((batch, 1), jnp.float32),
             grid=(grid,),
             in_specs=[
@@ -530,7 +534,7 @@ def _verify_call(digits: int, n_bits: int, tile: int, interpret: bool):
         )(sig_h, em_h, nb, nq, nr, ninvb, ninv_b, ninv_q, m2b, m2q, m2r, *consts)
         return out[:, 0] > 0
 
-    return run
+    return rns_verify_pallas
 
 
 def verify_pallas(

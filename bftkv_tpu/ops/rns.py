@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bftkv_tpu import trace
 from bftkv_tpu.ops import devbuf
 from bftkv_tpu.ops import limb
 from bftkv_tpu import flags
@@ -395,10 +396,10 @@ def _jitted_verify():
     f = flat_verify_fn()
 
     @jax.jit
-    def g(sig_halves, em_halves, key):
+    def rns_verify(sig_halves, em_halves, key):
         return f(sig_halves, em_halves, *key)
 
-    return g
+    return rns_verify
 
 
 @functools.lru_cache(maxsize=1)
@@ -413,7 +414,7 @@ def _jitted_verify_gather():
     cn = _Consts(context())
 
     @jax.jit
-    def g(sig_halves_u8, em_halves_u8, idx, ukey):
+    def rns_verify_gather(sig_halves_u8, em_halves_u8, idx, ukey):
         key = tuple(u[idx] for u in ukey)
         return _verify_kernel(
             cn,
@@ -422,7 +423,7 @@ def _jitted_verify_gather():
             key,
         )
 
-    return g
+    return rns_verify_gather
 
 
 # ---------------------------------------------------------------------------
@@ -495,13 +496,13 @@ def _jitted_pow(digits: int, n_bits: int, donate: bool = False):
     the kernel instead of defensively copying them — the host-side
     staging slot (:mod:`bftkv_tpu.ops.devbuf`) stays owned by the host
     and is reused for the next flush.  CPU ignores donation with a
-    warning, so callers gate it on the backend."""
+    warning, so callers gate it on the backend.
+
+    The program is named after its width (``rns_pow_1024``: the CRT
+    halves of RSA-2048), so a device trace tells the chains apart."""
     cn = _Consts(context(digits, n_bits))
 
-    @functools.partial(
-        jax.jit, donate_argnums=(0, 1, 2) if donate else ()
-    )
-    def g(base_halves_u8, exp_nibbles_t_u8, idx, ukey):
+    def rns_pow(base_halves_u8, exp_nibbles_t_u8, idx, ukey):
         key = tuple(u[idx] for u in ukey)
         return _pow_kernel(
             cn,
@@ -510,7 +511,8 @@ def _jitted_pow(digits: int, n_bits: int, donate: bool = False):
             key,
         )
 
-    return g
+    rns_pow.__name__ = f"rns_pow_{n_bits}"
+    return jax.jit(rns_pow, donate_argnums=(0, 1, 2) if donate else ())
 
 
 def _crt_matrix(ctx: RNSContext) -> np.ndarray:
@@ -591,7 +593,7 @@ def _pow_staging(digits: int, n_bits: int, padded: int):
 
 def power_mod_rns(
     bases: list[int], exps: list[int], mods: list[int], *,
-    n_bits: int = 1024, defer: bool = False,
+    n_bits: int = 1024, defer: bool = False, op: str = "modexp",
 ):
     """Batched x^e mod m with per-row (x, e, m) — the threshold-RSA /
     CRT-signing workhorse.  Returns a list of ints, or None when any
@@ -605,6 +607,10 @@ def power_mod_rns(
     the launch is dispatched but NOT blocked on, so the caller (the
     async dispatcher) can stage further width groups while the device
     works.  The staging slot stays in flight until ``wait()``.
+
+    ``op`` labels the launch's phase spans and histograms
+    (``flush.stage`` / ``.launch`` / ``.fetch`` / ``.unpack``): the
+    signer passes ``sign``.
     """
     if not mods:
         return []
@@ -613,37 +619,8 @@ def power_mod_rns(
             return None
     digits = max(32, (n_bits + 15) // 16)
     ctx = context(digits, n_bits)
-    unique: dict[int, int] = {}
-    urows: list = []
-    idxs: list[int] = []
-    for m in mods:
-        u = unique.get(m)
-        if u is None:
-            r = ctx.key_rows(m)
-            if r is None:
-                return None
-            u = unique[m] = len(urows)
-            urows.append(r)
-        idxs.append(u)
-    t = len(idxs)
-    # Pad the batch axis (floor 64) to power-of-two buckets so only a
-    # handful of kernel shapes compile.  The unique-modulus axis gets a
-    # fixed floor of 64: cross-request flushes mix many signers' p/q,
-    # and every fresh (T, K) pair would recompile the 256-step scan
-    # (~15-60 s); 64 padded key rows are < 1 MB of extra transfer.
-    padded = max(64, 1 << (t - 1).bit_length())
-    kpad = max(64, 1 << (len(urows) - 1).bit_length())
-    urows += [urows[0]] * (kpad - len(urows))
-    ukey = tuple(jnp.asarray(a) for a in stack_key_rows(urows))
-    # Stage operands into a persistent slot (devbuf ring) or throwaway
-    # arrays: ONLY the t live rows ride the int→limb→half pipeline; the
-    # pad region broadcasts row 0 in place, which is bit-identical to
-    # the historical pad-the-input-lists-with-item-0 convention (pad
-    # base = bases[0] % mods[0] = row 0's conversion; pad unique-index
-    # is 0 = row 0's by construction) without its per-pad-row bigint
-    # conversions or per-launch allocations.
-    ring, slot = _pow_staging(digits, n_bits, padded)
-    bh, nt, ix = slot["base_halves"], slot["nib_t"], slot["idx"]
+    t = len(mods)
+    ring = slot = None
     released = False
 
     def _release():
@@ -654,43 +631,88 @@ def power_mod_rns(
                 ring.release(slot)
 
     try:
-        base_digits = np.stack(
-            [limb.int_to_limbs(b % m, digits) for b, m in zip(bases, mods)]
-        )
-        bh[:t, 0::2] = base_digits & 0xFF
-        bh[:t, 1::2] = base_digits >> 8
-        ed = np.stack(
-            [limb.int_to_limbs(e, digits) for e in exps]
-        )  # (t, digits)
-        nib = np.empty((t, digits * 4), dtype=np.uint8)
-        nib[:, 0::4] = ed & 0xF  # little-endian within each 16-bit digit
-        nib[:, 1::4] = (ed >> 4) & 0xF
-        nib[:, 2::4] = (ed >> 8) & 0xF
-        nib[:, 3::4] = (ed >> 12) & 0xF
-        nt[:, :t] = nib[:, ::-1].T  # most-significant nibble first
-        ix[:t] = np.asarray(idxs, dtype=np.int32)
-        if padded > t:
-            bh[t:] = bh[0:1]
-            nt[:, t:] = nt[:, 0:1]
-            ix[t:] = 0
-        pow_args = (bh, nt, ix, ukey)
+        with trace.leaf("flush.stage", op, items=t) as sp:
+            unique: dict[int, int] = {}
+            urows: list = []
+            idxs: list[int] = []
+            for m in mods:
+                u = unique.get(m)
+                if u is None:
+                    r = ctx.key_rows(m)
+                    if r is None:
+                        return None
+                    u = unique[m] = len(urows)
+                    urows.append(r)
+                idxs.append(u)
+            # Pad the batch axis (floor 64) to power-of-two buckets so
+            # only a handful of kernel shapes compile.  The
+            # unique-modulus axis gets a fixed floor of 64:
+            # cross-request flushes mix many signers' p/q, and every
+            # fresh (T, K) pair would recompile the 256-step scan
+            # (~15-60 s); 64 padded key rows are < 1 MB of extra
+            # transfer.
+            padded = max(64, 1 << (t - 1).bit_length())
+            sp.attrs["bucket"] = padded
+            kpad = max(64, 1 << (len(urows) - 1).bit_length())
+            urows += [urows[0]] * (kpad - len(urows))
+            ukey = tuple(jnp.asarray(a) for a in stack_key_rows(urows))
+            # Stage operands into a persistent slot (devbuf ring) or
+            # throwaway arrays: ONLY the t live rows ride the
+            # int→limb→half pipeline; the pad region broadcasts row 0 in
+            # place, which is bit-identical to the historical
+            # pad-the-input-lists-with-item-0 convention (pad base =
+            # bases[0] % mods[0] = row 0's conversion; pad unique-index
+            # is 0 = row 0's by construction) without its per-pad-row
+            # bigint conversions or per-launch allocations.
+            ring, slot = _pow_staging(digits, n_bits, padded)
+            bh, nt, ix = slot["base_halves"], slot["nib_t"], slot["idx"]
+            base_digits = np.stack(
+                [limb.int_to_limbs(b % m, digits)
+                 for b, m in zip(bases, mods)]
+            )
+            bh[:t, 0::2] = base_digits & 0xFF
+            bh[:t, 1::2] = base_digits >> 8
+            ed = np.stack(
+                [limb.int_to_limbs(e, digits) for e in exps]
+            )  # (t, digits)
+            nib = np.empty((t, digits * 4), dtype=np.uint8)
+            nib[:, 0::4] = ed & 0xF  # little-endian within a 16-bit digit
+            nib[:, 1::4] = (ed >> 4) & 0xF
+            nib[:, 2::4] = (ed >> 8) & 0xF
+            nib[:, 3::4] = (ed >> 12) & 0xF
+            nt[:, :t] = nib[:, ::-1].T  # most-significant nibble first
+            ix[:t] = np.asarray(idxs, dtype=np.int32)
+            if padded > t:
+                bh[t:] = bh[0:1]
+                nt[:, t:] = nt[:, 0:1]
+                ix[t:] = 0
+            pow_args = (bh, nt, ix, ukey)
+        mods_live = list(mods)
+
+        def unpack(sigma: np.ndarray) -> list[int]:
+            with trace.leaf("flush.unpack", op, items=t):
+                vals = _sigma_to_ints(ctx, sigma)
+                return [v % m for v, m in zip(vals, mods_live)]
+
         sigma = None
         if _use_pallas("BFTKV_RNS_POW_BACKEND"):
             try:
                 from bftkv_tpu.ops import pallas_rns
 
-                sigma = np.asarray(
-                    pallas_rns.pow_pallas(
-                        *pow_args, digits=digits, n_bits=n_bits
-                    )
-                )[:t]
+                # the fused chain blocks on its result: launch and
+                # fetch are one interval here
+                with trace.leaf("flush.launch", op, items=t, bucket=padded):
+                    sigma = np.asarray(
+                        pallas_rns.pow_pallas(
+                            *pow_args, digits=digits, n_bits=n_bits
+                        )
+                    )[:t]
                 _PALLAS_STATUS["pow"] = "ok"
             except Exception as e:
                 _pallas_fell_back("pow", e)
         if sigma is not None:
             _release()
-            vals = _sigma_to_ints(ctx, sigma)
-            res = [v % m for v, m in zip(vals, mods)]
+            res = unpack(sigma)
             return DeferredModexp(lambda: res) if defer else res
         if _shardable(padded):
             fn = _jitted_pow_sharded(digits, n_bits)
@@ -701,18 +723,18 @@ def power_mod_rns(
                 digits, n_bits,
                 donate=jax.default_backend() in ("tpu", "gpu"),
             )
-        dev = fn(*pow_args)  # jax dispatch is async: not a result yet
-        mods_live = list(mods)
+        with trace.leaf("flush.launch", op, items=t, bucket=padded):
+            dev = fn(*pow_args)  # jax dispatch is async: not a result yet
 
         def finish() -> list[int]:
             try:
-                s = np.asarray(dev)[:t]
+                with trace.leaf("flush.fetch", op, items=t):
+                    s = np.asarray(dev)[:t]
             finally:
                 # Materialized (or launch failed): the device no longer
                 # reads the staging arrays either way.
                 _release()
-            vals = _sigma_to_ints(ctx, s)
-            return [v % m for v, m in zip(vals, mods_live)]
+            return unpack(s)
 
         if defer:
             # Slot ownership moves to the handle: finish() releases it.
@@ -734,7 +756,11 @@ def digits_to_halves(digits_u32: np.ndarray) -> np.ndarray:
 
 def digits_to_halves_u8(digits_u32: np.ndarray) -> np.ndarray:
     """Same as :func:`digits_to_halves` but uint8 — 4x less wire for
-    host→device transfer; the kernel casts to f32 on device."""
+    host→device transfer; the kernel casts to f32 on device.  Operands
+    that are uint8 already were split by the caller (its staging phase)
+    and pass through."""
+    if digits_u32.dtype == np.uint8:
+        return digits_u32
     t = digits_u32.shape[0]
     out = np.empty((t, 2 * digits_u32.shape[1]), dtype=np.uint8)
     out[:, 0::2] = (digits_u32 & 0xFF).astype(np.uint8)
@@ -834,7 +860,7 @@ def _jitted_verify_gather_sharded():
     cn = _Consts(context())
     mesh = _mesh()
 
-    def body(sig_halves_u8, em_halves_u8, idx, ukey):
+    def rns_verify_gather_sharded(sig_halves_u8, em_halves_u8, idx, ukey):
         key = tuple(u[idx] for u in ukey)
         return _verify_kernel(
             cn,
@@ -846,7 +872,7 @@ def _jitted_verify_gather_sharded():
     b = P("batch")
     return jax.jit(
         _shard_map(
-            body, mesh,
+            rns_verify_gather_sharded, mesh,
             in_specs=(b, b, b, (P(),) * 6),
             out_specs=b,
         )
@@ -860,7 +886,7 @@ def _jitted_pow_sharded(digits: int, n_bits: int):
     cn = _Consts(context(digits, n_bits))
     mesh = _mesh()
 
-    def body(base_halves_u8, exp_nibbles_t_u8, idx, ukey):
+    def rns_pow_sharded(base_halves_u8, exp_nibbles_t_u8, idx, ukey):
         key = tuple(u[idx] for u in ukey)
         return _pow_kernel(
             cn,
@@ -869,10 +895,11 @@ def _jitted_pow_sharded(digits: int, n_bits: int):
             key,
         )
 
+    rns_pow_sharded.__name__ = f"rns_pow_{n_bits}_sharded"
     b = P("batch")
     return jax.jit(
         _shard_map(
-            body, mesh,
+            rns_pow_sharded, mesh,
             # exponent nibbles ride (W, T): batch is axis 1 there.
             in_specs=(b, P(None, "batch"), b, (P(),) * 6),
             out_specs=b,

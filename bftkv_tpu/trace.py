@@ -40,6 +40,19 @@ Span-name taxonomy and label-cardinality rules: docs/DESIGN.md §7.
 trace context rides the wire); ``BFTKV_SLOW_TRACE_SECONDS`` sets the
 slow threshold (default 1.0).
 
+**The bridge to a device profiler.**  A process that holds a device
+(the crypto sidecar) calls :func:`set_bridge` with a factory of
+``jax.profiler.TraceAnnotation``; from then on every span named in
+:data:`BRIDGED` is also entered and left inside the profiler's own
+clock — the clock of the device's ``XLA Ops`` line — so a capture
+names what the host did in each gap between kernels.  Only leaf
+phases are bridged: the reduction names a gap after the host event
+that covers most of it, so a wrapper (``<pool>.flush``) or a parked
+thread (``dispatch.wait``) would name every gap after itself.  This
+module imports no profiler; with no bridge installed a span pays one
+``is None`` test.  :class:`leaf` is the call-site form: span, bridge
+and one observation of the histogram of the same name.
+
 **Phases (DESIGN.md §18).**  Every span name resolves to exactly one
 member of the CLOSED :data:`PHASES` enum via :data:`SPAN_PHASES` — the
 vocabulary the critical-path attribution plane
@@ -63,6 +76,7 @@ import time
 from collections import deque
 from bftkv_tpu import flags
 from bftkv_tpu.devtools.lockwatch import named_lock
+from bftkv_tpu.metrics import registry as _metrics
 
 __all__ = [
     "PHASES",
@@ -70,10 +84,15 @@ __all__ = [
     "Span",
     "SpanContext",
     "Tracer",
+    "BRIDGED",
+    "annotate",
     "attach",
+    "bridged",
     "capture",
+    "leaf",
     "new_id",
     "phase_of",
+    "set_bridge",
     "span",
     "tracer",
 ]
@@ -134,6 +153,16 @@ SPAN_PHASES: dict[str, str] = {
     "sign.flush": "dispatch",
     "modexp.flush": "dispatch",
     "sidecar.call": "sidecar",
+    # the phases of one device launch and the two ends of a sidecar
+    # request: leaves, bridged to the profiler (BRIDGED below)
+    "dispatch.linger": "dispatch",
+    "flush.stage": "dispatch",
+    "flush.launch": "dispatch",
+    "flush.fetch": "dispatch",
+    "flush.unpack": "dispatch",
+    "flush.scatter": "dispatch",
+    "sidecar.decode": "sidecar",
+    "sidecar.reply": "sidecar",
     # async tails + repair/anti-entropy planes
     "backfill.": "backfill",
     "sync.repair.backfill": "backfill",
@@ -169,6 +198,54 @@ def phase_of(name: str) -> str:
                 p = "other"
         _phase_memo[name] = p
     return p
+
+#: The spans that also land on the device profiler's host plane when a
+#: bridge is installed (see the module docstring).  CLOSED: leaf phases
+#: only, and ``sidecar.empty``, the one waiting interval allowed because
+#: it is a state of the whole process and not of a thread
+#: (:func:`annotate`; it is no ring span).
+BRIDGED = frozenset({
+    "dispatch.linger",
+    "flush.stage",
+    "flush.launch",
+    "flush.fetch",
+    "flush.unpack",
+    "flush.scatter",
+    "sidecar.decode",
+    "sidecar.reply",
+    "sidecar.empty",
+})
+
+#: ``factory(name, **attrs)`` -> context manager, or None (every process
+#: without a device).  Module-level on purpose: the profiler is one per
+#: process, like the tracer.
+_bridge = None
+
+
+def set_bridge(factory) -> None:
+    """Install (or, with None, remove) the profiler bridge."""
+    global _bridge
+    _bridge = factory
+
+
+def bridged() -> bool:
+    """Whether this process's leaf spans reach a profiler."""
+    return _bridge is not None
+
+
+def annotate(name: str, **attrs):
+    """The bridge's annotation for ``name``, already entered — for an
+    interval that starts on one thread and ends on another, which a
+    :class:`span` (thread-local stack) cannot be.  The caller leaves it
+    with ``__exit__(None, None, None)``.  None without a bridge, with
+    tracing off, or for a name outside :data:`BRIDGED`."""
+    bridge = _bridge
+    if bridge is None or not tracer.enabled or name not in BRIDGED:
+        return None
+    ann = bridge(name, **attrs)
+    ann.__enter__()
+    return ann
+
 
 slow_log = logging.getLogger("bftkv_tpu.trace.slow")
 
@@ -299,7 +376,7 @@ class span:
     ``attrs["error"]`` (interned error message when available) and
     still propagates."""
 
-    __slots__ = ("name", "attrs", "phase", "_sp")
+    __slots__ = ("name", "attrs", "phase", "_sp", "_ann")
 
     def __init__(self, name: str, attrs: dict | None = None,
                  phase: str | None = None):
@@ -308,9 +385,14 @@ class span:
         self.phase = phase
 
     def __enter__(self) -> Span:
+        self._ann = None
         if not tracer.enabled:
             self._sp = None
             return _NULL_SPAN
+        bridge = _bridge
+        if bridge is not None and self.name in BRIDGED:
+            self._ann = bridge(self.name, **(self.attrs or {}))
+            self._ann.__enter__()
         st = _stack()
         if st:
             parent = st[-1]
@@ -332,12 +414,44 @@ class span:
         sp = self._sp
         if sp is None:
             return False
+        if self._ann is not None:
+            self._ann.__exit__(etype, exc, tb)
         _stack().pop()
         sp.duration = time.perf_counter() - sp._t0
         if etype is not None:
             msg = getattr(exc, "message", None)
             sp.attrs["error"] = msg if isinstance(msg, str) else repr(exc)
         tracer.record(sp)
+        return False
+
+
+class leaf:
+    """One leaf phase of a device launch or of a sidecar request: a
+    :class:`span` (bridged to the profiler where a bridge is installed)
+    and one observation, in seconds, of the histogram of the same name
+    with ``op`` its only label.  The histogram does not depend on
+    ``BFTKV_TRACE``: an untraced run prints the same split.  ``attrs``
+    (items, padded bucket) ride the span only.  Per launch or per
+    request, never per item."""
+
+    __slots__ = ("_span", "_op", "_t0")
+
+    def __init__(self, name: str, op: str, **attrs):
+        attrs["op"] = op
+        self._span = span(name, attrs)
+        self._op = op
+
+    def __enter__(self) -> Span:
+        self._t0 = time.perf_counter()
+        return self._span.__enter__()
+
+    def __exit__(self, etype, exc, tb) -> bool:
+        self._span.__exit__(etype, exc, tb)
+        _metrics.observe(
+            self._span.name,
+            time.perf_counter() - self._t0,
+            labels={"op": self._op},
+        )
         return False
 
 
@@ -500,8 +614,6 @@ class Tracer:
         # Gauges refresh on every drain (the record hot path never pays
         # a metrics lock): each collector scrape — and any /trace hit —
         # keeps /metrics at most one drain stale.
-        from bftkv_tpu.metrics import registry as _metrics
-
         _metrics.gauge("trace.ring.dropped", ring_dropped)
         _metrics.gauge("trace.slow.dropped", slow_dropped)
         return {

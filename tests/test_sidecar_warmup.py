@@ -99,6 +99,42 @@ def test_warmup_covers_every_launchable_bucket(
     assert cache["dir"] and cache["warm"] is False  # nothing was loaded
 
 
+def test_warmup_splits_each_shape_by_what_jax_reported(monkeypatch):
+    """trace / lower / load / compile come from ``jax.monitoring``'s
+    duration events while the shape ran, run from the time its flushes
+    blocked on the device; a kind with no event reads 0."""
+    import jax
+
+    svc = _service(max_batch=64)
+    inner = svc.verify.submit
+    events = {
+        "/jax/core/compile/jaxpr_trace_duration": 0.25,
+        "/jax/core/compile/jaxpr_to_mlir_module_duration": 0.5,
+        "/jax/core/compile/backend_compile_duration": 2.0,
+        "/jax/compilation_cache/cache_retrieval_time_sec": 1.5,
+        "/jax/some/other/duration": 64.0,
+    }
+
+    def submit(items):
+        for name, seconds in events.items():
+            jax.monitoring.record_event_duration_secs(name, seconds)
+        metrics.observe("flush.fetch", 0.125, labels={"op": "verify"})
+        return inner(items)
+
+    svc.verify.submit = submit
+    try:
+        shapes = svc._warm()["shapes"]
+    finally:
+        jax.monitoring.unregister_event_listener(svc._on_jax_event)
+        jax.monitoring.unregister_event_duration_listener(svc._on_jax_duration)
+        vs.trace.set_bridge(None)
+    split = ("trace_s", "lower_s", "load_s", "compile_s", "run_s")
+    by_role = {s["role"]: [s[k] for k in split] for s in shapes}
+    assert by_role["verify"] == [0.25, 0.5, 1.5, 0.5, 0.125]
+    assert by_role["sign"] == by_role["modexp"] == [0.0] * 5
+    assert all(s["seconds"] >= 0 for s in shapes)
+
+
 def test_wrong_result_in_warmup_refuses_to_start():
     svc = _service(max_batch=64, verify_fn=lambda m, s, k: True)
     with pytest.raises(RuntimeError, match="wrong verdicts"):
