@@ -354,12 +354,10 @@ class RemoteVerifierDomain:
                     self.local.verify_batch([items[i] for i in ec_idx]),
                     dtype=bool,
                 )
-        for i in local_idx:
-            try:
-                msg, sig, key = items[i]
-                out_all[i] = rsa.verify_host(msg, sig, key)
-            except Exception:
-                out_all[i] = False
+        if local_idx:
+            out_all[np.asarray(local_idx)] = rsa.verify_host_many(
+                [items[i] for i in local_idx]
+            )
         if not wire_items:
             return out_all
         got = self._verify_remote(wire_items)
@@ -378,11 +376,7 @@ class RemoteVerifierDomain:
         if self.spot_rate <= 0 or self._rng.random() >= self.spot_rate:
             return got
         i = self._rng.randrange(len(items))
-        msg, sig, key = items[i]
-        try:
-            want = rsa.verify_host(msg, sig, key)
-        except Exception:
-            want = False
+        want = rsa.verify_host_many([items[i]])[0]
         metrics.incr("verify.spot_check")
         if bool(got[i]) == want:
             return got
@@ -472,28 +466,28 @@ class RemoteSignerDomain:
             if sigs is None:
                 metrics.incr("sign.remote_fallback", len(witems))
         if sigs is None:
-            sigs = [rsa.sign(msg, key) for msg, key in witems]
+            sigs = rsa.sign_many(witems)
             metrics.incr("sign.host", len(witems))
         for i, sig in zip(wire_idx, sigs):
             out[i] = sig
         return out
 
     def _self_check(self, witems: list, sigs: list) -> list | None:
-        for (msg, key), sig in zip(witems, sigs):
-            ok = False
-            try:
-                ok = bool(sig) and rsa.verify_host(msg, sig, key.public)
-            except Exception:
-                ok = False
-            if not ok:
-                # A forged/faulted signature: the service is dishonest
-                # or broken either way — bench it and re-sign the whole
-                # batch locally (deterministic PKCS#1 v1.5: the local
-                # signature is THE signature).
-                metrics.incr("crypto.sidecar.dishonest")
-                self.channel.trip()
-                return None
-        return sigs
+        """Every signature the service returned, verified on the host
+        tier's batch form before one is released."""
+        if all(sigs) and all(
+            rsa.verify_host_many(
+                [(msg, sig, key.public) for (msg, key), sig in zip(witems, sigs)]
+            )
+        ):
+            return sigs
+        # A forged/faulted signature: the service is dishonest or
+        # broken either way — bench it and re-sign the whole batch
+        # locally (deterministic PKCS#1 v1.5: the local signature is
+        # THE signature).
+        metrics.incr("crypto.sidecar.dishonest")
+        self.channel.trip()
+        return None
 
     def _sign_remote(self, witems: list) -> list | None:
         with self._lock:

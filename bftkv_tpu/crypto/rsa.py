@@ -12,6 +12,8 @@ from __future__ import annotations
 import contextlib
 import hashlib
 import logging
+import os
+import time
 from collections import OrderedDict
 from dataclasses import dataclass
 
@@ -62,17 +64,20 @@ class PrivateKey:
         return (self.n.bit_length() + 7) // 8
 
     def crt_params(self) -> tuple:
-        """Cached CRT + Montgomery material for the native modexp:
-        ``(dp, dq, qinv, (p_bytes, r2p, n0p, Lp), (q_bytes, r2q, n0q,
-        Lq))`` — one-time per key, consumed by :func:`_crt_powmod`."""
+        """Cached CRT + Montgomery material: ``(dp, dq, qinv, pp, qp)``
+        — one-time per key, consumed by :func:`_crt_pow_many`.  ``pp``
+        and ``qp`` are :func:`_mont_params` of the primes, or None for
+        a key the native modexp cannot take (an even or oversized
+        "prime": ``pow`` then does the work)."""
         cached = self.__dict__.get("_crt")
         if cached is None:
+            native = _native_ok(self.p) and _native_ok(self.q)
             cached = (
                 self.d % (self.p - 1),
                 self.d % (self.q - 1),
                 pow(self.q, -1, self.p),
-                _mont_params(self.p),
-                _mont_params(self.q),
+                _mont_params(self.p) if native else None,
+                _mont_params(self.q) if native else None,
             )
             self.__dict__["_crt"] = cached
         return cached
@@ -248,20 +253,21 @@ def emsa_pkcs1v15_sha256(message: bytes, em_len: int) -> int:
 # native/montmodexp.c is the same math as fixed-width CIOS Montgomery
 # with a 4-bit window (~5x) and releases the GIL.  pow() stays as the
 # fallback AND the semantics oracle (differential tests in
-# tests/test_rsa.py).  Disable with BFTKV_NATIVE_MODEXP=off.
+# tests/test_rsa.py, tests/test_host_batch.py).  Disable with
+# BFTKV_NATIVE_MODEXP=off.
+
+_NATIVE_DIR = os.path.abspath(
+    os.path.join(os.path.dirname(__file__), "..", "..", "native")
+)
 
 
-def _load_native_modexp():
+def _load_native_modexp(nd: str = _NATIVE_DIR):
     import importlib.util
-    import os
     import subprocess
     import sysconfig
 
     if flags.raw("BFTKV_NATIVE_MODEXP", "auto") == "off":
         return None
-    nd = os.path.abspath(
-        os.path.join(os.path.dirname(__file__), "..", "..", "native")
-    )
     try:
         import fcntl
 
@@ -275,12 +281,12 @@ def _load_native_modexp():
         # a lifetime of slow pure-pow signing).
         with open(os.path.join(nd, ".mont.lock"), "w") as lk:
             fcntl.flock(lk, fcntl.LOCK_EX)
-            if not os.path.exists(so_path) or (
-                os.path.getmtime(so_path) < os.path.getmtime(src)
-            ):
+            if _stale_native(so_path, src):
+                # -B: make's own mtime rule would keep a .so that is
+                # newer than the source and still lacks the batch entry
                 subprocess.run(
                     [
-                        "make", "-s", "mont",
+                        "make", "-s", "-B", "mont",
                         f"PY_INC={inc}", f"EXT_SUFFIX={suffix}",
                     ],
                     cwd=nd, check=True, capture_output=True,
@@ -292,85 +298,305 @@ def _load_native_modexp():
             spec.loader.exec_module(mod)
         # Self-check against the oracle before trusting it for real
         # signatures: a miscompiled extension must fall back, not
-        # corrupt the crypto plane.
-        b, e_, m_ = 0xABCDEF123456789, 65537, (1 << 127) - 1
-        width = (m_.bit_length() + 63) // 64 * 8
-        r2 = pow(2, 2 * 8 * width, m_)
-        n0 = (-pow(m_, -1, 1 << 64)) & ((1 << 64) - 1)
-        got = int.from_bytes(
-            mod.powmod(
-                b.to_bytes(width, "big"),
-                e_.to_bytes(3, "big"),
-                m_.to_bytes(width, "big"),
-                r2.to_bytes(width, "big"),
-                n0,
-            ),
-            "big",
+        # corrupt the crypto plane.  Both of the scan's forms: the
+        # bit-by-bit one a public exponent takes and the windowed one.
+        m_ = (1 << 127) - 1
+        key_row, width = _mont_params(m_)
+        rows = [(0xABCDEF123456789, F4), (m_ - 2, m_ - 2), (1, 0)]
+        got = mod.powmod_many(
+            width,
+            width,
+            b"".join(x.to_bytes(width, "big") for x, _ in rows),
+            b"".join(y.to_bytes(width, "big") for _, y in rows),
+            key_row * len(rows),
         )
-        if got != pow(b, e_, m_):
+        if got != b"".join(
+            pow(x, y, m_).to_bytes(width, "big") for x, y in rows
+        ):
             return None
         return mod
     except Exception:
         return None
 
 
-_MM = _load_native_modexp()
+def _stale_native(so_path: str, src: str) -> bool:
+    """Rebuild where the extension is missing, older than its source,
+    or built from a source that had no batch entry (a ``.so`` carried
+    over from an older tree): decided from the file, before anything
+    is loaded — an extension module cannot be loaded twice."""
+    if not os.path.exists(so_path) or (
+        os.path.getmtime(so_path) < os.path.getmtime(src)
+    ):
+        return True
+    with open(so_path, "rb") as f:
+        return b"powmod_many" not in f.read()
 
 
 def _mont_params(mod: int) -> tuple:
-    """``(mod_bytes, r2_bytes, n0inv, width)`` for one odd modulus."""
+    """``(key_row, width)`` for one odd modulus: the row is ``mod ||
+    r2 || n0inv`` (``width`` + ``width`` + 8 bytes, big-endian), the
+    form ``powmod_many`` takes one of per row."""
     width = (mod.bit_length() + 63) // 64 * 8
     r2 = pow(2, 2 * 8 * width, mod)
     n0 = (-pow(mod, -1, 1 << 64)) & ((1 << 64) - 1)
     return (
-        mod.to_bytes(width, "big"),
-        r2.to_bytes(width, "big"),
-        n0,
+        mod.to_bytes(width, "big")
+        + r2.to_bytes(width, "big")
+        + n0.to_bytes(8, "big"),
         width,
     )
 
 
-def _native_powmod(base: int, exp: int, params: tuple) -> int:
-    mod_b, r2_b, n0, width = params
-    return int.from_bytes(
-        _MM.powmod(
-            base.to_bytes(width, "big"),
-            exp.to_bytes(max(1, (exp.bit_length() + 7) // 8), "big"),
-            mod_b,
-            r2_b,
-            n0,
-        ),
-        "big",
+_MM = _load_native_modexp()
+
+#: Widest modulus the extension takes (native/montmodexp.c MAX_LIMBS).
+_NATIVE_MAX_BITS = 4096
+
+
+# -- the batched host tier ---------------------------------------------------
+# Every RSA operation this process does on the host — the client's own
+# signs and its check of every share, a daemon's self-check of what the
+# sidecar signed, the local fallback after a shed — is rows of
+# ``base ^ exp mod m``.  They cross into C once per chunk, not once per
+# item (the int<->bytes hop of a per-item call holds the GIL), and a
+# batch long enough to share is spread over one process-wide pool as
+# wide as the cores the process may use.  A batch that makes a single
+# chunk runs on the caller's thread: single operations never hop.
+
+#: Rows below which a chunk is not worth a hop to the pool, by the
+#: length of the exponent: ~2-5 ms of modexp either way (a public
+#: exponent costs ~50 us a row at 2048 bits, a CRT half ~0.5 ms).
+_CHUNK_ROWS_SHORT_EXP = 64
+_CHUNK_ROWS_LONG_EXP = 8
+
+_pool = None
+_pool_lock = named_lock("crypto.rsa.host_pool")
+
+
+def _pool_width() -> int:
+    try:
+        return max(1, len(os.sched_getaffinity(0)))
+    except AttributeError:  # not on Linux
+        return max(1, os.cpu_count() or 1)
+
+
+def _host_pool():
+    global _pool
+    with _pool_lock:
+        if _pool is None:
+            from concurrent.futures import ThreadPoolExecutor
+
+            _pool = ThreadPoolExecutor(
+                max_workers=_pool_width(),
+                thread_name_prefix="bftkv-hostrsa",
+            )
+        return _pool
+
+
+def _powmod_chunk(width: int, rows: list) -> bytes:
+    """One native call: rows of one width, ``(base, exp, (key_row, width))``."""
+    ewidth = max(1, max((e.bit_length() + 7) // 8 for _b, e, _p in rows))
+    return _MM.powmod_many(
+        width,
+        ewidth,
+        b"".join([b.to_bytes(width, "big") for b, _e, _p in rows]),
+        b"".join([e.to_bytes(ewidth, "big") for _b, e, _p in rows]),
+        b"".join([p[0] for _b, _e, p in rows]),
     )
+
+
+def _powmod_rows(rows: list) -> list[int]:
+    """``[(base, exp, (key_row, width))]`` → ``[base^exp mod m]`` through
+    the native batch entry; ``0 <= base < m`` and ``exp >= 0`` are the
+    caller's.  Rows are grouped by width (one call takes one width),
+    cut into chunks by the batch's length and, where that makes more
+    than one, spread over the pool; the caller's thread takes a chunk
+    itself."""
+    out: list = [None] * len(rows)
+    by_width: dict[int, list[int]] = {}
+    for i, (_b, _e, (_k, width)) in enumerate(rows):
+        by_width.setdefault(width, []).append(i)
+    jobs: list[tuple[int, list[int]]] = []
+    for width, idx in by_width.items():
+        short = all(rows[i][1] < (1 << 32) for i in idx)
+        per = _CHUNK_ROWS_SHORT_EXP if short else _CHUNK_ROWS_LONG_EXP
+        # up to two chunks a core: the cores are shared with other
+        # processes, and the slowest chunk sets the batch's time
+        n = max(1, min(2 * _pool_width(), len(idx) // per))
+        step = -(-len(idx) // n)
+        jobs += [(width, idx[o : o + step]) for o in range(0, len(idx), step)]
+
+    def run(job):
+        width, idx = job
+        return _powmod_chunk(width, [rows[i] for i in idx])
+
+    futures = [_host_pool().submit(run, job) for job in jobs[1:]]
+    results = [run(jobs[0])] if jobs else []
+    results += [f.result() for f in futures]
+    for (width, idx), res in zip(jobs, results):
+        for j, i in enumerate(idx):
+            out[i] = int.from_bytes(res[j * width : (j + 1) * width], "big")
+    return out
+
+
+def _native_ok(mod: int) -> bool:
+    return mod & 1 == 1 and 2 < mod and mod.bit_length() <= _NATIVE_MAX_BITS
+
+
+def _native_powmod(base: int, exp: int, params: tuple) -> int:
+    return _powmod_rows([(base, exp, params)])[0]
+
+
+#: modulus → ``_mont_params`` for PUBLIC moduli (a certificate builds a
+#: fresh ``PublicKey`` on every access).  LRU-bounded: moduli arrive in
+#: attacker-embedded certificates.  Private keys cache theirs on the
+#: key object (``PrivateKey.crt_params``).
+_PUB_PARAMS: "OrderedDict[int, tuple]" = OrderedDict()
+_PUB_PARAMS_MAX = 4096
+_pub_params_lock = named_lock("crypto.rsa.pub_params")
+
+
+def _pub_params(n: int) -> tuple:
+    with _pub_params_lock:
+        p = _PUB_PARAMS.get(n)
+        if p is not None:
+            _PUB_PARAMS.move_to_end(n)
+            return p
+    p = _mont_params(n)
+    with _pub_params_lock:
+        _PUB_PARAMS[n] = p
+        if len(_PUB_PARAMS) > _PUB_PARAMS_MAX:
+            _PUB_PARAMS.popitem(last=False)
+    return p
+
+
+def _count_host_batch(op: str, native: int, python: int, t0: float) -> None:
+    if native:
+        metrics.incr("host.batch.native", native, labels={"op": op})
+    if python:
+        metrics.incr("host.batch.python", python, labels={"op": op})
+    if native + python > 1:
+        metrics.observe(
+            "host.batch.seconds", time.perf_counter() - t0, labels={"op": op}
+        )
+
+
+def _crt_pow_many(pairs: list, op: str) -> list[int]:
+    """``[(c, key)]`` → ``[c^d mod n]`` via CRT: both halves of every
+    item are rows of one native batch; a key the extension cannot take
+    (or no extension) goes through ``pow``."""
+    t0 = time.perf_counter()
+    out: list = [None] * len(pairs)
+    rows: list = []
+    native: list[tuple[int, int]] = []  # (item index, qinv)
+    for i, (c, key) in enumerate(pairs):
+        dp, dq, qinv, pp, qp = key.crt_params()
+        if pp is not None and _MM is not None:
+            rows += [(c % key.p, dp, pp), (c % key.q, dq, qp)]
+            native.append((i, qinv))
+        else:
+            m1, m2 = pow(c, dp, key.p), pow(c, dq, key.q)
+            out[i] = m2 + (qinv * (m1 - m2)) % key.p * key.q
+    vals = _powmod_rows(rows)
+    for j, (i, qinv) in enumerate(native):
+        key = pairs[i][1]
+        m1, m2 = vals[2 * j], vals[2 * j + 1]
+        out[i] = m2 + (qinv * (m1 - m2)) % key.p * key.q
+    _count_host_batch(op, len(native), len(pairs) - len(native), t0)
+    return out
 
 
 def crt_pow_d(c: int, key: PrivateKey) -> int:
     """``c^d mod n`` via CRT — the shared private-key primitive behind
     signing and OAEP unwrap, native-accelerated when the Montgomery
     extension is built."""
-    dp, dq, qinv, pp, qp = key.crt_params()
-    if _MM is not None:
-        m1 = _native_powmod(c % key.p, dp, pp)
-        m2 = _native_powmod(c % key.q, dq, qp)
-    else:
-        m1 = pow(c, dp, key.p)
-        m2 = pow(c, dq, key.q)
-    h = (qinv * (m1 - m2)) % key.p
-    return m2 + h * key.q
+    return _crt_pow_many([(c, key)], "unwrap")[0]
+
+
+def sign_many(items: list[tuple[bytes, PrivateKey]]) -> list[bytes]:
+    """``[(message, key)]`` → PKCS#1 v1.5 signatures over
+    SHA-256(message), CRT-accelerated: the host tier's batch form.
+    Deterministic, so the bytes are those of any other correct signer."""
+    pairs = [
+        (emsa_pkcs1v15_sha256(message, key.size_bytes), key)
+        for message, key in items
+    ]
+    return [
+        s.to_bytes(key.size_bytes, "big")
+        for s, (_m, key) in zip(_crt_pow_many(pairs, "sign"), pairs)
+    ]
 
 
 def sign(message: bytes, key: PrivateKey) -> bytes:
-    """PKCS#1 v1.5 signature over SHA-256(message), CRT-accelerated."""
-    m = emsa_pkcs1v15_sha256(message, key.size_bytes)
-    return crt_pow_d(m, key).to_bytes(key.size_bytes, "big")
+    """One PKCS#1 v1.5 signature: :func:`sign_many` of one item."""
+    return sign_many([(message, key)])[0]
 
 
-def verify_host(message: bytes, sig: bytes, key: PublicKey) -> bool:
-    """Host oracle verify (used off the hot path and in tests)."""
+def _verify_oracle(message: bytes, sig: bytes, key: PublicKey) -> bool:
+    """Python ``pow``: the semantics every other verify tier is held to."""
     s = int.from_bytes(sig, "big")
     if s >= key.n:
         return False
     return pow(s, key.e, key.n) == emsa_pkcs1v15_sha256(message, key.size_bytes)
+
+
+def _verify_rows(items: list, strict: bool) -> list[bool]:
+    t0 = time.perf_counter()
+    out = [False] * len(items)
+    rows: list = []
+    want: list[tuple[int, int]] = []  # (item index, em)
+    params: dict[int, tuple] = {}  # a batch repeats a handful of keys
+    python = 0
+    for i, (message, sig, key) in enumerate(items):
+        # e = 65537 on a sound modulus goes native; an odd exponent or
+        # a junk key keeps the oracle's verdict, failing closed.
+        if (
+            _MM is not None
+            and key.e == F4
+            and key.n.bit_length() >= 512
+            and _native_ok(key.n)
+        ):
+            s = int.from_bytes(sig, "big")
+            if s < key.n:
+                p = params.get(key.n)
+                if p is None:
+                    p = params[key.n] = _pub_params(key.n)
+                rows.append((s, F4, p))
+                want.append(
+                    (i, emsa_pkcs1v15_sha256(message, key.size_bytes))
+                )
+            continue
+        python += 1
+        try:
+            out[i] = _verify_oracle(message, sig, key)
+        except Exception:
+            if strict:
+                raise
+    for (i, em), got in zip(want, _powmod_rows(rows)):
+        out[i] = got == em
+    _count_host_batch("verify", len(items) - python, python, t0)
+    return out
+
+
+def verify_host_many(
+    items: list[tuple[bytes, bytes, PublicKey]]
+) -> list[bool]:
+    """``[(message, sig, key)]`` → verdicts: the host tier's batch form.
+    Never raises for a key or a signature: junk fails closed."""
+    if verify_host is not _verify_host:
+        # The one-item form was replaced (a fault plant, a test
+        # double): the batch form answers as it would.
+        return [bool(verify_host(m, s, k)) for m, s, k in items]
+    return _verify_rows(items, strict=False)
+
+
+def verify_host(message: bytes, sig: bytes, key: PublicKey) -> bool:
+    """Host verify of one item: :func:`verify_host_many` of one, except
+    that a key no encoding fits raises as the oracle does."""
+    return _verify_rows([(message, sig, key)], strict=True)[0]
+
+
+_verify_host = verify_host
 
 
 class SignerDomain:
@@ -612,8 +838,11 @@ class SignerDomain:
                     by_width.setdefault(w, []).append(
                         (i, key, m, domp, domq, dp, dq, qinv)
                     )
-        for i in host_idx:
-            out[i] = sign(items[i][0], items[i][1])
+        if host_idx:
+            for i, sig in zip(
+                host_idx, sign_many([items[i] for i in host_idx])
+            ):
+                out[i] = sig
         from bftkv_tpu.ops import rsa as rsa_ops
 
         for w, group in by_width.items():
@@ -783,12 +1012,17 @@ class VerifierDomain:
             # lose to host ``pow`` at every batch size (the verdict
             # dispatch.calibration() reaches): 768 collective-signature
             # verifies cost ~14 s through CPU-XLA against ~0.2 s here.
-            self._builtin_threshold = False
             import jax
 
-            if jax.default_backend() == "cpu":
+            # Decide first, mark decided last: the first call here
+            # initialises the backend (seconds), and a second caller
+            # arriving meanwhile must wait for the same verdict, not
+            # find the mark already set and launch through CPU-XLA.
+            on_cpu = jax.default_backend() == "cpu"
+            if on_cpu:
                 self._host_threshold = 1 << 30
-                return True
+            self._builtin_threshold = False
+            return on_cpu
         return False
 
     def assemble(
@@ -829,6 +1063,7 @@ class VerifierDomain:
         device_items: list[tuple[bytes, bytes, PublicKey]] = []
         ec_idx: list[int] = []
         ec_items: list = []
+        odd_idx: list[int] = []
         # The per-item tier split is the first interval of the launch's
         # flush.stage where the batch is bound for the RNS chain (the
         # second, in _verify_rns, builds the operands).
@@ -857,12 +1092,7 @@ class VerifierDomain:
                 else:
                     # Host oracle for odd exponents; fails closed on
                     # junk keys.
-                    try:
-                        out[i] = key.n > 0 and verify_host(
-                            message, sig_bytes, key
-                        )
-                    except Exception:
-                        out[i] = False
+                    odd_idx.append(i)
         if ec_items:
             from bftkv_tpu.crypto import ecdsa as _ecdsa
 
@@ -870,10 +1100,13 @@ class VerifierDomain:
             out[np.asarray(ec_idx)] = np.asarray(
                 _ecdsa.verify_batch(ec_items), dtype=bool
             )
+        if odd_idx:
+            out[np.asarray(odd_idx)] = verify_host_many(
+                [items[i] for i in odd_idx]
+            )
         if device_items and self._stay_on_host(len(device_items)):
             metrics.incr("verify.host", len(device_items))
-            for j, (message, sig_bytes, key) in zip(device_idx, device_items):
-                out[j] = verify_host(message, sig_bytes, key)
+            out[np.asarray(device_idx)] = verify_host_many(device_items)
         elif device_items and self.backend == "rns":
             self._verify_rns(device_idx, device_items, out)
         elif device_items:
@@ -929,6 +1162,8 @@ class VerifierDomain:
         unique: dict[int, int] = {}
         urows: list = []
         idxs, digit_rows, em_rows, keep_idx = [], [], [], []
+        host_idx: list[int] = []
+        host_items: list = []
         with trace.leaf(
             "flush.stage", "verify", items=len(device_items)
         ) as sp:
@@ -939,12 +1174,8 @@ class VerifierDomain:
                     # Hostile modulus (or oversized sig): host oracle,
                     # failing closed on junk.
                     metrics.incr("verify.host")
-                    try:
-                        out[j] = s < key.n and verify_host(
-                            message, sig_bytes, key
-                        )
-                    except Exception:
-                        out[j] = False
+                    host_idx.append(j)
+                    host_items.append((message, sig_bytes, key))
                     continue
                 u = unique.get(key.n)
                 if u is None:
@@ -958,6 +1189,8 @@ class VerifierDomain:
                     )
                 )
                 keep_idx.append(j)
+            if host_items:
+                out[np.asarray(host_idx)] = verify_host_many(host_items)
             if not idxs:
                 return
             k = len(idxs)

@@ -86,9 +86,9 @@ class Signer:
 
         When a cross-request sign dispatcher is installed, concurrent
         handlers' share issuance batches into shared CRT-modexp
-        launches and stops serializing on the GIL (host ``pow`` does
-        not release it); without one, signing falls back to host.
-        ``issue`` is the one-item form."""
+        launches; without one, signing is the host tier's batch form
+        (``rsa.sign_many``: native, off the GIL, spread over the
+        process's cores).  ``issue`` is the one-item form."""
         from bftkv_tpu.ops import dispatch
 
         # Both algorithms ride the dispatcher when one is installed —
@@ -105,14 +105,13 @@ class Signer:
             from bftkv_tpu.crypto import ecdsa as _ecdsa
 
             sigs = [_ecdsa.sign(tbs, self.key) for tbs in tbs_list]
-        elif d is not None:
-            # Calibration says these items end on host either way
-            # (ops/dispatch.py install-time crossover): sign inline and
-            # skip the collector wait + flush queue entirely.
-            metrics.incr("sign.host", len(tbs_list))
-            sigs = [rsa.sign(tbs, self.key) for tbs in tbs_list]
         else:
-            sigs = [rsa.sign(tbs, self.key) for tbs in tbs_list]
+            if d is not None:
+                # Calibration says these items end on host either way
+                # (ops/dispatch.py install-time crossover): sign inline
+                # and skip the collector wait + flush queue entirely.
+                metrics.incr("sign.host", len(tbs_list))
+            sigs = rsa.sign_many([(tbs, self.key) for tbs in tbs_list])
         # Seed the verify memo: a signature this process just produced
         # with its own key verifies under its own certificate by the
         # scheme's correctness (crypto/vcache.py).

@@ -11,12 +11,15 @@
  * disables; the pure pow() path remains the semantics oracle, pinned
  * by differential tests in tests/test_rsa.py).
  *
- * API:  powmod(base, exp, mod, r2, n0inv) -> bytes
- *   base, mod, r2: big-endian byte strings, len(mod) a multiple of 8;
- *   base < mod;  r2 = 2^(2*64*nlimbs) mod mod (caller precomputes,
- *   cached per key);  n0inv = -mod^-1 mod 2^64.
- *   exp: big-endian byte string, any length > 0.
- * Returns the big-endian result, len(mod) bytes.
+ * API:  powmod_many(width, ewidth, bases, exps, keys) -> bytes
+ *   N rows, each with its own modulus, all big-endian: bases is
+ *   N*width bytes (width a multiple of 8, base < mod), exps N*ewidth,
+ *   keys N*(2*width+8) — per row mod || r2 || n0inv, with
+ *   r2 = 2^(2*64*nlimbs) mod mod and n0inv = -mod^-1 mod 2^64 (the
+ *   caller precomputes them, cached per key).  Returns N*width bytes.
+ *   The GIL is released once for the whole call: the host tier's batch
+ *   entry (crypto/rsa.py sign_many / verify_host_many; one row is the
+ *   one-item form).
  */
 
 #define PY_SSIZE_T_CLEAN
@@ -98,99 +101,145 @@ static void mont_mul(const u64 *a, const u64 *b, const u64 *n, u64 n0inv,
     if (t[L] || geq(t, n, L)) sub_n(t, n, L);
 }
 
-static PyObject *py_powmod(PyObject *self, PyObject *args) {
-    Py_buffer base_b, exp_b, mod_b, r2_b;
-    unsigned long long n0inv;
-    if (!PyArg_ParseTuple(args, "y*y*y*y*K", &base_b, &exp_b, &mod_b,
-                          &r2_b, &n0inv))
-        return NULL;
+/* x^e mod n for one row, limbs in and out; e is big-endian bytes.
+ * Touches no Python object: it runs with the GIL released.  Leading zeros of e cost nothing (rows of one batch pad
+ * their exponents to a common length). */
+static void powmod_core(const u64 *x, const unsigned char *e,
+                        Py_ssize_t elen, const u64 *n, const u64 *r2,
+                        u64 n0inv, int L, u64 *acc /* L limbs out */) {
+    u64 table[16][MAX_LIMBS];
+    u64 one[MAX_LIMBS], t[MAX_LIMBS + 2];
+    size_t bytes = (size_t)L * 8;
 
-    PyObject *ret = NULL;
-    int L = (int)(mod_b.len / 8);
-    if (mod_b.len % 8 != 0 || L <= 0 || L > MAX_LIMBS ||
-        base_b.len > mod_b.len || r2_b.len > mod_b.len || exp_b.len == 0) {
-        PyErr_SetString(PyExc_ValueError, "montmodexp: bad operand shape");
-        goto done;
-    }
+    while (elen > 0 && e[0] == 0) { e++; elen--; }
+    memset(one, 0, bytes);
+    one[0] = 1;
+    /* table[1] = x in Montgomery form; table[0] = 1 in Mont form */
+    mont_mul(x, r2, n, n0inv, L, t);
+    memcpy(table[1], t, bytes);
+    mont_mul(one, r2, n, n0inv, L, t);
+    memcpy(table[0], t, bytes);
+    memcpy(acc, table[0], bytes);
 
-    {
-        u64 n[MAX_LIMBS], x[MAX_LIMBS], r2[MAX_LIMBS];
-        u64 table[16][MAX_LIMBS];
-        u64 acc[MAX_LIMBS], t[MAX_LIMBS + 2];
-        unsigned char out[MAX_LIMBS * 8];
-        const unsigned char *e = (const unsigned char *)exp_b.buf;
-        Py_ssize_t elen = exp_b.len;
-
-        be_to_limbs((const unsigned char *)mod_b.buf, mod_b.len, n, L);
-        be_to_limbs((const unsigned char *)base_b.buf, base_b.len, x, L);
-        be_to_limbs((const unsigned char *)r2_b.buf, r2_b.len, r2, L);
-        if (!(n[0] & 1)) {
-            PyErr_SetString(PyExc_ValueError, "montmodexp: even modulus");
-            goto done;
+    if (elen <= 4) {
+        /* A public exponent (65537: 16 squarings and one multiply):
+         * bit by bit, since the window table alone costs 14 multiplies. */
+        int started = 0;
+        for (Py_ssize_t i = 0; i < elen; i++) {
+            for (int bit = 7; bit >= 0; bit--) {
+                int b = (e[i] >> bit) & 1;
+                if (started) {
+                    mont_mul(acc, acc, n, n0inv, L, t);
+                    memcpy(acc, t, bytes);
+                }
+                if (b) {
+                    if (started) {
+                        mont_mul(acc, table[1], n, n0inv, L, t);
+                        memcpy(acc, t, bytes);
+                    } else {
+                        memcpy(acc, table[1], bytes);
+                        started = 1;
+                    }
+                }
+            }
         }
-
-        Py_BEGIN_ALLOW_THREADS;
-
-        /* table[1] = x in Montgomery form; table[0] = 1 in Mont form */
-        mont_mul(x, r2, n, (u64)n0inv, L, t);
-        memcpy(table[1], t, (size_t)L * 8);
-        {
-            u64 one[MAX_LIMBS];
-            memset(one, 0, (size_t)L * 8);
-            one[0] = 1;
-            mont_mul(one, r2, n, (u64)n0inv, L, t);
-            memcpy(table[0], t, (size_t)L * 8);
-        }
+    } else {
         for (int i = 2; i < 16; i++) {
-            mont_mul(table[i - 1], table[1], n, (u64)n0inv, L, t);
-            memcpy(table[i], t, (size_t)L * 8);
+            mont_mul(table[i - 1], table[1], n, n0inv, L, t);
+            memcpy(table[i], t, bytes);
         }
-
-        /* 4-bit windowed scan over the big-endian exponent bytes */
-        memcpy(acc, table[0], (size_t)L * 8);
+        /* 4-bit windowed scan over the big-endian exponent bytes; the
+         * leading byte is not 0, so at most one leading nibble is */
+        int started = 0;
         for (Py_ssize_t i = 0; i < elen; i++) {
             unsigned char byte = e[i];
             for (int half = 0; half < 2; half++) {
                 int w = half == 0 ? (byte >> 4) : (byte & 0xF);
+                if (!started) {
+                    if (w) {
+                        started = 1;
+                        memcpy(acc, table[w], bytes);
+                    }
+                    continue;
+                }
                 for (int s = 0; s < 4; s++) {
-                    mont_mul(acc, acc, n, (u64)n0inv, L, t);
-                    memcpy(acc, t, (size_t)L * 8);
+                    mont_mul(acc, acc, n, n0inv, L, t);
+                    memcpy(acc, t, bytes);
                 }
                 if (w) {
-                    mont_mul(acc, table[w], n, (u64)n0inv, L, t);
-                    memcpy(acc, t, (size_t)L * 8);
+                    mont_mul(acc, table[w], n, n0inv, L, t);
+                    memcpy(acc, t, bytes);
                 }
             }
         }
+    }
 
-        /* out of Montgomery form */
-        {
-            u64 one[MAX_LIMBS];
-            memset(one, 0, (size_t)L * 8);
-            one[0] = 1;
-            mont_mul(acc, one, n, (u64)n0inv, L, t);
-            memcpy(acc, t, (size_t)L * 8);
+    /* out of Montgomery form */
+    mont_mul(acc, one, n, n0inv, L, t);
+    memcpy(acc, t, bytes);
+}
+
+static PyObject *py_powmod_many(PyObject *self, PyObject *args) {
+    Py_buffer base_b, exp_b, key_b;
+    Py_ssize_t width, ewidth;
+    if (!PyArg_ParseTuple(args, "nny*y*y*", &width, &ewidth, &base_b, &exp_b,
+                          &key_b))
+        return NULL;
+
+    PyObject *ret = NULL;
+    int L = (int)(width / 8);
+    Py_ssize_t rows = width > 0 ? base_b.len / width : 0;
+    Py_ssize_t kw = 2 * width + 8;
+    if (width <= 0 || width % 8 != 0 || L > MAX_LIMBS || ewidth <= 0 ||
+        base_b.len != rows * width || exp_b.len != rows * ewidth ||
+        key_b.len != rows * kw) {
+        PyErr_SetString(PyExc_ValueError, "montmodexp: bad operand shape");
+        goto done;
+    }
+    {
+        const unsigned char *keys = (const unsigned char *)key_b.buf;
+        for (Py_ssize_t r = 0; r < rows; r++) {
+            if (!(keys[r * kw + width - 1] & 1)) {
+                PyErr_SetString(PyExc_ValueError, "montmodexp: even modulus");
+                goto done;
+            }
         }
+    }
+    ret = PyBytes_FromStringAndSize(NULL, rows * width);
+    if (ret == NULL) goto done;
+    {
+        const unsigned char *bases = (const unsigned char *)base_b.buf;
+        const unsigned char *exps = (const unsigned char *)exp_b.buf;
+        const unsigned char *keys = (const unsigned char *)key_b.buf;
+        unsigned char *out = (unsigned char *)PyBytes_AS_STRING(ret);
 
-        limbs_to_be(acc, L, out);
-
+        Py_BEGIN_ALLOW_THREADS;
+        for (Py_ssize_t r = 0; r < rows; r++) {
+            u64 n[MAX_LIMBS], x[MAX_LIMBS], r2[MAX_LIMBS], acc[MAX_LIMBS];
+            u64 n0inv[1];
+            const unsigned char *k = keys + r * kw;
+            be_to_limbs(k, width, n, L);
+            be_to_limbs(k + width, width, r2, L);
+            be_to_limbs(k + 2 * width, 8, n0inv, 1);
+            be_to_limbs(bases + r * width, width, x, L);
+            powmod_core(x, exps + r * ewidth, ewidth, n, r2, n0inv[0], L,
+                        acc);
+            limbs_to_be(acc, L, out + r * width);
+        }
         Py_END_ALLOW_THREADS;
-
-        ret = PyBytes_FromStringAndSize((const char *)out, (Py_ssize_t)L * 8);
     }
 
 done:
     PyBuffer_Release(&base_b);
     PyBuffer_Release(&exp_b);
-    PyBuffer_Release(&mod_b);
-    PyBuffer_Release(&r2_b);
+    PyBuffer_Release(&key_b);
     return ret;
 }
 
 static PyMethodDef Methods[] = {
-    {"powmod", py_powmod, METH_VARARGS,
-     "powmod(base, exp, mod, r2, n0inv) -> bytes (all big-endian; "
-     "len(mod) %% 8 == 0; r2 = 2^(2*64*L) mod mod; n0inv = -mod^-1 mod 2^64)"},
+    {"powmod_many", py_powmod_many, METH_VARARGS,
+     "powmod_many(width, ewidth, bases, exps, keys) -> bytes (N rows packed "
+     "big-endian; keys rows are mod || r2 || n0inv(8); one GIL release)"},
     {NULL, NULL, 0, NULL},
 };
 

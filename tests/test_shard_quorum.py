@@ -227,6 +227,9 @@ def test_keyed_cache_and_generation(universe):
 def test_route_metric_closed_enum(universe):
     from bftkv_tpu.metrics import registry as metrics
 
+    # the registry is the worker's: an earlier file's wider universe
+    # (48 shards) must not be counted against this one's two
+    metrics.reset()
     qs = WotQS(build(universe, "u01"))
     for i in range(32):
         qs.choose_quorum_for(b"m/%d" % i, q.READ)
