@@ -353,6 +353,22 @@ def _chunks(payload: bytes, count: int) -> list:
 # -- the service ------------------------------------------------------------
 
 
+def identity_bits() -> list[int]:
+    """The deployment's RSA identity widths (``BFTKV_IDENTITY_BITS``),
+    ascending.  A value that is no list of widths fails the start."""
+    raw = flags.get("BFTKV_IDENTITY_BITS") or ""
+    try:
+        widths = sorted({int(w) for w in raw.split(",") if w.strip()})
+    except ValueError:
+        widths = []
+    if not widths or widths[0] < 512 or widths[-1] > 16384:
+        raise ValueError(
+            f"BFTKV_IDENTITY_BITS={raw!r}: want RSA widths, such as "
+            "'2048' or '2048,3072'"
+        )
+    return widths
+
+
 def _phase_sum(snap: dict, name: str) -> float:
     """Seconds a phase histogram holds, over its ``op`` label sets."""
     return sum(
@@ -431,6 +447,9 @@ class SidecarService:
         # the recalibration loop exists, so its compile-laden round
         # trips can never price the crossover.
         self.warmup = self._warm()
+        # Exists from the start, so that 0 reads as 0 and not as a
+        # program without the counter.
+        metrics.incr("sidecar.unwarmed_width", 0)
         # After the warm-up, which passes no admission: the queue's
         # "empty since" is then the moment the service can first serve.
         self.admission = admission or AdmissionQueue(
@@ -465,14 +484,25 @@ class SidecarService:
         The first launch of a shape compiles it (12–23 s each on a v5e
         host; a 30 s tenant channel timeout would turn that into silent
         host fallback), so a sidecar that is listening is a sidecar whose
-        programs are built.  Shapes: verify buckets are the powers of
-        two from 256 to ``max_batch``; a sign flush of n signatures is
-        2n CRT-half rows, buckets 64 … 2·``max_batch``, and its fault
-        check rides the verify buckets; the modexp dispatcher's
-        1024-bit launches share the sign programs (other modulus
-        widths, and flushes mixing more than 64 distinct moduli, still
-        compile on first use).  A wrong result or a device error
-        raises: a sidecar that cannot launch does not start.
+        programs are built.  Which programs is the deployment's to say:
+        its identity widths (``BFTKV_IDENTITY_BITS``, default 2048),
+        each asked of ``ops.rns.chains``.  Per width: where the verify
+        chain takes the modulus, the verify buckets — the powers of two
+        from 256 to ``max_batch``, built once, one program serving
+        every modulus the chain holds — which a sign's fault check
+        rides too; where the pow chain takes the CRT halves, the sign
+        buckets — a flush of n signatures is 2n rows, buckets 64 …
+        2·``max_batch`` — and one modexp launch at that row width,
+        which shares the sign programs.  A wrong result or a device
+        error raises: a sidecar that cannot launch does not start.
+
+        Afterwards the domains know what was built
+        (``VerifierDomain.chain_warm``, ``warm_rows`` of the signer and
+        of the modexp dispatcher): an item of an undeclared width is
+        served from the host tier and counted
+        (``sidecar.unwarmed_width``), so no request ever compiles.
+        (One shape still can: a flush mixing more than 64 distinct
+        moduli escalates the key axis.)
 
         Nothing to do on a CPU backend — calibration pins host there
         and no flush ever launches."""
@@ -482,7 +512,7 @@ class SidecarService:
             return {"shapes": [], "seconds": 0.0}
         from bftkv_tpu import ops
         from bftkv_tpu.crypto import rsa as rsamod
-        from bftkv_tpu.ops import dispatch
+        from bftkv_tpu.ops import dispatch, rns
 
         import jax
 
@@ -507,12 +537,10 @@ class SidecarService:
             return out + [hi]
 
         t_start = time.monotonic()
-        key = rsamod.generate(2048)
         msg = b"bftkv-sidecar-warmup"
-        sig = rsamod.sign(msg, key)
-        forged = sig[:-1] + bytes([sig[-1] ^ 1])
         shapes: list[dict] = []
         fetched = _phase_sum(metrics.snapshot(), "flush.fetch")
+        bits = 0  # the width being warmed
 
         def timed(role: str, n: int, fn) -> None:
             # The shapes run one at a time (submit blocks), so every
@@ -528,7 +556,7 @@ class SidecarService:
                 metrics.snapshot(), "flush.fetch"
             )
             shape = {
-                "role": role, "items": n, "seconds": dt,
+                "role": role, "items": n, "bits": bits, "seconds": dt,
                 "trace_s": round(d["trace"], 3),
                 "lower_s": round(d["lower"], 3),
                 "load_s": round(d["load"], 3),
@@ -538,9 +566,9 @@ class SidecarService:
             }
             shapes.append(shape)
             _log.info(
-                "warm-up: %s x%d in %.1f s (trace %.1f, lower %.1f, "
-                "load %.1f, compile %.1f, run %.1f)",
-                role, n, dt, shape["trace_s"], shape["lower_s"],
+                "warm-up: %s x%d at %d bits in %.1f s (trace %.1f, lower "
+                "%.1f, load %.1f, compile %.1f, run %.1f)",
+                role, n, bits, dt, shape["trace_s"], shape["lower_s"],
                 shape["load_s"], shape["compile_s"], shape["run_s"],
             )
 
@@ -570,17 +598,42 @@ class SidecarService:
                     "wrong residue"
                 )
 
-        v_lo = max(256, self.verify.verifier.host_threshold)
-        for n in buckets(min(v_lo, self.verify.max_batch),
-                         self.verify.max_batch):
-            timed("verify", n, warm_verify)
-        s_lo = max(32, self.sign.signer.host_threshold)
-        for n in buckets(min(s_lo, self.sign.max_batch),
-                         self.sign.max_batch):
-            timed("sign", n, warm_sign)
-        m = min(max(64, self.modexp.device_threshold),
-                self.modexp.max_batch)
-        timed("modexp", m, warm_modexp)
+        # One key per declared width; the verify chain's one program
+        # is built by the first width it takes, every sign bucket after
+        # every verify bucket (a sign's fault check rides them).
+        keys = {b: rsamod.generate(b) for b in identity_bits()}
+        bits = next((b for b in keys if rns.chains(b).verify), 0)
+        verify_warm = bool(bits)
+        if verify_warm:
+            key = keys[bits]
+            sig = rsamod.sign(msg, key)
+            forged = sig[:-1] + bytes([sig[-1] ^ 1])
+            v_lo = max(256, self.verify.verifier.host_threshold)
+            for n in buckets(min(v_lo, self.verify.max_batch),
+                             self.verify.max_batch):
+                timed("verify", n, warm_verify)
+        # the CRT-half row width of each key the pow chain can take
+        rows = {
+            bits: 16 * -(-max(k.p.bit_length(), k.q.bit_length()) // 16)
+            for bits, k in keys.items()
+        }
+        rows = {b: r for b, r in rows.items() if rns.chains(r).pow}
+        for bits in rows:
+            key = keys[bits]
+            sig = rsamod.sign(msg, key)
+            s_lo = max(32, self.sign.signer.host_threshold)
+            for n in buckets(min(s_lo, self.sign.max_batch),
+                             self.sign.max_batch):
+                timed("sign", n, warm_sign)
+        for bits in rows:
+            key = keys[bits]
+            m = min(max(64, self.modexp.device_threshold),
+                    self.modexp.max_batch)
+            timed("modexp", m, warm_modexp)
+        self.verify.verifier.chain_warm = verify_warm
+        self.sign.signer.warm_rows = self.modexp.warm_rows = frozenset(
+            rows.values()
+        )
         # Warm-up is not traffic.  Its round trips included compilation
         # (or a cache load) and say nothing about what a launch costs;
         # its items are not any tenant's.  The observed-RTT series and
@@ -594,6 +647,10 @@ class SidecarService:
         return {
             "shapes": shapes,
             "seconds": round(time.monotonic() - t_start, 3),
+            # what was declared, and what that built
+            "identity_bits": list(keys),
+            "verify_chain": verify_warm,
+            "pow_rows": sorted(set(rows.values())),
             "compile_cache": {
                 "dir": ops.compile_cache_dir(),
                 **counts,

@@ -70,6 +70,7 @@ from bftkv_tpu.cmd.verify_sidecar import (
 from bftkv_tpu.crypto import cert as certmod
 from bftkv_tpu.crypto import rsa
 from bftkv_tpu.metrics import registry as metrics
+from bftkv_tpu.ops import rns
 from bftkv_tpu import flags, trace
 from bftkv_tpu.devtools.lockwatch import named_lock
 
@@ -325,20 +326,32 @@ class RemoteVerifierDomain:
         # Hostile public keys (oversized e, absurd n) must fail closed
         # per item like the local path — not blow up the whole frame.
         # ECDSA P-256 items never ride the (RSA-shaped) sidecar wire:
-        # they go to the local domain's batched EC verifier.
+        # they go to the local domain's batched EC verifier.  Nor does
+        # an RSA item no device chain can take (``ops.rns.chains``: a
+        # modulus over the bases' reach — RSA-3072 and wider): the
+        # sidecar could only run it on ITS host, in the one process
+        # every tenant waits for, so this process's native tier
+        # verifies it and it never meets the wire or the admission
+        # queue.
         wire_idx: list[int] = []
         wire_items: list = []
         out_all = np.zeros((len(items),), dtype=bool)
         local_idx: list[int] = []
         ec_idx: list[int] = []
+        wide = 0
         for i, (msg, sig, key) in enumerate(items):
             if certmod.is_ec(key):
                 ec_idx.append(i)
-            elif 0 < key.e < (1 << 32) and key.n > 0:
+            elif not (0 < key.e < (1 << 32) and key.n > 0):
+                local_idx.append(i)
+            elif rns.chains(key.n.bit_length()).verify:
                 wire_idx.append(i)
                 wire_items.append((msg, sig, key))
             else:
                 local_idx.append(i)
+                wide += 1
+        if wide:
+            metrics.incr("verify.local_wide", wide)
         if ec_idx:
             if self._ec_host_only:
                 from bftkv_tpu.crypto import ecdsa as _ecdsa

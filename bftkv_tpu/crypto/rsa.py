@@ -16,6 +16,7 @@ import os
 import time
 from collections import OrderedDict
 from dataclasses import dataclass
+from itertools import repeat
 
 import numpy as np
 
@@ -540,30 +541,45 @@ def _verify_oracle(message: bytes, sig: bytes, key: PublicKey) -> bool:
     return pow(s, key.e, key.n) == emsa_pkcs1v15_sha256(message, key.size_bytes)
 
 
+def _sound_f4(key: PublicKey) -> bool:
+    """e = 65537 on a modulus a verify tier can work with: odd, wide
+    enough for the PKCS#1 encoding (512 bits) and no wider than the
+    native extension takes.  Everything else is the oracle's."""
+    return (
+        key.e == F4 and key.n.bit_length() >= 512 and _native_ok(key.n)
+    )
+
+
+def _rows_match(triples: list) -> list[bool]:
+    """``[(s, em, key)]`` → ``[s^65537 mod n == em]`` as rows of one
+    native batch; ``s < n`` on a :func:`_sound_f4` key is the caller's."""
+    params: dict[int, tuple] = {}  # a batch repeats a handful of keys
+    rows: list = []
+    for s, _em, key in triples:
+        p = params.get(key.n)
+        if p is None:
+            p = params[key.n] = _pub_params(key.n)
+        rows.append((s, F4, p))
+    return [
+        got == em for got, (_s, em, _k) in zip(_powmod_rows(rows), triples)
+    ]
+
+
 def _verify_rows(items: list, strict: bool) -> list[bool]:
     t0 = time.perf_counter()
     out = [False] * len(items)
-    rows: list = []
-    want: list[tuple[int, int]] = []  # (item index, em)
-    params: dict[int, tuple] = {}  # a batch repeats a handful of keys
+    native: list[int] = []  # item indices
+    triples: list = []
     python = 0
     for i, (message, sig, key) in enumerate(items):
         # e = 65537 on a sound modulus goes native; an odd exponent or
         # a junk key keeps the oracle's verdict, failing closed.
-        if (
-            _MM is not None
-            and key.e == F4
-            and key.n.bit_length() >= 512
-            and _native_ok(key.n)
-        ):
+        if _MM is not None and _sound_f4(key):
             s = int.from_bytes(sig, "big")
             if s < key.n:
-                p = params.get(key.n)
-                if p is None:
-                    p = params[key.n] = _pub_params(key.n)
-                rows.append((s, F4, p))
-                want.append(
-                    (i, emsa_pkcs1v15_sha256(message, key.size_bytes))
+                native.append(i)
+                triples.append(
+                    (s, emsa_pkcs1v15_sha256(message, key.size_bytes), key)
                 )
             continue
         python += 1
@@ -572,10 +588,37 @@ def _verify_rows(items: list, strict: bool) -> list[bool]:
         except Exception:
             if strict:
                 raise
-    for (i, em), got in zip(want, _powmod_rows(rows)):
-        out[i] = got == em
+    for i, ok in zip(native, _rows_match(triples)):
+        out[i] = ok
     _count_host_batch("verify", len(items) - python, python, t0)
     return out
+
+
+#: The widths a ``bits`` label may read: identity widths in use, and
+#: ``other`` for whatever an attacker's certificate carries (labels
+#: stay low-cardinality, DESIGN.md section 7).
+_BITS_CLASSES = (1024, 2048, 3072, 4096)
+
+
+def bits_class(n: int):
+    """The ``bits`` label of modulus ``n``: the identity width it
+    belongs to (the next of 1024 / 2048 / 3072 / 4096), else ``other``."""
+    b = n.bit_length()
+    return next((c for c in _BITS_CLASSES if b <= c), "other")
+
+
+def count_tier(name: str, moduli, counts=None) -> None:
+    """Count items on a tier (``verify.device``, ``verify.host``,
+    ``sign.device``, ``sign.host``): the plain total every reader of
+    the name has, and beside it ``<name>.bits{bits=...}`` by key width.
+    One modulus an item, or distinct moduli with how many items each."""
+    by_bits: dict = {}
+    for n, k in zip(moduli, counts if counts is not None else repeat(1)):
+        c = bits_class(n)
+        by_bits[c] = by_bits.get(c, 0) + int(k)
+    for c, k in by_bits.items():
+        metrics.incr(name, k)
+        metrics.incr(name + ".bits", k, labels={"bits": c})
 
 
 def verify_host_many(
@@ -643,6 +686,11 @@ class SignerDomain:
         # key, so these per-key constants must not be recomputed per item.
         self._crt: "OrderedDict[int, tuple[int, int, int]]" = OrderedDict()
         self._dom_lock = named_lock("crypto.rsa.montgomery")
+        #: Row widths (bits) whose pow programs are built.  None:
+        #: nobody said, a launch compiles on first use.  The sidecar
+        #: says after its warm-up; a sign at another width then goes to
+        #: the host tier and never compiles inside a request.
+        self.warm_rows: frozenset | None = None
 
     _CACHE_MAX = 1024  # distinct private keys in one trust domain: few
 
@@ -708,7 +756,7 @@ class SignerDomain:
             return False
         if vals is None:
             return False
-        metrics.incr("sign.device", len(group))
+        count_tier("sign.device", (g[1].n for g in group))
         metrics.observe("sign.device_batch", len(group))
         sigs: list[tuple[int, object, int]] = []  # (item idx, key, s)
         with trace.leaf("flush.unpack", "sign", items=len(group)):
@@ -743,51 +791,84 @@ class SignerDomain:
 
     @staticmethod
     def _fault_check(sigs: list, group: list) -> list[bool]:
-        """s^65537 ≡ em (mod n) for every produced signature, as one
-        RNS verify batch when the moduli allow, host ``pow`` otherwise."""
+        """s^65537 ≡ em (mod n) for every produced signature: one RNS
+        verify launch for the moduli the verify chain can take
+        (``ops.rns.chains``), one native host batch for the sound
+        moduli it cannot (RSA-3072 and wider), ``pow`` for the rest."""
         from bftkv_tpu.ops import rns as rns_ops
 
         ems = [g[2] for g in group]
-        ctx = rns_ops.context()
+        ctx = None
         unique: dict[int, int] = {}
         urows: list = []
         idxs: list[int] = []
         dig_s: list[np.ndarray] = []
         dig_em: list[np.ndarray] = []
         device_pos: list[int] = []
+        host_pos: list[int] = []
         ok = [False] * len(sigs)
         # The check is a verify launch of its own — stage, launch,
         # fetch, unpack — under the op of the sign it polices.
         with trace.leaf("flush.stage", "sign", items=len(sigs)) as stage:
             for pos, ((_i, key, s), em) in enumerate(zip(sigs, ems)):
-                kr = ctx.key_rows(key.n) if key.e == F4 else None
+                kr = None
+                if (
+                    key.e == F4
+                    and rns_ops.chains(key.n.bit_length()).verify
+                ):
+                    ctx = ctx or rns_ops.context()
+                    kr = ctx.key_rows(key.n)
                 if kr is None:
-                    ok[pos] = pow(s, key.e, key.n) == em
+                    host_pos.append(pos)
                     continue
                 u = unique.get(key.n)
                 if u is None:
                     u = unique[key.n] = len(urows)
                     urows.append(kr)
                 idxs.append(u)
-                dig_s.append(limb.int_to_limbs(s, 128))
-                dig_em.append(limb.int_to_limbs(em, 128))
+                dig_s.append(limb.int_to_limbs(s, ctx.digits))
+                dig_em.append(limb.int_to_limbs(em, ctx.digits))
                 device_pos.append(pos)
             if device_pos:
                 k = len(device_pos)
                 padded = max(256, 1 << (k - 1).bit_length())
-                stage.attrs["bucket"] = padded
+                bits = 16 * ctx.digits
+                stage.attrs.update(bucket=padded, bits=bits)
                 idxs += [0] * (padded - k)
-                dig_s += [np.zeros(128, dtype=np.uint32)] * (padded - k)
+                dig_s += [np.zeros(ctx.digits, dtype=np.uint32)] * (padded - k)
                 dig_em += [dig_em[0]] * (padded - k)
                 kpad = max(64, 1 << (len(urows) - 1).bit_length())
                 urows += [urows[0]] * (kpad - len(urows))
                 staged = _stage_verify_operands(
                     dig_s, dig_em, idxs, urows
                 )
+        if host_pos:
+            # Every one is checked, in one call: the native rows of the
+            # host tier for a sound key (no ``pow`` per item under the
+            # GIL), the oracle's ``pow`` for an odd exponent.  Host
+            # work between the fetch and the answers: flush.unpack.
+            with trace.leaf("flush.unpack", "sign", items=len(host_pos)):
+                t0 = time.perf_counter()
+                native: list[int] = []
+                for pos in host_pos:
+                    _i, key, s = sigs[pos]
+                    if _MM is not None and _sound_f4(key) and s < key.n:
+                        native.append(pos)
+                    else:
+                        ok[pos] = pow(s, key.e, key.n) == ems[pos]
+                for pos, good in zip(native, _rows_match(
+                    [(sigs[pos][2], ems[pos], sigs[pos][1]) for pos in native]
+                )):
+                    ok[pos] = good
+                _count_host_batch(
+                    "verify", len(native), len(host_pos) - len(native), t0
+                )
         if device_pos:
-            with trace.leaf("flush.launch", "sign", items=k, bucket=padded):
+            with trace.leaf(
+                "flush.launch", "sign", items=k, bucket=padded, bits=bits
+            ):
                 dev = rns_ops.verify_e65537_rns_indexed(*staged)
-            with trace.leaf("flush.fetch", "sign", items=k):
+            with trace.leaf("flush.fetch", "sign", items=k, bits=bits):
                 good = np.asarray(dev)[:k]
             for pos, g in zip(device_pos, good):
                 ok[pos] = bool(g)
@@ -824,10 +905,20 @@ class SignerDomain:
             # per-item encodings and CRT constants: staging of the
             # launches below, the first interval of their flush.stage
             with trace.leaf("flush.stage", "sign", items=len(items)):
+                from bftkv_tpu.ops import rns as rns_ops
+
                 for i, (message, key) in enumerate(items):
                     lp = limb.nlimbs_for_bits(key.p.bit_length())
                     lq = limb.nlimbs_for_bits(key.q.bit_length())
                     w = max(lp, lq)
+                    if self.backend == "rns" and not (
+                        rns_ops.chains(16 * w).pow
+                        and rns_ops.pow_rows_warm(16 * w, self.warm_rows)
+                    ):
+                        # rows the bases cannot hold, or a program
+                        # nobody built: the host tier
+                        host_idx.append(i)
+                        continue
                     domp = self._dom(key.p, w)
                     domq = self._dom(key.q, w)
                     if domp is None or domq is None:
@@ -884,7 +975,7 @@ class SignerDomain:
                 )
             )[:k]
             vals = limb.limbs_to_ints(res)
-            metrics.incr("sign.device", len(group))
+            count_tier("sign.device", (g[1].n for g in group))
             sigs: list[tuple[int, object, int]] = []
             for j, (i, key, m, _domp, _domq, _dp, _dq, qinv) in enumerate(group):
                 m1, m2 = vals[2 * j], vals[2 * j + 1]
@@ -908,7 +999,7 @@ class SignerDomain:
                         key.size_bytes, "big"
                     )
         if host_idx:
-            metrics.incr("sign.host", len(host_idx))
+            count_tier("sign.host", (items[i][1].n for i in host_idx))
         return out  # type: ignore[return-value]
 
 
@@ -918,11 +1009,14 @@ class VerifierDomain:
 
     All keys in one domain share a limb width (2048-bit by default);
     heterogeneous batches mix keys freely since every element carries its
-    own modulus row. Keys that can't go through the device kernel — a
-    non-65537 exponent, or a hostile modulus (even / zero / wider than
-    the limb budget, reachable from attacker-embedded certificates) —
-    fall back to the host oracle or fail closed; they never raise out of
-    the verification path.
+    own modulus row.  Three tiers, chosen per item by what the code can
+    observe of the key: the device chain, for e = 65537 on a sound
+    modulus the chain can take (``ops.rns.chains``); the native host
+    tier, for e = 65537 on a sound modulus it cannot — RSA-3072 and
+    wider identities — and for batches under the crossover; the host
+    oracle, for a non-65537 exponent or a hostile modulus (even / zero
+    / absurdly wide, reachable from attacker-embedded certificates),
+    which fails closed.  Nothing raises out of the verification path.
     """
 
     _CACHE_MAX = 4096  # moduli are attacker-influenced (embedded certs)
@@ -958,9 +1052,9 @@ class VerifierDomain:
         )
         #: "rns" (default): residue-number-system f32/MXU kernel, ~19x
         #: the limb kernel at large batch; "limb": the XLA Montgomery
-        #: limb kernel; "pallas": the VMEM-resident limb chain. Hostile
-        #: keys the RNS path cannot take (shared factor with a channel
-        #: prime, etc.) fall back per item.
+        #: limb kernel; "pallas": the VMEM-resident limb chain. Keys
+        #: whose rows the RNS path cannot build (a factor shared with a
+        #: channel prime) fall back per item.
         self.backend = backend or flags.raw("BFTKV_VERIFY_BACKEND", "rns")
         if self.backend not in ("rns", "limb", "pallas"):
             raise ValueError(f"unknown verify backend {self.backend!r}")
@@ -970,6 +1064,11 @@ class VerifierDomain:
         # Pipelined dispatcher flushes call verify_batch from multiple
         # worker threads; the LRU mutations must not race.
         self._cache_lock = named_lock("crypto.rsa.verify_cache")
+        #: Whether the verify chain's programs are built.  None: nobody
+        #: said, a launch compiles on first use.  The sidecar says after
+        #: its warm-up (False where no declared identity width has a
+        #: verify chain): a request then never compiles one.
+        self.chain_warm: bool | None = None
 
     def _dom(self, n: int) -> bigint.MontgomeryDomain | None:
         """Montgomery domain for ``n``, or None if ``n`` is unusable.
@@ -992,6 +1091,16 @@ class VerifierDomain:
             if len(self._cache) > self._CACHE_MAX:
                 self._cache.popitem(last=False)
         return dom
+
+    def _chain_takes(self, bits: int) -> bool:
+        """Whether this domain's device chain can take a sound modulus
+        of ``bits`` bits: the RNS chain by the bases' reach
+        (``ops.rns.chains``), the limb chains by the limb budget."""
+        if self.backend == "rns":
+            from bftkv_tpu.ops import rns
+
+            return rns.chains(bits).verify
+        return bits <= 16 * self.nlimbs
 
     @property
     def host_threshold(self) -> int:
@@ -1063,7 +1172,10 @@ class VerifierDomain:
         device_items: list[tuple[bytes, bytes, PublicKey]] = []
         ec_idx: list[int] = []
         ec_items: list = []
+        wide_idx: list[int] = []
         odd_idx: list[int] = []
+        takes: dict[int, bool] = {}  # modulus bits -> the chain takes it
+        unwarmed = 0
         # The per-item tier split is the first interval of the launch's
         # flush.stage where the batch is bound for the RNS chain (the
         # second, in _verify_rns, builds the operands).
@@ -1081,18 +1193,31 @@ class VerifierDomain:
                     ec_idx.append(i)
                     ec_items.append((message, sig_bytes, key))
                     continue
-                # 512-bit floor keeps the PKCS#1 encoding well-defined.
-                if (
-                    key.e == F4
-                    and key.n.bit_length() >= 512
-                    and self._dom(key.n) is not None
-                ):
-                    device_idx.append(i)
-                    device_items.append((message, sig_bytes, key))
-                else:
+                if not _sound_f4(key):
                     # Host oracle for odd exponents; fails closed on
                     # junk keys.
                     odd_idx.append(i)
+                    continue
+                # A flush repeats a handful of widths thousands of
+                # times: the rule is asked once a width.
+                bits = key.n.bit_length()
+                t = takes.get(bits)
+                if t is None:
+                    t = takes[bits] = self._chain_takes(bits)
+                if t and self.chain_warm is False:
+                    unwarmed += 1
+                    t = False
+                if t:
+                    device_idx.append(i)
+                    device_items.append((message, sig_bytes, key))
+                else:
+                    # A sound key the chain cannot hold (RSA-3072 and
+                    # wider): the native host tier, as a tier.
+                    wide_idx.append(i)
+        if unwarmed:
+            from bftkv_tpu.ops import rns
+
+            rns.note_unwarmed("verifies", max(takes), unwarmed)
         if ec_items:
             from bftkv_tpu.crypto import ecdsa as _ecdsa
 
@@ -1105,12 +1230,17 @@ class VerifierDomain:
                 [items[i] for i in odd_idx]
             )
         if device_items and self._stay_on_host(len(device_items)):
-            metrics.incr("verify.host", len(device_items))
-            out[np.asarray(device_idx)] = verify_host_many(device_items)
-        elif device_items and self.backend == "rns":
+            # under the crossover: the host tier's too
+            wide_idx += device_idx
+            device_items = []
+        if wide_idx:
+            wide = [items[i] for i in wide_idx]
+            count_tier("verify.host", (k.n for _m, _s, k in wide))
+            out[np.asarray(wide_idx)] = verify_host_many(wide)
+        if device_items and self.backend == "rns":
             self._verify_rns(device_idx, device_items, out)
         elif device_items:
-            metrics.incr("verify.device", len(device_items))
+            count_tier("verify.device", (k.n for _m, _s, k in device_items))
             sig, em, n, npr, r2 = self.assemble(device_items)
             k = len(device_items)
             # Pad to a power-of-two bucket (floor 256): the kernel is jitted
@@ -1171,9 +1301,9 @@ class VerifierDomain:
                 kr = ctx.key_rows(key.n)
                 s = int.from_bytes(sig_bytes, "big")
                 if kr is None or s >= key.n:
-                    # Hostile modulus (or oversized sig): host oracle,
+                    # No rows for this modulus (a factor shared with a
+                    # channel prime) or an oversized sig: host tier,
                     # failing closed on junk.
-                    metrics.incr("verify.host")
                     host_idx.append(j)
                     host_items.append((message, sig_bytes, key))
                     continue
@@ -1182,27 +1312,30 @@ class VerifierDomain:
                     u = unique[key.n] = len(urows)
                     urows.append(kr)
                 idxs.append(u)
-                digit_rows.append(limb.int_to_limbs(s, 128))
+                digit_rows.append(limb.int_to_limbs(s, ctx.digits))
                 em_rows.append(
                     limb.int_to_limbs(
-                        emsa_pkcs1v15_sha256(message, key.size_bytes), 128
+                        emsa_pkcs1v15_sha256(message, key.size_bytes),
+                        ctx.digits,
                     )
                 )
                 keep_idx.append(j)
             if host_items:
+                count_tier("verify.host", (k.n for _m, _s, k in host_items))
                 out[np.asarray(host_idx)] = verify_host_many(host_items)
             if not idxs:
                 return
             k = len(idxs)
-            metrics.incr("verify.device", k)
+            count_tier("verify.device", unique, np.bincount(idxs))
             metrics.observe("verify.device_batch", k)
             # Power-of-two buckets (floor 256), padding with row 0's key
             # and sig digits of 0 — 0^e never equals a PKCS#1 encoding.
             padded = max(256, 1 << (k - 1).bit_length())
-            sp.attrs["bucket"] = padded
+            bits = 16 * ctx.digits
+            sp.attrs.update(bucket=padded, bits=bits)
             for _ in range(padded - k):
                 idxs.append(0)
-                digit_rows.append(np.zeros(128, dtype=np.uint32))
+                digit_rows.append(np.zeros(ctx.digits, dtype=np.uint32))
                 em_rows.append(em_rows[0])
             # The unique-key axis is padded to a fixed floor of 64 (64
             # rows ≈ 800 KB of transfer — noise) so the (T, K) shape pair
@@ -1214,11 +1347,13 @@ class VerifierDomain:
             staged = _stage_verify_operands(
                 digit_rows, em_rows, idxs, urows
             )
-        with trace.leaf("flush.launch", "verify", items=k, bucket=padded):
+        with trace.leaf(
+            "flush.launch", "verify", items=k, bucket=padded, bits=bits
+        ):
             dev = rns.verify_e65537_rns_indexed(*staged)
-        with trace.leaf("flush.fetch", "verify", items=k):
+        with trace.leaf("flush.fetch", "verify", items=k, bits=bits):
             ok = np.asarray(dev)[:k]
-        with trace.leaf("flush.unpack", "verify", items=k):
+        with trace.leaf("flush.unpack", "verify", items=k, bits=bits):
             out[np.asarray(keep_idx)] = ok
 
 

@@ -235,6 +235,12 @@ _flag("BFTKV_SIDECAR_MAX_WAIT", "0.5", "float",
 _flag("BFTKV_SIDECAR_MAX_KEYS", "64", "int",
       "Sign-key handles one sidecar connection may register (bounds "
       "hostile registration floods).")
+_flag("BFTKV_IDENTITY_BITS", "2048", "str",
+      "The deployment's RSA identity widths, comma-separated (`3072`, "
+      "`2048,3072`): the sidecar builds the device programs of these "
+      "widths before it listens; a sign or modexp at any other width is "
+      "served from the host tier, never compiled inside a request. "
+      "Chooses no tier: calibration does.")
 
 _begin("Device kernels & dispatch")
 _flag("BFTKV_DISPATCH_CALIBRATE", "1", "switch",

@@ -62,6 +62,7 @@ LABEL_KEYS = (
     "kind",       # autopilot plan kind: split / retire
     "resource",   # capacity-plane resource (closed capacity.RESOURCES enum)
     "width",      # device batch limb-width group (bounded: few limb sizes + "ec")
+    "bits",       # RSA identity width class: 1024/2048/3072/4096/other
     "le",         # histogram bucket bound (fixed BUCKETS ladder)
 )
 

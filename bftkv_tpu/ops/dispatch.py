@@ -878,6 +878,9 @@ class ModexpDispatcher(_BatchDispatcher):
             if device_threshold is not None
             else ALWAYS_HOST
         )
+        #: Row widths (bits) whose pow programs are built; None:
+        #: nobody said (``SignerDomain.warm_rows`` has the rule).
+        self.warm_rows: frozenset | None = None
 
     def apply_calibration(self, cal: dict) -> None:
         self._prefer_host = cal["prefer_host"]
@@ -887,13 +890,20 @@ class ModexpDispatcher(_BatchDispatcher):
 
     def _width_groups(self, items: list, device_idx: list[int]):
         from bftkv_tpu.ops import limb as limb_ops
+        from bftkv_tpu.ops import rns as rns_ops
 
-        # One launch per limb-width group (uniform kernel shapes).
+        # One launch per limb-width group (uniform kernel shapes); a
+        # width the bases cannot hold, or whose program nobody built,
+        # gets none: its items are the host tier's.
         by_width: dict[int, list[int]] = {}
         for i in device_idx:
             w = limb_ops.nlimbs_for_bits(items[i][2].bit_length())
             by_width.setdefault(w, []).append(i)
-        return by_width
+        return {
+            w: idxs for w, idxs in by_width.items()
+            if rns_ops.chains(16 * w).pow
+            and rns_ops.pow_rows_warm(16 * w, self.warm_rows, len(idxs))
+        }
 
     def _note_device_group(self, w: int, idxs: list[int]) -> None:
         metrics.incr("modexp.device", len(idxs))

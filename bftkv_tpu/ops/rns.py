@@ -31,6 +31,8 @@ from __future__ import annotations
 
 import functools
 import logging
+import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -43,6 +45,8 @@ from bftkv_tpu import flags
 from bftkv_tpu.metrics import registry as metrics
 
 __all__ = [
+    "Chains",
+    "chains",
     "RNSContext",
     "context",
     "verify_e65537_rns",
@@ -66,42 +70,114 @@ def _gen_primes(lo: int, hi: int) -> list[int]:
     return [int(lo + i) for i in np.nonzero(sieve)[0]]
 
 
+def _deal_bases(n_bits: int) -> tuple[list[int], list[int]]:
+    """The two bases for numbers of ``n_bits`` bits: all primes of
+    [2^10, 2^12), largest first, dealt alternately so both get ~equal
+    bit mass, until each clears ``n_bits`` by a healthy margin (the
+    AMM slack analysis needs M > (k+2)^2 N), then cut to equal channel
+    counts.  The supply is finite — 392 primes, 4,391 bits — so this
+    is where a width the chains cannot hold is found out: ValueError.
+    """
+    need = n_bits + 64
+    pb: list[int] = []
+    pq: list[int] = []
+    bits_b = bits_q = 0.0
+    for p in _gen_primes(1 << 10, 1 << PR_BITS)[::-1]:
+        if bits_b <= bits_q:
+            pb.append(p)
+            bits_b += np.log2(p)
+        else:
+            pq.append(p)
+            bits_q += np.log2(p)
+        if bits_b > need and bits_q > need:
+            break
+    else:
+        raise ValueError("not enough sub-2^12 primes for the bases")
+    # Equal channel counts keep the matmul shapes square-ish.
+    k = min(len(pb), len(pq))
+    pb, pq = pb[:k], pq[:k]
+    if math.prod(pb) <= (1 << need) or math.prod(pq) <= (1 << need):
+        raise ValueError("base bit mass too small")
+    return pb, pq
+
+
+@functools.lru_cache(maxsize=256)
+def _bases_hold(n_bits: int) -> bool:
+    try:
+        _deal_bases(n_bits)
+    except ValueError:
+        return False
+    return True
+
+
+class Chains(NamedTuple):
+    """Which device chains can take a number of some width."""
+
+    verify: bool  # a modulus this wide rides the verify chain
+    pow: bool  # a row (modulus and exponent) this wide rides the pow chain
+
+
+@functools.lru_cache(maxsize=256)
+def chains(bits: int) -> Chains:
+    """THE capability rule: what the RNS chains can take at ``bits``.
+
+    The pow chain is compiled per row width (``context(digits,
+    n_bits)``), so it takes any width the prime supply can build two
+    bases for — about 2,130 bits: the CRT halves of RSA-2048, -3072
+    and -4096, whole moduli up to 2048 bits.  The verify chain works
+    on whole moduli in the one context ``context()`` and takes what
+    fits its digits.  A wider modulus is not hostile, it is beyond the
+    f32-exact design (channel products < 2^24), and belongs to the
+    native host tier (``crypto/rsa.py:verify_host_many``).  Everyone
+    who routes by width — verifier, fault check, signer, modexp
+    dispatcher, tenant channel, the sidecar's warm-up — asks here.
+    """
+    if bits <= 0:
+        return Chains(False, False)
+    return Chains(
+        verify=bits <= 16 * DIGITS and _bases_hold(16 * DIGITS),
+        pow=_bases_hold(bits),
+    )
+
+
+_unwarmed_logged: set = set()
+
+
+def note_unwarmed(what: str, bits: int, items: int) -> None:
+    """Items of a width whose device program the owner did not build
+    (the sidecar's warm-up, by the deployment's declared identity
+    widths): they are served from the host tier — counted per item
+    (``sidecar.unwarmed_width``), logged once a kind and width — and
+    never compile inside a request."""
+    metrics.incr("sidecar.unwarmed_width", items)
+    if (what, bits) not in _unwarmed_logged and len(_unwarmed_logged) < 64:
+        _unwarmed_logged.add((what, bits))
+        logging.getLogger("bftkv_tpu.ops.rns").warning(
+            "%s of %d bits arrived, and this deployment's declared "
+            "identity widths (BFTKV_IDENTITY_BITS) built no device "
+            "program for them: served on the host tier", what, bits,
+        )
+
+
+def pow_rows_warm(n_bits: int, warm_rows, items: int = 1) -> bool:
+    """Whether a pow launch at ``n_bits`` may go to the device now.
+    ``warm_rows`` is the owner's word on which row widths have their
+    programs built (``None``: nobody said, compile on first use)."""
+    if warm_rows is None or n_bits in warm_rows:
+        return True
+    note_unwarmed("pow rows", n_bits, items)
+    return False
+
+
 class RNSContext:
     """Shared (key-independent) precomputation for one digit width."""
 
     def __init__(self, digits: int = DIGITS, n_bits: int = 2048):
-        # All primes below 2^12, largest first; two interleaved bases
-        # so both get ~equal bit mass. Each base must clear n_bits by a
-        # healthy margin (the AMM slack analysis needs M > (k+2)^2 N).
-        primes = [p for p in _gen_primes(1 << 10, 1 << PR_BITS)][::-1]
-        need = n_bits + 64
-        self.pb: list[int] = []
-        self.pq: list[int] = []
-        bits_b = bits_q = 0.0
-        for p in primes:
-            if bits_b <= bits_q:
-                self.pb.append(p)
-                bits_b += np.log2(p)
-            else:
-                self.pq.append(p)
-                bits_q += np.log2(p)
-            if bits_b > need and bits_q > need:
-                break
-        else:
-            raise ValueError("not enough sub-2^12 primes for the bases")
-        # Equal channel counts keep the matmul shapes square-ish.
-        k = min(len(self.pb), len(self.pq))
-        self.pb, self.pq = self.pb[:k], self.pq[:k]
-        self.k = k
+        self.pb, self.pq = _deal_bases(n_bits)
+        k = self.k = len(self.pb)
         self.digits = digits
-        self.M = 1
-        for p in self.pb:
-            self.M *= p
-        self.Mq = 1
-        for q in self.pq:
-            self.Mq *= q
-        if self.M <= (1 << need) or self.Mq <= (1 << need):
-            raise ValueError("base bit mass too small")
+        self.M = math.prod(self.pb)
+        self.Mq = math.prod(self.pq)
 
         f = lambda xs: np.asarray(xs, dtype=np.float32)
         self.p_all = f(self.pb + self.pq)
@@ -162,10 +238,13 @@ class RNSContext:
     def key_rows(self, n: int):
         """Channel constants for one public modulus ``n`` (cached).
 
-        Returns None for modulo that cannot ride the RNS path: even,
-        too wide for the digit budget, or sharing a factor with a
-        channel prime — real RSA moduli never do, but certificates are
-        attacker-supplied, so such keys must fall back, not raise.
+        Returns None for a modulus this context cannot build rows
+        for: even, wider than its digits (callers route by width
+        first — :func:`chains` — so that is a caller's slip, not a
+        hostile key: an RSA-3072 modulus is merely beyond the bases),
+        or sharing a factor with a channel prime — real RSA moduli
+        never do, but certificates are attacker-supplied, so such
+        keys must fall back, not raise.
         """
         if n <= 0 or n % 2 == 0 or n.bit_length() > 16 * self.digits:
             return None
@@ -631,7 +710,7 @@ def power_mod_rns(
                 ring.release(slot)
 
     try:
-        with trace.leaf("flush.stage", op, items=t) as sp:
+        with trace.leaf("flush.stage", op, items=t, bits=n_bits) as sp:
             unique: dict[int, int] = {}
             urows: list = []
             idxs: list[int] = []
@@ -690,7 +769,7 @@ def power_mod_rns(
         mods_live = list(mods)
 
         def unpack(sigma: np.ndarray) -> list[int]:
-            with trace.leaf("flush.unpack", op, items=t):
+            with trace.leaf("flush.unpack", op, items=t, bits=n_bits):
                 vals = _sigma_to_ints(ctx, sigma)
                 return [v % m for v, m in zip(vals, mods_live)]
 
@@ -701,7 +780,9 @@ def power_mod_rns(
 
                 # the fused chain blocks on its result: launch and
                 # fetch are one interval here
-                with trace.leaf("flush.launch", op, items=t, bucket=padded):
+                with trace.leaf(
+                    "flush.launch", op, items=t, bucket=padded, bits=n_bits
+                ):
                     sigma = np.asarray(
                         pallas_rns.pow_pallas(
                             *pow_args, digits=digits, n_bits=n_bits
@@ -723,12 +804,14 @@ def power_mod_rns(
                 digits, n_bits,
                 donate=jax.default_backend() in ("tpu", "gpu"),
             )
-        with trace.leaf("flush.launch", op, items=t, bucket=padded):
+        with trace.leaf(
+            "flush.launch", op, items=t, bucket=padded, bits=n_bits
+        ):
             dev = fn(*pow_args)  # jax dispatch is async: not a result yet
 
         def finish() -> list[int]:
             try:
-                with trace.leaf("flush.fetch", op, items=t):
+                with trace.leaf("flush.fetch", op, items=t, bits=n_bits):
                     s = np.asarray(dev)[:t]
             finally:
                 # Materialized (or launch failed): the device no longer

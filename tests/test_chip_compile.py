@@ -73,12 +73,14 @@ def _operands(kind: str, rows: int, place, place_t, place_key):
             place((rows,), jnp.int32),
             _key_shapes(rns.context(), place_key),
         )
-    # RSA-2048 CRT halves: 64 digits, 1024-bit exponents → 256 nibbles.
+    # CRT halves: RSA-2048's are 64 digits with 1024-bit exponents (256
+    # nibbles), RSA-3072's 96 digits with 1536-bit ones (384).
+    digits = {"pow": 64, "pow1536": 96}[kind]
     return (
-        place((rows, 128), jnp.uint8),
-        place_t((256, rows), jnp.uint8),
+        place((rows, 2 * digits), jnp.uint8),
+        place_t((4 * digits, rows), jnp.uint8),
         place((rows,), jnp.int32),
-        _key_shapes(rns.context(64, 1024), place_key),
+        _key_shapes(rns.context(digits, 16 * digits), place_key),
     )
 
 
@@ -103,14 +105,16 @@ def _compiled_ok(compiled, *, kernel: bool = False) -> None:
         ("verify", 4096),  # the sidecar's max_batch, warm-up's largest
         ("pow", 512),      # 256 share signs = 512 CRT-half rows
         ("pow", 2048),     # four servers' 256-sign batches coalesced
+        ("pow1536", 2048),  # the same flush on RSA-3072 identities
     ],
 )
 def test_xla_chain_compiles_for_one_chip(topo, kind, rows):
     one = _on(SingleDeviceSharding(topo.devices[0]))
-    fn = (
-        rns._jitted_verify_gather() if kind == "verify"
-        else rns._jitted_pow(64, 1024, True)  # donated, as on a device
-    )
+    fn = {  # the pow chains donated, as on a device
+        "verify": rns._jitted_verify_gather,
+        "pow": lambda: rns._jitted_pow(64, 1024, True),
+        "pow1536": lambda: rns._jitted_pow(96, 1536, True),
+    }[kind]()
     with warnings.catch_warnings():
         # ``_jitted_pow`` donates uint8 operands that no f32 output
         # can alias; JAX says so once per lowering.
