@@ -839,9 +839,12 @@ def digits_to_halves(digits_u32: np.ndarray) -> np.ndarray:
 
 def digits_to_halves_u8(digits_u32: np.ndarray) -> np.ndarray:
     """Same as :func:`digits_to_halves` but uint8 — 4x less wire for
-    host→device transfer; the kernel casts to f32 on device.  Operands
-    that are uint8 already were split by the caller (its staging phase)
-    and pass through."""
+    host→device transfer; the kernel casts to f32 on device.  The
+    halves of a number's 16-bit little-endian digits, low half first,
+    are the number's little-endian byte string: a caller that holds
+    the bytes builds this array from them directly
+    (``crypto/rsa.py:_stage_verify_operands``), and operands that are
+    uint8 already pass through."""
     if digits_u32.dtype == np.uint8:
         return digits_u32
     t = digits_u32.shape[0]
@@ -1024,12 +1027,23 @@ def verify_e65537_rns_indexed(
     return _jitted_verify_gather()(sig_h, em_h, idx, unique_rows)
 
 
-def stack_key_rows(rows: list):
+def stack_key_rows(rows: list, pad_to: int = 0):
     """Stack per-key row tuples (from :meth:`RNSContext.key_rows`) into
     the batch tensors ``verify_e65537_rns`` takes. The (T, 1) reshape
-    of the scalar redundant-channel entries lives here and only here."""
-    stack = lambda i: np.stack([np.asarray(r[i]) for r in rows])
-    t = len(rows)
+    of the scalar redundant-channel entries lives here and only here.
+    ``pad_to`` repeats row 0 up to that many rows (the unique-key axis
+    of an indexed launch is padded to a few fixed sizes)."""
+    t = max(len(rows), pad_to)
+
+    def stack(i):
+        real = np.stack([np.asarray(r[i]) for r in rows])
+        if t == len(rows):
+            return real
+        out = np.empty((t,) + real.shape[1:], dtype=real.dtype)
+        out[: len(rows)] = real
+        out[len(rows) :] = real[0]
+        return out
+
     return (
         stack(0),
         stack(1).reshape(t, 1),
