@@ -2,8 +2,9 @@
 
 Single-item client-side operations (a writer signs its own packet once per
 write — reference: protocol/client.go:134) stay on host; *verification*,
-the O(n²) per-write cluster cost, is batched on TPU via
-``bftkv_tpu.ops.rsa``. The EMSA-PKCS1-v1_5 encoding mirrors what the
+the O(n²) per-write cluster cost, is batched on TPU via the RNS chains
+of ``bftkv_tpu.ops.rns``, which alone decides what a chain can take
+(``rns.chains``). The EMSA-PKCS1-v1_5 encoding mirrors what the
 reference gets from Go's crypto/rsa (crypto/threshold/rsa/rsa.go:345-378).
 """
 
@@ -24,7 +25,7 @@ import numpy as np
 from bftkv_tpu import trace
 from bftkv_tpu.errors import ERR_INVALID_SIGNATURE
 from bftkv_tpu.metrics import registry as metrics
-from bftkv_tpu.ops import bigint, limb
+from bftkv_tpu.ops import limb
 from bftkv_tpu import flags
 from bftkv_tpu.devtools.lockwatch import named_lock
 
@@ -44,9 +45,6 @@ class PublicKey:
     @property
     def size_bytes(self) -> int:
         return (self.n.bit_length() + 7) // 8
-
-    def domain(self) -> bigint.MontgomeryDomain:
-        return bigint.MontgomeryDomain(self.n)
 
 
 @dataclass
@@ -654,24 +652,22 @@ _verify_host = verify_host
 class SignerDomain:
     """Batched PKCS#1 v1.5 signing on device via CRT.
 
-    Each signature is two half-width modexps (mod p and mod q) batched
-    across concurrent requests into one ``ops.rsa.power_batch`` launch —
-    both halves of every signature ride in the *same* batch — plus a
-    cheap host-side CRT recombination.  A 1024-bit modexp on a v5e runs
-    ~7x a single host core at batch 256 and, unlike host ``pow``,
-    releases the GIL, so server handler threads keep flowing.
-
-    Below ``host_threshold`` items the host signs directly (a device
-    launch costs ~100 ms regardless of size; a host CRT sign is ~9 ms).
+    Each signature is two half-width modexps (mod p and mod q), batched
+    across concurrent requests into one RNS pow launch a row width
+    (``ops.rns.power_mod_rns``) — both halves of every signature ride
+    in the *same* launch — plus a host-side CRT recombination and a
+    fault check of every output before release.  An item rides when
+    ``ops.rns`` takes its key: rows of a width the pow chain holds
+    (``rns.chains``) and has a built program for
+    (``rns.pow_rows_warm``), primes it has key rows for.  Every other
+    item, and every batch below ``host_threshold`` items, is the host
+    tier's (:func:`sign_many`): a launch costs tens of ms whatever its
+    size.
     """
 
     HOST_CROSSOVER = 16
 
-    def __init__(
-        self, host_threshold: int | None = None, backend: str | None = None
-    ):
-        import os
-
+    def __init__(self, host_threshold: int | None = None):
         from bftkv_tpu import ops
 
         ops.enable_compile_cache()
@@ -680,21 +676,13 @@ class SignerDomain:
                 flags.raw("BFTKV_HOST_SIGN_THRESHOLD", self.HOST_CROSSOVER)
             )
         self.host_threshold = host_threshold
-        #: "rns" (default): windowed modexp in the residue number
-        #: system — MXU matmul base extensions, ~10x the limb kernel at
-        #: large batch; "limb": the XLA Montgomery limb kernel.  Keys
-        #: the RNS path cannot take fall back to the limb kernel, then
-        #: to host.
-        self.backend = backend or flags.raw("BFTKV_SIGN_BACKEND", "rns")
-        if self.backend not in ("rns", "limb"):
-            raise ValueError(f"unknown sign backend {self.backend!r}")
-        self._doms: "OrderedDict[int, bigint.MontgomeryDomain | None]" = (
+        # key.n -> (dp, dq, qinv), or None for a key the pow chain has
+        # no rows for: one server signs every share with one key, so
+        # these per-key answers must not be recomputed per item.
+        self._crt: "OrderedDict[int, tuple[int, int, int] | None]" = (
             OrderedDict()
         )
-        # key.n -> (dp, dq, qinv): one server signs every share with one
-        # key, so these per-key constants must not be recomputed per item.
-        self._crt: "OrderedDict[int, tuple[int, int, int]]" = OrderedDict()
-        self._dom_lock = named_lock("crypto.rsa.montgomery")
+        self._crt_lock = named_lock("crypto.rsa.montgomery")
         #: Row widths (bits) whose pow programs are built.  None:
         #: nobody said, a launch compiles on first use.  The sidecar
         #: says after its warm-up; a sign at another width then goes to
@@ -704,34 +692,33 @@ class SignerDomain:
 
     _CACHE_MAX = 1024  # distinct private keys in one trust domain: few
 
-    def _dom(self, prime: int, nlimbs: int):
-        with self._dom_lock:
-            dom = self._doms.get(prime, False)
-            if dom is not False:
-                self._doms.move_to_end(prime)
-                return dom
-        try:
-            dom = bigint.MontgomeryDomain(prime, nlimbs)
-        except ValueError:
-            dom = None
-        with self._dom_lock:
-            self._doms[prime] = dom
-            if len(self._doms) > self._CACHE_MAX:
-                self._doms.popitem(last=False)
-        return dom
-
-    def _crt_params(self, key: "PrivateKey") -> tuple[int, int, int]:
-        with self._dom_lock:
-            p = self._crt.get(key.n)
-            if p is not None:
+    def _crt_params(
+        self, key: "PrivateKey", n_bits: int
+    ) -> tuple[int, int, int] | None:
+        """``(dp, dq, qinv)`` of a key whose CRT halves ride the pow
+        chain at ``n_bits``-bit rows; None for a key one of whose
+        "primes" has no rows there (even, or sharing a factor with a
+        channel prime: a tenant may REGISTER any p * q = n).  Asked
+        once a key, so that such a key costs its own items the device
+        and not its width group: ``power_mod_rns`` answers None for a
+        whole launch when one modulus has no rows."""
+        with self._crt_lock:
+            p = self._crt.get(key.n, False)
+            if p is not False:
                 self._crt.move_to_end(key.n)
                 return p
-        p = (
-            key.d % (key.p - 1),
-            key.d % (key.q - 1),
-            pow(key.q, -1, key.p),
-        )
-        with self._dom_lock:
+        from bftkv_tpu.ops import rns as rns_ops
+
+        ctx = rns_ops.pow_context(n_bits)
+        if ctx.key_rows(key.p) is None or ctx.key_rows(key.q) is None:
+            p = None
+        else:
+            p = (
+                key.d % (key.p - 1),
+                key.d % (key.q - 1),
+                pow(key.q, -1, key.p),
+            )
+        with self._crt_lock:
             self._crt[key.n] = p
             if len(self._crt) > self._CACHE_MAX:
                 self._crt.popitem(last=False)
@@ -741,38 +728,41 @@ class SignerDomain:
         """One RNS modexp launch for a width group: both CRT halves of
         every signature ride as rows with per-row modulus and secret
         exponent.  Returns False (leaving ``out`` untouched) when the
-        group cannot take the RNS path — caller falls back to the limb
-        kernel."""
+        launch cannot serve the group — the caller signs it on the
+        host tier."""
         from bftkv_tpu.ops import rns as rns_ops
 
         bases: list[int] = []
         exps: list[int] = []
         mods: list[int] = []
-        for _i, key, m, _domp, _domq, dp, dq, _qinv in group:
+        for _i, key, m, dp, dq, _qinv in group:
             bases += [m, m]
             exps += [dp, dq]
             mods += [key.p, key.q]
+        vals = None
         try:
             vals = rns_ops.power_mod_rns(
                 bases, exps, mods, n_bits=w * 16, op="sign"
             )
         except Exception:
-            # Unexpected kernel failure (the *expected* "can't take this
-            # key" signal is vals None): degrade to the limb path, but
-            # loudly — a silently broken RNS backend would misattribute
-            # every bench number.
-            metrics.incr("sign.rns_fallback")
-            log.exception("RNS sign path failed; falling back to limb kernel")
-            return False
+            log.exception("RNS sign launch failed")
         if vals is None:
+            # sign_batch asked everything power_mod_rns refuses a
+            # launch by (row width, rows of every prime), so None is
+            # as unexpected as a kernel failure.  Degrade, but loudly:
+            # a silently broken RNS backend would misattribute every
+            # bench number.
+            metrics.incr("sign.rns_fallback")
+            log.error(
+                "RNS sign path served no launch of %d signs at %d-bit "
+                "rows; signing them on the host tier", len(group), w * 16,
+            )
             return False
         count_tier("sign.device", (g[1].n for g in group))
         metrics.observe("sign.device_batch", len(group))
         sigs: list[tuple[int, object, int]] = []  # (item idx, key, s)
         with trace.leaf("flush.unpack", "sign", items=len(group)):
-            for j, (i, key, m, _domp, _domq, _dp, _dq, qinv) in enumerate(
-                group
-            ):
+            for j, (i, key, _m, _dp, _dq, qinv) in enumerate(group):
                 m1, m2 = vals[2 * j], vals[2 * j + 1]
                 h = (qinv * (m1 - m2)) % key.p
                 s = m2 + h * key.q
@@ -922,118 +912,47 @@ class SignerDomain:
                 from bftkv_tpu.ops import rns as rns_ops
 
                 for i, (message, key) in enumerate(items):
-                    lp = limb.nlimbs_for_bits(key.p.bit_length())
-                    lq = limb.nlimbs_for_bits(key.q.bit_length())
-                    w = max(lp, lq)
-                    if self.backend == "rns" and not (
-                        rns_ops.chains(16 * w).pow
-                        and rns_ops.pow_rows_warm(16 * w, self.warm_rows)
+                    w = limb.nlimbs_for_bits(
+                        max(key.p.bit_length(), key.q.bit_length())
+                    )
+                    crt = None
+                    if rns_ops.chains(16 * w).pow and rns_ops.pow_rows_warm(
+                        16 * w, self.warm_rows
                     ):
-                        # rows the bases cannot hold, or a program
-                        # nobody built: the host tier
-                        host_idx.append(i)
-                        continue
-                    domp = self._dom(key.p, w)
-                    domq = self._dom(key.q, w)
-                    if domp is None or domq is None:
+                        crt = self._crt_params(key, 16 * w)
+                    if crt is None:
+                        # rows the bases cannot hold, a program nobody
+                        # built, a "prime" without rows: the host tier
                         host_idx.append(i)
                         continue
                     m = emsa_pkcs1v15_sha256(message, key.size_bytes)
-                    dp, dq, qinv = self._crt_params(key)
-                    by_width.setdefault(w, []).append(
-                        (i, key, m, domp, domq, dp, dq, qinv)
-                    )
+                    by_width.setdefault(w, []).append((i, key, m, *crt))
+        for w, group in by_width.items():
+            if not self._sign_group_rns(w, group, out):
+                host_idx += [g[0] for g in group]
         if host_idx:
             for i, sig in zip(
                 host_idx, sign_many([items[i] for i in host_idx])
             ):
                 out[i] = sig
-        from bftkv_tpu.ops import rsa as rsa_ops
-
-        for w, group in by_width.items():
-            if self.backend == "rns" and self._sign_group_rns(w, group, out):
-                continue
-            rows_base, rows_e, rows_n, rows_np, rows_r2, rows_one = (
-                [], [], [], [], [], []
-            )
-            for _i, key, m, domp, domq, dp, dq, _qinv in group:
-                for prime, dom, dexp in (
-                    (key.p, domp, dp),
-                    (key.q, domq, dq),
-                ):
-                    rows_base.append(limb.int_to_limbs(m % prime, w))
-                    rows_e.append(limb.int_to_limbs(dexp, w))
-                    rows_n.append(dom.n)
-                    rows_np.append(dom.n_prime)
-                    rows_r2.append(dom.r2)
-                    rows_one.append(dom.one_mont)
-            # Pad to a power-of-two bucket (floor 32) so only a handful
-            # of kernel shapes ever compile.
-            k = len(rows_base)
-            padded = max(32, 1 << (k - 1).bit_length())
-            for _ in range(padded - k):
-                rows_base.append(rows_base[0])
-                rows_e.append(rows_e[0])
-                rows_n.append(rows_n[0])
-                rows_np.append(rows_np[0])
-                rows_r2.append(rows_r2[0])
-                rows_one.append(rows_one[0])
-            res = np.asarray(
-                rsa_ops.power_batch(
-                    np.stack(rows_base),
-                    np.stack(rows_e),
-                    np.stack(rows_n),
-                    np.stack(rows_np),
-                    np.stack(rows_r2),
-                    np.stack(rows_one),
-                )
-            )[:k]
-            vals = limb.limbs_to_ints(res)
-            count_tier("sign.device", (g[1].n for g in group))
-            sigs: list[tuple[int, object, int]] = []
-            for j, (i, key, m, _domp, _domq, _dp, _dq, qinv) in enumerate(group):
-                m1, m2 = vals[2 * j], vals[2 * j + 1]
-                h = (qinv * (m1 - m2)) % key.p
-                s = m2 + h * key.q
-                sigs.append((i, key, s))
-            # Same Boneh–DeMillo–Lipton gate as the RNS path: a single
-            # faulted CRT half from the limb kernel would leak the key
-            # via gcd(s^e − em, n) just the same (ADVICE r3 low 3).
-            ok = self._fault_check(sigs, group)
-            for (i, key, s), good, g in zip(sigs, ok, group):
-                if good:
-                    out[i] = s.to_bytes(key.size_bytes, "big")
-                else:
-                    metrics.incr("sign.fault")
-                    log.error(
-                        "limb sign fault check failed for one signature; "
-                        "re-signing on host"
-                    )
-                    out[i] = pow(g[2], key.d, key.n).to_bytes(
-                        key.size_bytes, "big"
-                    )
-        if host_idx:
             count_tier("sign.host", (items[i][1].n for i in host_idx))
         return out  # type: ignore[return-value]
 
 
 class VerifierDomain:
-    """Pre-encoded Montgomery parameters for a set of public keys, ready to
-    assemble ``(batch, L)`` operands for ``ops.rsa.verify_batch_e65537``.
+    """Batched RSA e = 65537 verify of ``[(message, sig, key)]``.
 
-    All keys in one domain share a limb width (2048-bit by default);
-    heterogeneous batches mix keys freely since every element carries its
-    own modulus row.  Three tiers, chosen per item by what the code can
-    observe of the key: the device chain, for e = 65537 on a sound
-    modulus the chain can take (``ops.rns.chains``); the native host
-    tier, for e = 65537 on a sound modulus it cannot — RSA-3072 and
+    Heterogeneous batches mix keys freely: the RNS verify chain
+    (``ops.rns.verify_e65537_rns_indexed``) gathers each row's key
+    constants on device.  Three tiers, chosen per item by what the
+    code can observe of the key: the device chain, for e = 65537 on a
+    sound modulus the chain can take (``ops.rns.chains``); the native
+    host tier, for e = 65537 on a sound modulus it cannot — RSA-3072 and
     wider identities — and for batches under the crossover; the host
     oracle, for a non-65537 exponent or a hostile modulus (even / zero
     / absurdly wide, reachable from attacker-embedded certificates),
     which fails closed.  Nothing raises out of the verification path.
     """
-
-    _CACHE_MAX = 4096  # moduli are attacker-influenced (embedded certs)
 
     #: Below this many items a batch verifies on host: a device launch
     #: costs ~tens of ms regardless of size, while a host e=65537 verify
@@ -1043,16 +962,12 @@ class VerifierDomain:
 
     def __init__(
         self,
-        nlimbs: int = 128,
         host_threshold: int | None = None,
         backend: str | None = None,
     ):
-        import os
-
         from bftkv_tpu import ops
 
         ops.enable_compile_cache()
-        self.nlimbs = nlimbs
         if host_threshold is None:
             host_threshold = flags.raw("BFTKV_HOST_VERIFY_THRESHOLD")
         #: Nobody chose the crossover (no argument, no flag, and no
@@ -1064,58 +979,19 @@ class VerifierDomain:
             self.HOST_CROSSOVER if host_threshold is None
             else int(host_threshold)
         )
-        #: "rns" (default): residue-number-system f32/MXU kernel, ~19x
-        #: the limb kernel at large batch; "limb": the XLA Montgomery
-        #: limb kernel; "pallas": the VMEM-resident limb chain. Keys
-        #: whose rows the RNS path cannot build (a factor shared with a
-        #: channel prime) fall back per item.
-        self.backend = backend or flags.raw("BFTKV_VERIFY_BACKEND", "rns")
-        if self.backend not in ("rns", "limb", "pallas"):
-            raise ValueError(f"unknown verify backend {self.backend!r}")
-        self._cache: "OrderedDict[int, bigint.MontgomeryDomain | None]" = (
-            OrderedDict()
-        )
-        # Pipelined dispatcher flushes call verify_batch from multiple
-        # worker threads; the LRU mutations must not race.
-        self._cache_lock = named_lock("crypto.rsa.verify_cache")
+        # ``backend`` selects nothing and reads no flag: one device
+        # chain is left.  The keyword is pinned by
+        # benchmarks/tests/test_stage_array_share.py, which passes
+        # "rns" and which only a ``benchmark`` issue may edit
+        # (ROADMAP D15).
+        if backend not in (None, "rns"):
+            raise ValueError(f"unknown verify backend {backend!r}")
         #: Whether the verify chain's programs are built.  None: nobody
         #: said, a launch compiles on first use.  The sidecar says after
         #: its warm-up (False where no declared identity width has a
         #: verify chain): a request then never compiles one.
         self.chain_warm: bool | None = None
         _count_staged(0, 0)  # exist from the start: 0 reads as 0
-
-    def _dom(self, n: int) -> bigint.MontgomeryDomain | None:
-        """Montgomery domain for ``n``, or None if ``n`` is unusable.
-
-        LRU-bounded: hostile packets can embed certificates with arbitrary
-        fresh moduli, so an unbounded cache would grow with attacker
-        traffic (one precomputation + dict entry per distinct n).
-        """
-        with self._cache_lock:
-            dom = self._cache.get(n, False)
-            if dom is not False:
-                self._cache.move_to_end(n)
-                return dom
-        try:
-            dom = bigint.MontgomeryDomain(n, self.nlimbs)
-        except ValueError:
-            dom = None
-        with self._cache_lock:
-            self._cache[n] = dom
-            if len(self._cache) > self._CACHE_MAX:
-                self._cache.popitem(last=False)
-        return dom
-
-    def _chain_takes(self, bits: int) -> bool:
-        """Whether this domain's device chain can take a sound modulus
-        of ``bits`` bits: the RNS chain by the bases' reach
-        (``ops.rns.chains``), the limb chains by the limb budget."""
-        if self.backend == "rns":
-            from bftkv_tpu.ops import rns
-
-            return rns.chains(bits).verify
-        return bits <= 16 * self.nlimbs
 
     @property
     def host_threshold(self) -> int:
@@ -1149,38 +1025,10 @@ class VerifierDomain:
             return on_cpu
         return False
 
-    def assemble(
-        self, items: list[tuple[bytes, bytes, PublicKey]]
-    ) -> tuple[np.ndarray, ...]:
-        """items = [(message, sig, key)] → operand arrays for the kernel.
-
-        Every key must have e = 65537 and a kernel-compatible modulus
-        (``verify_batch`` pre-filters; direct callers own that check).
-        """
-        sigs, ems, ns, nps, r2s = [], [], [], [], []
-        for message, sig_bytes, key in items:
-            dom = self._dom(key.n)
-            s = int.from_bytes(sig_bytes, "big")
-            if s >= key.n:
-                s = 0  # forces a mismatch; keeps shapes static
-            em = emsa_pkcs1v15_sha256(message, key.size_bytes)
-            sigs.append(limb.int_to_limbs(s, self.nlimbs))
-            ems.append(limb.int_to_limbs(em, self.nlimbs))
-            ns.append(dom.n)
-            nps.append(dom.n_prime)
-            r2s.append(dom.r2)
-        return (
-            np.stack(sigs),
-            np.stack(ems),
-            np.stack(ns),
-            np.stack(nps),
-            np.stack(r2s),
-        )
-
     def verify_batch(self, items: list[tuple[bytes, bytes, PublicKey]]) -> np.ndarray:
         """Batched TPU verify of [(message, sig, key)] → (batch,) bool."""
         from bftkv_tpu.crypto import cert as certmod  # lazy: cert imports rsa
-        from bftkv_tpu.ops import rsa as rsa_ops
+        from bftkv_tpu.ops import rns
 
         out = np.zeros((len(items),), dtype=bool)
         device_idx: list[int] = []
@@ -1197,9 +1045,7 @@ class VerifierDomain:
         # The tier split is the first interval of the launch's
         # flush.stage where the batch is bound for the RNS chain (the
         # second, in _verify_rns, builds the operands).
-        to_device = self.backend == "rns" and not self._stay_on_host(
-            len(items)
-        )
+        to_device = not self._stay_on_host(len(items))
         with (
             trace.leaf("flush.stage", "verify", items=len(items))
             if to_device else contextlib.nullcontext()
@@ -1225,7 +1071,7 @@ class VerifierDomain:
                         bits = key.n.bit_length()
                         t = takes.get(bits)
                         if t is None:
-                            t = takes[bits] = self._chain_takes(bits)
+                            t = takes[bits] = rns.chains(bits).verify
                         if t and self.chain_warm is False:
                             g = _KeyGroup(unwarmed_idx)
                         elif t:
@@ -1242,8 +1088,6 @@ class VerifierDomain:
         unwarmed = len(unwarmed_idx)
         wide_idx += unwarmed_idx
         if unwarmed:
-            from bftkv_tpu.ops import rns
-
             rns.note_unwarmed("verifies", max(takes), unwarmed)
         if ec_idx:
             from bftkv_tpu.crypto import ecdsa as _ecdsa
@@ -1264,7 +1108,7 @@ class VerifierDomain:
             wide = [items[i] for i in wide_idx]
             count_tier("verify.host", (k.n for _m, _s, k in wide))
             out[np.asarray(wide_idx)] = verify_host_many(wide)
-        if device_idx and self.backend == "rns":
+        if device_idx:
             self._verify_rns(
                 items,
                 device_idx,
@@ -1272,44 +1116,6 @@ class VerifierDomain:
                 [g for g in groups.values() if g.chain],
                 out,
             )
-        elif device_idx:
-            device_items = [items[i] for i in device_idx]
-            count_tier("verify.device", (k.n for _m, _s, k in device_items))
-            sig, em, n, npr, r2 = self.assemble(device_items)
-            k = len(device_items)
-            # Pad to a power-of-two bucket (floor 256): the kernel is jitted
-            # per shape, and XLA compilation is expensive on TPU — without
-            # bucketing, every distinct flush size from the dispatcher would
-            # compile a fresh program. Pad rows reuse row 0's modulus with
-            # sig=0 vs row 0's em, which can never verify; they are sliced
-            # off.
-            padded = max(256, 1 << (k - 1).bit_length())
-            if padded != k:
-                def pad(a, fill_from_row0):
-                    extra = np.broadcast_to(
-                        a[0] if fill_from_row0 else np.zeros_like(a[0]),
-                        (padded - k,) + a.shape[1:],
-                    )
-                    return np.concatenate([a, extra], axis=0)
-
-                sig = pad(sig, False)
-                em, n, npr, r2 = (pad(a, True) for a in (em, n, npr, r2))
-            if self.backend == "pallas":
-                import jax
-
-                from bftkv_tpu.ops import pallas_mont
-
-                ok = np.asarray(
-                    pallas_mont.verify_e65537(
-                        sig, em, n, npr, r2,
-                        interpret=jax.default_backend() not in ("tpu",),
-                    )
-                )[:k]
-            else:
-                ok = np.asarray(
-                    rsa_ops.verify_batch_e65537(sig, em, n, npr, r2)
-                )[:k]
-            out[np.asarray(device_idx)] = ok
         return out
 
     def _verify_rns(

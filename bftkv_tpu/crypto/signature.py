@@ -11,8 +11,9 @@ Capability parity with the reference's ``Signature`` and
   distinct valid signers (reference: crypto_pgp.go:477-519).
 
 TPU redesign: ``verify`` assembles **one batch** of (message, sig, key)
-triples across all signers and runs a single jitted modexp kernel
-(``bftkv_tpu.ops.rsa.verify_batch_e65537``) instead of the reference's
+triples across all signers and hands it to ``rsa.VerifierDomain``, whose
+device tier is a single jitted RNS verify chain
+(``bftkv_tpu.ops.rns.verify_e65537_rns_indexed``), instead of the reference's
 sequential per-signer ``CheckDetachedSignature`` loop — the O(n²)
 per-write cluster cost named in SURVEY.md §2.
 """

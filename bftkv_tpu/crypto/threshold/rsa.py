@@ -18,9 +18,10 @@ n-k (reference: docs/tex/method.tex:374-377).
 
 TPU redesign: a server's per-request fragment exponentiations — up to
 C(n-1, n-k)-ish modexps with exponents that *grow past the key size* at
-each tree level — run as ONE ``ops.rsa.power_batch`` launch over
-``(nfrag, L)`` limb arrays instead of the reference's sequential
-``big.Int.Exp`` loop.
+each tree level — run as ONE ``ops.modexp.BatchModExp`` launch (the
+RNS pow chain up to 2,048-bit operands, ``ops.modexp.power_batch`` over
+``(nfrag, L)`` limb arrays beyond) instead of the reference's
+sequential ``big.Int.Exp`` loop.
 """
 
 from __future__ import annotations

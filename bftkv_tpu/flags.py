@@ -190,11 +190,6 @@ _flag("BFTKV_NATIVE_CODEC", "auto", "str",
 _flag("BFTKV_OS_RNG", "", "switch",
       "`1` restores os.urandom for every secret draw (default: "
       "per-thread SHA-256 hash-DRBG reseeded from os.urandom).")
-_flag("BFTKV_SIGN_BACKEND", "rns", "str",
-      "RSA sign backend: `rns` windowed modexp (default), `bigint`, "
-      "`host`.")
-_flag("BFTKV_VERIFY_BACKEND", "rns", "str",
-      "RSA verify backend: `rns` (default), `bigint`, `host`.")
 _flag("BFTKV_HOST_SIGN_THRESHOLD", None, "int",
       "Batch size below which signs stay on host (unset: measured "
       "crossover from dispatcher calibration).")
@@ -202,7 +197,9 @@ _flag("BFTKV_HOST_VERIFY_THRESHOLD", None, "int",
       "Batch size below which verifies stay on host (unset: measured "
       "crossover from dispatcher calibration).")
 _flag("BFTKV_EC_BACKEND", "auto", "str",
-      "EC scalar-mul backend: `auto`, `device`, `host`.")
+      "EC scalar-mul backend: `limb` (`ops/ec.py`), `rns` "
+      "(`ops/ec_rns.py`), or `auto`: `rns` on a TPU backend, `limb` "
+      "elsewhere.")
 _flag("BFTKV_EC_SIGN_THRESHOLD", None, "int",
       "EC sign host/device crossover batch size (unset: built-in "
       "crossover constant).")

@@ -4,8 +4,10 @@ Every distributed-crypto subsystem in the reference bottoms out in
 ``big.Int.Exp`` loops — TPA's DH rounds (crypto/auth/auth.go), threshold
 RSA's per-fragment signing (crypto/threshold/rsa/rsa.go:140-178), and
 threshold DSA's partial-R combination (crypto/threshold/dsa/dsa.go:33-52).
-This engine replaces those per-item loops with one
-``ops.rsa.power_batch`` launch per request batch.
+This engine replaces those per-item loops with one launch per request
+batch: the RNS pow chain (``ops.rns.power_mod_rns``) for operands up to
+2,048 bits, :func:`power_batch` (the limb Montgomery engine) for the
+wider ones, which no RNS width class is built for.
 
 Policy: batches below ``min_batch`` (default 4, override with
 ``BFTKV_TPU_MIN_MODEXP_BATCH``) run as host ``pow`` — a single modexp
@@ -18,10 +20,37 @@ from __future__ import annotations
 import logging
 from collections import OrderedDict
 
+import jax
+import jax.numpy as jnp
 import numpy as np
-from bftkv_tpu import flags
 
-__all__ = ["BatchModExp"]
+from bftkv_tpu import flags
+from bftkv_tpu.ops import bigint
+
+__all__ = ["BatchModExp", "power_batch"]
+
+
+@jax.jit
+def power_batch(
+    base: jnp.ndarray,
+    e: jnp.ndarray,
+    n: jnp.ndarray,
+    n_prime: jnp.ndarray,
+    r2: jnp.ndarray,
+    one_mont: jnp.ndarray,
+) -> jnp.ndarray:
+    """base^e mod n with per-element full-width exponents, all operands
+    ``(batch, L)`` digit arrays.
+
+    The device path of operands wider than the RNS width classes:
+    threshold-RSA fragment exponents grow past the key size per tree
+    level (reference: crypto/threshold/rsa/rsa.go:97-117).
+    """
+    b_mont = bigint.to_mont(base, r2, n, n_prime)
+    v_mont = bigint.mont_exp(
+        b_mont, e, n, n_prime, jnp.broadcast_to(one_mont, b_mont.shape)
+    )
+    return bigint.from_mont(v_mont, n, n_prime)
 
 
 class BatchModExp:
@@ -41,8 +70,6 @@ class BatchModExp:
         return cls._shared
 
     def _domain(self, n: int, nlimbs: int):
-        from bftkv_tpu.ops import bigint
-
         key = (n, nlimbs)
         dom = self._domains.get(key)
         if dom is None:
@@ -67,13 +94,12 @@ class BatchModExp:
         if len(pairs) < self.min_batch or n % 2 == 0 or n <= 1:
             return [pow(b % n, e, n) for b, e in pairs]
         from bftkv_tpu.ops import limb
-        from bftkv_tpu.ops import rsa as rsa_ops
 
         nlimbs = limb.nlimbs_for_bits(n.bit_length())
         max_e = max(e for _, e in pairs)
 
-        # Prefer the RNS windowed-modexp kernel (~10x the limb kernel at
-        # batch): it covers moduli/exponents up to the context width.
+        # Prefer the RNS windowed-modexp kernel: it covers
+        # moduli/exponents up to the context width.
         # Sub-2^12 primes cannot fund a 4096-bit base pair, so wider
         # operands (threshold-RSA fragment exponents grow past the key
         # size per tree level, rsa.go:97-117) stay on the limb path.
@@ -120,7 +146,7 @@ class BatchModExp:
         dom = self._domain(n, nlimbs)
         base = limb.ints_to_limbs([b % n for b, _ in pairs], nlimbs)
         exp = limb.ints_to_limbs([e for _, e in pairs], e_limbs)
-        out = rsa_ops.power_batch(
+        out = power_batch(
             base,
             exp,
             np.broadcast_to(dom.n, base.shape),

@@ -1,7 +1,7 @@
 """RSA verification in a residue number system — MXU/f32-native bignum.
 
-The limb kernels (:mod:`bftkv_tpu.ops.bigint`, the Pallas variant) are
-bound by *emulated* 32-bit integer multiplies on the VPU — a 128-limb
+The limb Montgomery engine (:mod:`bftkv_tpu.ops.bigint`) is bound by
+*emulated* 32-bit integer multiplies on the VPU — a 128-limb
 Montgomery product is a 128-step convolution of digit products, and
 every digit product pays the int32-mul emulation tax. This module
 removes both the convolution and the integer arithmetic:
@@ -49,6 +49,7 @@ __all__ = [
     "chains",
     "RNSContext",
     "context",
+    "pow_context",
     "verify_e65537_rns",
     "flat_verify_fn",
     "stack_key_rows",
@@ -266,6 +267,13 @@ class RNSContext:
 @functools.lru_cache(maxsize=4)
 def context(digits: int = DIGITS, n_bits: int = 2048) -> RNSContext:
     return RNSContext(digits, n_bits)
+
+
+def pow_context(n_bits: int) -> RNSContext:
+    """The context of the pow chain at ``n_bits``-bit rows: a modulus
+    rides :func:`power_mod_rns` there when this context has
+    ``key_rows`` for it."""
+    return context(max(32, (n_bits + 15) // 16), n_bits)
 
 
 # ---------------------------------------------------------------------------
@@ -696,8 +704,8 @@ def power_mod_rns(
     for e in exps:
         if e < 0 or e.bit_length() > n_bits:
             return None
-    digits = max(32, (n_bits + 15) // 16)
-    ctx = context(digits, n_bits)
+    ctx = pow_context(n_bits)
+    digits = ctx.digits
     t = len(mods)
     ring = slot = None
     released = False

@@ -139,7 +139,7 @@ def role_kernels(args) -> int:
     for backend in ("pallas", "xla"):
         os.environ["BFTKV_RNS_VERIFY_BACKEND"] = backend
         os.environ["BFTKV_RNS_POW_BACKEND"] = backend
-        dom = rsa.VerifierDomain(host_threshold=0, backend="rns")
+        dom = rsa.VerifierDomain(host_threshold=0)
         got, first, steady = _timed(lambda: list(dom.verify_batch(items)))
         record["verify"][backend] = {
             "first_call_s": first, "call_s": steady,
@@ -267,8 +267,8 @@ def role_mesh(args) -> int:
     for mode in ("auto", "off"):
         shard_mode(mode)
         name = "sharded" if mode == "auto" else "single"
-        vd = rsa.VerifierDomain(host_threshold=0, backend="rns")
-        sd = rsa.SignerDomain(host_threshold=0, backend="rns")
+        vd = rsa.VerifierDomain(host_threshold=0)
+        sd = rsa.SignerDomain(host_threshold=0)
         ok, v_first, v_s = _timed(lambda: list(vd.verify_batch(items)), 1)
         sigs, s_first, s_s = _timed(lambda: sd.sign_batch(to_sign), 1)
         results[name] = ([bool(b) for b in ok], sigs)

@@ -119,6 +119,36 @@ def test_mont_exp(bits, ebits):
         assert g == pow(x, ei, n)
 
 
+@pytest.mark.parametrize("ebits", [2049, 4096])
+def test_modexp_engine_takes_exponents_no_rns_context_holds(
+    ebits, monkeypatch
+):
+    """The use that keeps ``ops.modexp.power_batch``: threshold-RSA
+    fragment exponents wider than 2,048 bits (up to 4,096) stay on the
+    device through the limb Montgomery engine, and equal ``pow``."""
+    from bftkv_tpu.ops import modexp, rns
+
+    rng = random.Random(ebits)
+    n = rng.getrandbits(512) | (1 << 511) | 1
+    pairs = [
+        (rng.getrandbits(500), rng.getrandbits(ebits) | (1 << (ebits - 1)))
+        for _ in range(4)
+    ]
+    monkeypatch.setattr(
+        rns, "power_mod_rns",
+        lambda *_a, **_k: pytest.fail("no RNS width class holds these"),
+    )
+    launches = []
+    real = modexp.power_batch
+    monkeypatch.setattr(
+        modexp, "power_batch",
+        lambda *a: launches.append(a[1].shape) or real(*a),
+    )
+    got = modexp.BatchModExp(min_batch=1).modexp(pairs, n)
+    assert got == [pow(b, e, n) for b, e in pairs]
+    assert launches == [(4, 256)]  # one launch, the 4,096-bit bucket
+
+
 def test_mont_exp_shared_exponent():
     # Exponent broadcast from a single shared vector (e.g. fixed e).
     n = rand_odd(256)

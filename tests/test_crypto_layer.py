@@ -120,7 +120,7 @@ def test_collective_combine_and_verify(identities):
     ring = keyring.Keyring()
     for _, c in identities:
         ring.register([c])
-    cs = signature.CollectiveSignature(rsa.VerifierDomain(nlimbs=64))
+    cs = signature.CollectiveSignature(rsa.VerifierDomain())
     q = FixedQuorum(3)
     ss = None
     done = False
@@ -140,7 +140,7 @@ def test_collective_combine_and_verify(identities):
 
 def test_collective_verify_without_keyring_uses_embedded_certs(identities):
     tbss = b"payload"
-    cs = signature.CollectiveSignature(rsa.VerifierDomain(nlimbs=64))
+    cs = signature.CollectiveSignature(rsa.VerifierDomain())
     q = FixedQuorum(2)
     ss = None
     for key, c in identities[:2]:
@@ -152,7 +152,7 @@ def test_collective_verify_without_keyring_uses_embedded_certs(identities):
 
 def test_duplicate_signer_counted_once(identities):
     tbss = b"dup"
-    cs = signature.CollectiveSignature(rsa.VerifierDomain(nlimbs=64))
+    cs = signature.CollectiveSignature(rsa.VerifierDomain())
     key, c = identities[0]
     q = FixedQuorum(2)
     ss = None

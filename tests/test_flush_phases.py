@@ -13,7 +13,6 @@ a chip run.
 
 from __future__ import annotations
 
-import functools
 import os
 import re
 import socket
@@ -33,7 +32,7 @@ from bftkv_tpu import admission, ops, trace  # noqa: E402
 from bftkv_tpu.cmd import verify_sidecar as vs  # noqa: E402
 from bftkv_tpu.crypto import remote_verify, rsa  # noqa: E402
 from bftkv_tpu.metrics import registry as metrics  # noqa: E402
-from bftkv_tpu.ops import dispatch, ec_rns, pallas_mont, pallas_rns, rns  # noqa: E402
+from bftkv_tpu.ops import dispatch, ec_rns, pallas_rns, rns  # noqa: E402
 
 FLUSH_PHASES = ("flush.stage", "flush.launch", "flush.fetch", "flush.unpack")
 PHASES = ("dispatch.linger", *FLUSH_PHASES, "flush.scatter",
@@ -303,8 +302,7 @@ def test_with_tracing_off_a_span_records_nothing_and_calls_nothing(
 def _module_name(jitted, *args) -> str:
     if args == (None,):
         return "jit_" + jitted.__wrapped__.__name__
-    lower = getattr(jitted, "lower", jitted)  # or a bound .lower itself
-    return re.search(r"module @(\S+)", lower(*args).as_text()).group(1)
+    return re.search(r"module @(\S+)", jitted.lower(*args).as_text()).group(1)
 
 
 def _shapes(tree):
@@ -346,9 +344,6 @@ def _served_path_modules():
     pcv = pallas_rns._pad_consts(d, 2048)
     yield "jit_rns_verify_prep", pallas_rns._verify_prep(pcv.k, pcv.kpad), (
         idx, key)
-    u32 = jax.ShapeDtypeStruct((pallas_mont.TILE, pallas_mont.L), np.uint32)
-    yield "jit_verify_e65537", functools.partial(
-        pallas_mont.verify_e65537.lower, interpret=True), (u32,) * 5
 
 
 def test_no_program_of_the_served_path_is_called_g():
@@ -362,8 +357,8 @@ def test_no_program_of_the_served_path_is_called_g():
 
 
 def test_pallas_chains_carry_their_names():
-    src = open(pallas_rns.__file__).read() + open(pallas_mont.__file__).read()
-    for name in ("rns_pow_chain", "rns_verify_chain", "mont_verify_chain"):
+    src = open(pallas_rns.__file__).read()
+    for name in ("rns_pow_chain", "rns_verify_chain"):
         assert f'name="{name}"' in src
     for fn in (pallas_rns._pow_call(32, 512, 8, True),
                pallas_rns._verify_call(rns.DIGITS, 2048, 8, True)):

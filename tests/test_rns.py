@@ -2,8 +2,8 @@
 
 Covers the Bajard/Shenoy base-extension math on real signatures, mixed
 key sizes, adversarial inputs (bit flips, wrong keys, sig >= n, hostile
-moduli sharing a factor with a channel prime), and backend equivalence
-through VerifierDomain.
+moduli sharing a factor with a channel prime), and the device chain
+against the host oracle through VerifierDomain.
 """
 
 import numpy as np
@@ -64,31 +64,37 @@ def test_rns_wrong_key_rejected(keys):
     assert not got.any()
 
 
-@pytest.mark.slow  # tier-2: heavy on a small-CPU tier-1 box (see pytest.ini)
-def test_verifier_domain_backends_agree(keys):
-    """All three device backends (rns / limb / pallas) return identical
-    verdicts on the same adversarial batch."""
+def test_verifier_domain_device_chain_agrees_with_host_oracle(keys):
+    """The device chain and the host oracle (``verify_host_many``)
+    return identical verdicts on the same adversarial batch."""
     key = keys[0]
     sig = rsa.sign(b"m", key)
+    s = int.from_bytes(sig, "big")
     items = [
         (b"m", sig, key.public),
         (b"x", sig, key.public),
         (b"m", sig, keys[1].public),
         (b"m", (key.n + 5).to_bytes(key.size_bytes + 1, "big"), key.public),
+        (b"m", sig[:-1] + bytes([sig[-1] ^ 1]), key.public),  # forged
+        (b"m", sig[1:], key.public),  # short
+        (b"m", b"\x00\x00" + sig, key.public),  # over-long, same integer
+        (b"m", (s + key.n).to_bytes(key.size_bytes + 1, "big"), key.public),
+        (b"m", key.n.to_bytes(key.size_bytes, "big"), key.public),  # s = n
     ]
-    results = {}
-    for backend in ("rns", "limb", "pallas"):
-        dom = rsa.VerifierDomain(host_threshold=0, backend=backend)
-        results[backend] = list(dom.verify_batch(items))
-    assert (
-        results["rns"] == results["limb"] == results["pallas"]
-        == [True, False, False, False]
-    )
+    dom = rsa.VerifierDomain(host_threshold=0)
+    got = list(dom.verify_batch(items))
+    assert got == rsa.verify_host_many(items)
+    assert got == [True, False, False, False, False, False, True, False, False]
 
 
 def test_backend_name_validated():
-    with pytest.raises(ValueError):
-        rsa.VerifierDomain(backend="rsn")
+    """``backend`` is a pinned keyword that selects nothing: the one
+    chain's name or None pass, anything else is refused."""
+    for name in ("rsn", "limb", "pallas"):
+        with pytest.raises(ValueError):
+            rsa.VerifierDomain(backend=name)
+    rsa.VerifierDomain(backend="rns")
+    rsa.VerifierDomain(backend=None)
 
 
 def test_hostile_modulus_falls_back(keys):

@@ -49,7 +49,7 @@ def test_sign_matches_cryptography_oracle(keys):
 def test_verify_batch_tpu(keys):
     # host_threshold=0 forces the device kernel even for a small batch —
     # this test also covers the power-of-two padding path (8 → 256 rows).
-    dom = rsa.VerifierDomain(nlimbs=128, host_threshold=0)
+    dom = rsa.VerifierDomain(host_threshold=0)
     msgs = [f"msg-{i}".encode() for i in range(6)]
     items = []
     for i, m in enumerate(msgs):
@@ -64,7 +64,7 @@ def test_verify_batch_tpu(keys):
 
 
 def test_verify_batch_oversize_sig(keys):
-    dom = rsa.VerifierDomain(nlimbs=128, host_threshold=0)
+    dom = rsa.VerifierDomain(host_threshold=0)
     key = keys[0]
     bad_sig = (key.n + 1).to_bytes(key.size_bytes + 1, "big")
     ok = dom.verify_batch([(b"m", bad_sig, key.public)])
@@ -92,6 +92,97 @@ def test_sign_batch_host_crossover(keys):
     dom = rsa.SignerDomain(host_threshold=64)
     items = [(b"a", keys[0]), (b"b", keys[1])]
     assert dom.sign_batch(items) == [rsa.sign(b"a", keys[0]), rsa.sign(b"b", keys[1])]
+
+
+def oracle_sign(message: bytes, key: rsa.PrivateKey) -> bytes:
+    em = rsa.emsa_pkcs1v15_sha256(message, key.size_bytes)
+    return pow(em, key.d, key.n).to_bytes(key.size_bytes, "big")
+
+
+@pytest.mark.parametrize("failure", ["returns_none", "raises"])
+def test_group_the_pow_chain_cannot_serve_is_signed_on_the_host(
+    keys, monkeypatch, failure
+):
+    """The one fallback of the one sign chain: the host tier, counted
+    and never a limb program."""
+    from bftkv_tpu.metrics import registry as metrics
+    from bftkv_tpu.ops import bigint, rns
+
+    def power_mod_rns(*_a, **_k):
+        if failure == "raises":
+            raise RuntimeError("planted kernel failure")
+        return None
+
+    monkeypatch.setattr(rns, "power_mod_rns", power_mod_rns)
+    monkeypatch.setattr(
+        bigint, "mont_exp", lambda *_a: pytest.fail("limb program traced")
+    )
+    items = [(b"fb-%d" % i, keys[i % len(keys)]) for i in range(5)]
+    metrics.reset()
+    sigs = rsa.SignerDomain(host_threshold=0).sign_batch(items)
+    assert sigs == [oracle_sign(m, k) for m, k in items]
+    m = metrics.snapshot()
+    assert m["sign.rns_fallback"] == 1  # one width group, one launch lost
+    assert m["sign.host"] == m["sign.host.bits{bits=1024}"] == 5
+    assert "sign.device" not in m
+
+
+@pytest.mark.parametrize("kind", ["even", "channel_prime_factor"])
+def test_one_malformed_key_costs_its_own_items_the_device(
+    keys, monkeypatch, kind
+):
+    """A tenant may REGISTER any p * q = n.  A "prime" the pow chain
+    has no rows for sends that key's items to the host tier; the sound
+    keys of its width group still ride one launch."""
+    from bftkv_tpu.metrics import registry as metrics
+    from bftkv_tpu.ops import rns
+
+    good = keys[0]
+    ctx = rns.pow_context(512)
+    if kind == "even":
+        p = good.p + 1
+    else:
+        c = ctx.pb[0]
+        p = c * ((good.p // c - 2) | 1)  # odd, below good.p, same width
+    assert p.bit_length() == good.p.bit_length()
+    assert ctx.key_rows(p) is None and ctx.key_rows(good.q) is not None
+    bad = rsa.PrivateKey(n=p * good.q, e=good.e, d=good.d, p=p, q=good.q)
+    items = [(b"mk-%d" % i, keys[i % len(keys)]) for i in range(7)]
+    items.insert(4, (b"mk-bad", bad))
+
+    launches = []
+    real = rns.power_mod_rns
+
+    def spy(bases, exps, mods, **kw):
+        launches.append(len(mods))
+        return real(bases, exps, mods, **kw)
+
+    monkeypatch.setattr(rns, "power_mod_rns", spy)
+    metrics.reset()
+    sd = rsa.SignerDomain(host_threshold=0)
+    sigs = sd.sign_batch(items)
+    assert sigs.pop(4) == rsa.sign_many([items.pop(4)])[0]
+    assert sigs == [oracle_sign(m, k) for m, k in items]
+    assert launches == [14]  # both CRT halves of the seven sound items
+    m = metrics.snapshot()
+    assert m["sign.device"] == 7 and m["sign.host"] == 1
+    assert "sign.rns_fallback" not in m
+    # asked once a key: the answer is kept beside the CRT constants
+    assert sd._crt[bad.n] is None and len(sd._crt) == 1 + len(keys)
+
+
+@pytest.mark.parametrize("op", ["VERIFY", "SIGN"])
+def test_backend_flags_are_undeclared(op, monkeypatch):
+    """One chain an operation: a stale environment that still names a
+    backend is read by nobody, and a read of the name raises."""
+    from bftkv_tpu import flags
+
+    name = f"BFTKV_{op}_BACKEND"  # spelled out nowhere in the tree
+    monkeypatch.setenv(name, "limb")
+    with pytest.raises(KeyError):
+        flags.raw(name)
+    rsa.VerifierDomain()
+    rsa.SignerDomain()
 
 
 def test_sign_dispatcher_end_to_end(keys):
@@ -122,7 +213,7 @@ def test_sign_dispatcher_end_to_end(keys):
 def test_verify_batch_host_crossover(keys):
     """Small batches route to the host oracle (device launches only pay
     off past a few hundred items); results are identical either way."""
-    dom = rsa.VerifierDomain(nlimbs=128, host_threshold=64)
+    dom = rsa.VerifierDomain(host_threshold=64)
     key = keys[0]
     sig = rsa.sign(b"m", key)
     ok = dom.verify_batch([(b"m", sig, key.public), (b"x", sig, key.public)])
