@@ -654,7 +654,7 @@ class SignerDomain:
 
     Each signature is two half-width modexps (mod p and mod q), batched
     across concurrent requests into one RNS pow launch a row width
-    (``ops.rns.power_mod_rns``) — both halves of every signature ride
+    (``ops.rns.pow_rows_rns``) — both halves of every signature ride
     in the *same* launch — plus a host-side CRT recombination and a
     fault check of every output before release.  An item rides when
     ``ops.rns`` takes its key: rows of a width the pow chain holds
@@ -676,55 +676,59 @@ class SignerDomain:
                 flags.raw("BFTKV_HOST_SIGN_THRESHOLD", self.HOST_CROSSOVER)
             )
         self.host_threshold = host_threshold
-        # key.n -> (dp, dq, qinv), or None for a key the pow chain has
-        # no rows for: one server signs every share with one key, so
-        # these per-key answers must not be recomputed per item.
-        self._crt: "OrderedDict[int, tuple[int, int, int] | None]" = (
-            OrderedDict()
-        )
+        # key.n -> _SignKey, or None for a key the pow chain has no
+        # rows for: one server signs every share with one key, so what
+        # is a constant of the key is computed once a key.
+        self._crt: "OrderedDict[int, _SignKey | None]" = OrderedDict()
         self._crt_lock = named_lock("crypto.rsa.montgomery")
         #: Row widths (bits) whose pow programs are built.  None:
         #: nobody said, a launch compiles on first use.  The sidecar
         #: says after its warm-up; a sign at another width then goes to
         #: the host tier and never compiles inside a request.
         self.warm_rows: frozenset | None = None
-        _count_staged(0, 0)  # the fault check's: a scrape finds both
+        # exist from the start (a scrape finds them; 0 reads as 0): the
+        # fault check's, this domain's, its launches' key table's
+        _count_staged(0, 0)
+        _count_staged(0, 0, "sign")
+        metrics.incr("pow.keytable.upload", 0)
 
     _CACHE_MAX = 1024  # distinct private keys in one trust domain: few
 
-    def _crt_params(
-        self, key: "PrivateKey", n_bits: int
-    ) -> tuple[int, int, int] | None:
-        """``(dp, dq, qinv)`` of a key whose CRT halves ride the pow
-        chain at ``n_bits``-bit rows; None for a key one of whose
-        "primes" has no rows there (even, or sharing a factor with a
-        channel prime: a tenant may REGISTER any p * q = n).  Asked
-        once a key, so that such a key costs its own items the device
-        and not its width group: ``power_mod_rns`` answers None for a
-        whole launch when one modulus has no rows."""
+    def _sign_key(self, key: "PrivateKey") -> "_SignKey | None":
+        """The constants of ``key`` that a sign launch needs
+        (:class:`_SignKey`); None for a key whose CRT halves cannot
+        ride the pow chain: rows the bases cannot hold
+        (``rns.chains``), or a "prime" without rows at that width
+        (even, or sharing a factor with a channel prime: a tenant may
+        REGISTER any p * q = n).  Asked once a key, so that such a key
+        costs its own items the device and not its width group:
+        ``pow_rows_rns`` answers None for a whole launch when one
+        modulus has no rows."""
         with self._crt_lock:
-            p = self._crt.get(key.n, False)
-            if p is not False:
+            rec = self._crt.get(key.n, False)
+            if rec is not False:
                 self._crt.move_to_end(key.n)
-                return p
+                return rec
         from bftkv_tpu.ops import rns as rns_ops
 
-        ctx = rns_ops.pow_context(n_bits)
-        if ctx.key_rows(key.p) is None or ctx.key_rows(key.q) is None:
-            p = None
-        else:
-            p = (
-                key.d % (key.p - 1),
-                key.d % (key.q - 1),
-                pow(key.q, -1, key.p),
-            )
+        bits = 16 * limb.nlimbs_for_bits(
+            max(key.p.bit_length(), key.q.bit_length())
+        )
+        rec = None
+        if rns_ops.chains(bits).pow:
+            ctx = rns_ops.pow_context(bits)
+            if (
+                ctx.key_rows(key.p) is not None
+                and ctx.key_rows(key.q) is not None
+            ):
+                rec = _SignKey(key, bits, ctx.digits)
         with self._crt_lock:
-            self._crt[key.n] = p
+            self._crt[key.n] = rec
             if len(self._crt) > self._CACHE_MAX:
                 self._crt.popitem(last=False)
-        return p
+        return rec
 
-    def _sign_group_rns(self, w: int, group: list, out: list) -> bool:
+    def _sign_lane_rns(self, lane: "_SignLane", out: list) -> bool:
         """One RNS modexp launch for a width group: both CRT halves of
         every signature ride as rows with per-row modulus and secret
         exponent.  Returns False (leaving ``out`` untouched) when the
@@ -732,22 +736,21 @@ class SignerDomain:
         host tier."""
         from bftkv_tpu.ops import rns as rns_ops
 
-        bases: list[int] = []
-        exps: list[int] = []
-        mods: list[int] = []
-        for _i, key, m, dp, dq, _qinv in group:
-            bases += [m, m]
-            exps += [dp, dq]
-            mods += [key.p, key.q]
+        t = len(lane.idx)
         vals = None
         try:
-            vals = rns_ops.power_mod_rns(
-                bases, exps, mods, n_bits=w * 16, op="sign"
+            vals = rns_ops.pow_rows_rns(
+                lane.bits,
+                [m for rec in lane.keys for m in (rec.p, rec.q)],
+                lane.row_mod, lane.base_bytes,
+                np.concatenate([rec.nib for rec in lane.keys]),
+                lane.row_mod,  # dp rides with p, dq with q
+                op="sign",
             )
         except Exception:
             log.exception("RNS sign launch failed")
         if vals is None:
-            # sign_batch asked everything power_mod_rns refuses a
+            # sign_batch asked everything pow_rows_rns refuses a
             # launch by (row width, rows of every prime), so None is
             # as unexpected as a kernel failure.  Degrade, but loudly:
             # a silently broken RNS backend would misattribute every
@@ -755,27 +758,36 @@ class SignerDomain:
             metrics.incr("sign.rns_fallback")
             log.error(
                 "RNS sign path served no launch of %d signs at %d-bit "
-                "rows; signing them on the host tier", len(group), w * 16,
+                "rows; signing them on the host tier", t, lane.bits,
             )
             return False
-        count_tier("sign.device", (g[1].n for g in group))
-        metrics.observe("sign.device_batch", len(group))
-        sigs: list[tuple[int, object, int]] = []  # (item idx, key, s)
-        with trace.leaf("flush.unpack", "sign", items=len(group)):
-            for j, (i, key, _m, _dp, _dq, qinv) in enumerate(group):
-                m1, m2 = vals[2 * j], vals[2 * j + 1]
-                h = (qinv * (m1 - m2)) % key.p
-                s = m2 + h * key.q
-                sigs.append((i, key, s))
+        count_tier(
+            "sign.device", (rec.n for rec in lane.keys),
+            np.bincount(lane.ksel, minlength=len(lane.keys)),
+        )
+        metrics.observe("sign.device_batch", t)
+        ss: list[int] = []
+        s_bytes: list[bytes] = []  # made once: the check's rows, out[i]
+        with trace.leaf("flush.unpack", "sign", items=t):
+            halves = iter(vals)
+            for k, m1, m2 in zip(lane.ksel, halves, halves):
+                rec = lane.keys[k]
+                s = m2 + ((rec.qinv * (m1 - m2)) % rec.p) * rec.q
+                ss.append(s)
+                s_bytes.append(s.to_bytes(rec.size, "big"))
         # Fault check (Boneh–DeMillo–Lipton): one silently wrong CRT
         # half would let any observer factor the modulus via
         # gcd(s^e − em, n).  Verify every output before release — one
         # cheap e=65537 batch (17 modmuls) against the 1280-modmul
         # sign — and re-sign faulted items on the host.
-        ok = self._fault_check(sigs, group)
-        for (i, key, s), good, g in zip(sigs, ok, group):
+        ok = self._fault_check(
+            lane.item_keys, ss, lane.ems, s_bytes, lane.em_bytes
+        )
+        for i, key, em, sig, good in zip(
+            lane.idx, lane.item_keys, lane.ems, s_bytes, ok
+        ):
             if good:
-                out[i] = s.to_bytes(key.size_bytes, "big")
+                out[i] = sig
             else:
                 metrics.incr("sign.fault")
                 log.error(
@@ -784,33 +796,38 @@ class SignerDomain:
                 )
                 # Straight pow, no CRT: after a fault, produce the
                 # signature by the most fault-immune route available.
-                out[i] = pow(g[2], key.d, key.n).to_bytes(
+                out[i] = pow(em, key.d, key.n).to_bytes(
                     key.size_bytes, "big"
                 )
         return True
 
     @staticmethod
-    def _fault_check(sigs: list, group: list) -> list[bool]:
+    def _fault_check(
+        keys: list, ss: list[int], ems: list[int],
+        s_bytes: list[bytes], em_bytes: list[bytes],
+    ) -> list[bool]:
         """s^65537 ≡ em (mod n) for every produced signature: one RNS
         verify launch for the moduli the verify chain can take
         (``ops.rns.chains``), one native host batch for the sound
-        moduli it cannot (RSA-3072 and wider), ``pow`` for the rest."""
+        moduli it cannot (RSA-3072 and wider), ``pow`` for the rest.
+        Item for item: the key, the signature and the encoded message
+        as integers, and the same two as the big-endian byte strings
+        of the key's size that their maker already holds."""
         from bftkv_tpu.ops import rns as rns_ops
 
-        ems = [g[2] for g in group]
         ctx = None
         groups: dict = {}  # (n, e) -> _KeyGroup: asked once a key
-        lane = None
+        lanes: dict[int, _Lane] = {}  # key size in bytes -> its rows
         urows: list = []
         idxs: list[int] = []
         device_pos: list[int] = []
         host_pos: list[int] = []
         pulled = 0
-        ok = [False] * len(sigs)
+        ok = [False] * len(ss)
         # The check is a verify launch of its own — stage, launch,
         # fetch, unpack — under the op of the sign it polices.
-        with trace.leaf("flush.stage", "sign", items=len(sigs)) as stage:
-            for pos, ((_i, key, s), em) in enumerate(zip(sigs, ems)):
+        with trace.leaf("flush.stage", "sign", items=len(ss)) as stage:
+            for pos, key in enumerate(keys):
                 g = groups.get((key.n, key.e))
                 if g is None:
                     rows = None
@@ -825,25 +842,30 @@ class SignerDomain:
                         host_pos if rows is None else device_pos, chain, key.n
                     )
                     g.rows = rows
+                    if rows is not None:
+                        # the integers as whole rows, encodings included
+                        g.lane = lanes.get(g.size)
+                        if g.lane is None:
+                            g.lane = lanes[g.size] = _Lane(g.size, g.size)
                 g.idx.append(pos)
                 if g.rows is None:
                     pulled += g.chain
                     continue
-                if lane is None:
-                    # the integers as whole rows, encodings included
-                    lane = _Lane(2 * ctx.digits, 2 * ctx.digits)
                 if g.slot < 0:
                     g.slot = len(urows)
                     urows.append(g.rows)
+                lane = g.lane
                 lane.pos.append(len(idxs))
-                lane.sigs.append(s.to_bytes(lane.size, "big"))
-                lane.tails.append(em.to_bytes(lane.size, "big"))
+                lane.sigs.append(s_bytes[pos])
+                lane.tails.append(em_bytes[pos])
                 idxs.append(g.slot)
             _count_staged(len(device_pos), pulled)
             if device_pos:
                 k = len(device_pos)
                 bits = 16 * ctx.digits
-                staged = _stage_verify_operands(ctx, [lane], idxs, urows)
+                staged = _stage_verify_operands(
+                    ctx, list(lanes.values()), idxs, urows
+                )
                 padded = len(staged[2])
                 stage.attrs.update(bucket=padded, bits=bits)
         if host_pos:
@@ -855,13 +877,13 @@ class SignerDomain:
                 t0 = time.perf_counter()
                 native: list[int] = []
                 for pos in host_pos:
-                    _i, key, s = sigs[pos]
+                    key, s = keys[pos], ss[pos]
                     if _MM is not None and _sound_f4(key) and s < key.n:
                         native.append(pos)
                     else:
                         ok[pos] = pow(s, key.e, key.n) == ems[pos]
                 for pos, good in zip(native, _rows_match(
-                    [(sigs[pos][2], ems[pos], sigs[pos][1]) for pos in native]
+                    [(ss[pos], ems[pos], keys[pos]) for pos in native]
                 )):
                     ok[pos] = good
                 _count_host_batch(
@@ -884,8 +906,8 @@ class SignerDomain:
             import secrets as _secrets
 
             spot = device_pos[_secrets.randbelow(len(device_pos))]
-            _i, skey, sval = sigs[spot]
-            host_ok = pow(sval, skey.e, skey.n) == ems[spot]
+            skey = keys[spot]
+            host_ok = pow(ss[spot], skey.e, skey.n) == ems[spot]
             if host_ok != ok[spot]:
                 metrics.incr("sign.fault_check_divergence")
                 log.error(
@@ -898,38 +920,68 @@ class SignerDomain:
     def sign_batch(self, items: list[tuple[bytes, "PrivateKey"]]) -> list[bytes]:
         """[(message, key)] → [signature bytes], batched on device."""
         out: list[bytes | None] = [None] * len(items)
-        # Group device-eligible halves by limb width (p and q of one key
-        # always share a width; different key sizes go in separate
-        # launches so shapes stay uniform).
-        by_width: dict[int, list] = {}
+        # Device-eligible halves ride one launch a row width (p and q
+        # of one key always share a width; different key sizes go in
+        # separate launches so shapes stay uniform).
+        lanes: dict[int, _SignLane] = {}
         host_idx: list[int] = []
         if len(items) < self.host_threshold:
             host_idx = list(range(len(items)))
         else:
-            # per-item encodings and CRT constants: staging of the
-            # launches below, the first interval of their flush.stage
+            # Encodings and reduced bases: staging of the launches
+            # below, the first interval of their flush.stage.  A flush
+            # repeats a handful of keys hundreds of times: what a key
+            # decides is asked once a distinct key, an item costs a
+            # lookup of its key, SHA-256 and two reductions.
             with trace.leaf("flush.stage", "sign", items=len(items)):
                 from bftkv_tpu.ops import rns as rns_ops
 
-                for i, (message, key) in enumerate(items):
-                    w = limb.nlimbs_for_bits(
-                        max(key.p.bit_length(), key.q.bit_length())
-                    )
-                    crt = None
-                    if rns_ops.chains(16 * w).pow and rns_ops.pow_rows_warm(
-                        16 * w, self.warm_rows
+                groups: dict[int, _SignGroup] = {}  # key.n -> its items'
+                owners: list[_SignGroup] = []  # beside items
+                for _message, key in items:
+                    g = groups.get(key.n)
+                    if g is None:
+                        g = groups[key.n] = _SignGroup(self._sign_key(key))
+                    g.count += 1
+                    owners.append(g)
+                for g in groups.values():
+                    # rows the bases cannot hold or a "prime" without
+                    # rows (no record), a program nobody built: the
+                    # host tier
+                    rec = g.rec
+                    if rec is None or not rns_ops.pow_rows_warm(
+                        rec.bits, self.warm_rows, g.count
                     ):
-                        crt = self._crt_params(key, 16 * w)
-                    if crt is None:
-                        # rows the bases cannot hold, a program nobody
-                        # built, a "prime" without rows: the host tier
+                        continue
+                    lane = lanes.get(rec.bits)
+                    if lane is None:
+                        lane = lanes[rec.bits] = _SignLane(rec.bits)
+                    g.lane, g.k = lane, len(lane.keys)
+                    lane.keys.append(rec)
+                sha256 = hashlib.sha256
+                for i, ((message, key), g) in enumerate(zip(items, owners)):
+                    lane = g.lane
+                    if lane is None:
                         host_idx.append(i)
                         continue
-                    m = emsa_pkcs1v15_sha256(message, key.size_bytes)
-                    by_width.setdefault(w, []).append((i, key, m, *crt))
-        for w, group in by_width.items():
-            if not self._sign_group_rns(w, group, out):
-                host_idx += [g[0] for g in group]
+                    rec = g.rec
+                    em_b = rec.head + sha256(message).digest()
+                    em = int.from_bytes(em_b, "big")
+                    lane.idx.append(i)
+                    lane.item_keys.append(key)
+                    lane.ksel.append(g.k)
+                    lane.em_bytes.append(em_b)
+                    lane.ems.append(em)
+                    lane.base.append((em % rec.p).to_bytes(rec.row, "little"))
+                    lane.base.append((em % rec.q).to_bytes(rec.row, "little"))
+                for lane in lanes.values():
+                    lane.close()
+                _count_staged(
+                    len(items) - len(host_idx), len(host_idx), "sign"
+                )
+        for lane in lanes.values():
+            if not self._sign_lane_rns(lane, out):
+                host_idx += lane.idx
         if host_idx:
             for i, sig in zip(
                 host_idx, sign_many([items[i] for i in host_idx])
@@ -937,6 +989,78 @@ class SignerDomain:
                 out[i] = sig
             count_tier("sign.host", (items[i][1].n for i in host_idx))
         return out  # type: ignore[return-value]
+
+
+class _SignKey:
+    """What a sign launch needs of one private key, computed once a
+    key and kept on ``SignerDomain._crt``: the CRT constants, the row
+    width its halves ride at, and the two secret exponents as the
+    pow chain scans them (``rns.exp_nibbles``: dp, then dq)."""
+
+    __slots__ = (
+        "bits", "row", "n", "p", "q", "qinv", "size", "head", "nib",
+    )
+
+    def __init__(self, key: "PrivateKey", bits: int, digits: int):
+        from bftkv_tpu.ops import rns as rns_ops
+
+        self.bits = bits  # of a row: p, q and the exponents fit
+        self.row = 2 * digits  # a row's bytes
+        self.n, self.p, self.q = key.n, key.p, key.q
+        self.qinv = pow(key.q, -1, key.p)
+        self.size = key.size_bytes  # a signature's length
+        self.head = _emsa_head(self.size)
+        self.nib = rns_ops.exp_nibbles(
+            [key.d % (key.p - 1), key.d % (key.q - 1)], digits
+        )
+
+
+class _SignGroup:
+    """One distinct key of a sign flush: its record, how many items
+    name it, and — when its rows ride — the lane of its width and its
+    place among that lane's keys."""
+
+    __slots__ = ("rec", "count", "lane", "k")
+
+    def __init__(self, rec: "_SignKey | None"):
+        self.rec = rec
+        self.count = 0
+        self.lane: _SignLane | None = None
+        self.k = -1
+
+
+class _SignLane:
+    """The rows of one sign launch — one row width: per item its place
+    in the flush, its key (as the item names it, and among the lane's
+    distinct ``keys``), its encoded message as bytes and as an
+    integer; per row (two an item: mod p, mod q) the reduced base as
+    ``row`` little-endian bytes."""
+
+    __slots__ = (
+        "bits", "keys", "idx", "item_keys", "ksel", "em_bytes", "ems",
+        "base", "base_bytes", "row_mod",
+    )
+
+    def __init__(self, bits: int):
+        self.bits = bits
+        self.keys: list[_SignKey] = []
+        self.idx: list[int] = []
+        self.item_keys: list = []
+        self.ksel: list[int] = []
+        self.em_bytes: list[bytes] = []
+        self.ems: list[int] = []
+        self.base: list[bytes] = []
+        self.base_bytes = b""
+        self.row_mod: np.ndarray | None = None
+
+    def close(self) -> None:
+        """The per-item lists as what ``rns.pow_rows_rns`` takes: row
+        2j is item j's half mod p (the lane's modulus 2k), row 2j + 1
+        its half mod q (2k + 1), k the item's key among ``keys``."""
+        self.base_bytes = b"".join(self.base)
+        self.row_mod = (
+            2 * np.asarray(self.ksel, dtype=np.intp)[:, None] + (0, 1)
+        ).ravel()
 
 
 class VerifierDomain:
@@ -1263,11 +1387,15 @@ def _em_template(size: int, width: int) -> np.ndarray:
     return row
 
 
-def _count_staged(array: int, item: int) -> None:
-    """Chain-bound items staged by the array route, and pulled aside
-    to the per-item route."""
-    metrics.incr("verify.stage.array", array)
-    metrics.incr("verify.stage.item", item)
+def _count_staged(array: int, item: int, op: str = "verify") -> None:
+    """Items of a device-bound flush staged by the array route, and
+    those that left it.  ``verify``: chain-bound verify and fault-check
+    items, and the ones pulled aside to the per-item route.  ``sign``:
+    sign items whose pow rows were staged, and the ones sent to the
+    host tier (a key without rows, rows no base holds, an unwarmed
+    width)."""
+    metrics.incr(op + ".stage.array", array)
+    metrics.incr(op + ".stage.item", item)
 
 
 def _stage_verify_operands(ctx, lanes: list, idxs: list, urows: list) -> tuple:

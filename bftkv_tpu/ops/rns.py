@@ -39,8 +39,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from bftkv_tpu import trace
+from bftkv_tpu.devtools.lockwatch import named_lock
 from bftkv_tpu.ops import devbuf
-from bftkv_tpu.ops import limb
 from bftkv_tpu import flags
 from bftkv_tpu.metrics import registry as metrics
 
@@ -224,6 +224,7 @@ class RNSContext:
             D[2 * d, 2 * k] = w_lo % PR
             D[2 * d + 1, 2 * k] = w_hi % PR
         self._D = self._split6(D)
+        self.pow_keys = _PowKeyTable(self)
 
     @staticmethod
     def _split6(m: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
@@ -271,7 +272,7 @@ def context(digits: int = DIGITS, n_bits: int = 2048) -> RNSContext:
 
 def pow_context(n_bits: int) -> RNSContext:
     """The context of the pow chain at ``n_bits``-bit rows: a modulus
-    rides :func:`power_mod_rns` there when this context has
+    rides :func:`pow_rows_rns` there when this context has
     ``key_rows`` for it."""
     return context(max(32, (n_bits + 15) // 16), n_bits)
 
@@ -603,37 +604,48 @@ def _jitted_pow(digits: int, n_bits: int, donate: bool = False):
 
 
 def _crt_matrix(ctx: RNSContext) -> np.ndarray:
-    """(k, D) float64 16-bit digit planes of M_i = M/p_i, cached on ctx.
-    Row sums Σ σ_i·M_i stay < k·2^12·2^16 = 2^35 < 2^53: exact."""
+    """(k, D) float64 32-bit digit planes of M_i = M/p_i, cached on ctx.
+    Row sums Σ σ_i·M_i stay < k·2^12·2^32 < 2^53 (k < 512): exact."""
     m = getattr(ctx, "_crt_digits", None)
     if m is None:
-        width = (ctx.M.bit_length() + PR_BITS + 15) // 16 + 1
-        m = np.zeros((ctx.k, width), dtype=np.float64)
-        for i, p in enumerate(ctx.pb):
-            m[i] = limb.int_to_limbs(ctx.M // p, width)
+        width = (ctx.M.bit_length() + PR_BITS + 31) // 32 + 1
+        m = np.stack([
+            np.frombuffer((ctx.M // p).to_bytes(4 * width, "little"), "<u4")
+            for p in ctx.pb
+        ]).astype(np.float64)
         ctx._crt_digits = m
     return m
 
 
 def _sigma_to_ints(ctx: RNSContext, sigma: np.ndarray) -> list[int]:
-    """Batched RNS→integer via a float64 digit matmul + one carry pass."""
-    m = _crt_matrix(ctx)
-    acc = sigma.astype(np.float64) @ m  # (T, D) digit sums < 2^35
-    acc = acc.astype(np.int64)
+    """Batched RNS→integer: the digit sums Σ σ_i·M_i, one carry pass,
+    one byte string for the whole batch.
+
+    ``einsum`` and not ``@``: a float64 ``@`` of this size wakes the
+    BLAS pool, whose workers then spin on every core of a host that
+    the daemons' native RSA batches also want.  ``einsum`` runs on the
+    caller's thread, releases the GIL, and its sums (integers below
+    2^53) are exact in any order."""
+    acc = np.einsum(
+        "tk,kd->td", sigma.astype(np.float64), _crt_matrix(ctx)
+    ).astype(np.int64)
+    out = np.empty(acc.shape, dtype="<u4")
     carry = np.zeros(acc.shape[0], dtype=np.int64)
-    out = np.empty_like(acc, dtype=np.uint16)
     for d in range(acc.shape[1]):
-        s = acc[:, d] + carry
-        out[:, d] = (s & 0xFFFF).astype(np.uint16)
-        carry = s >> 16
-    vals = [
-        int.from_bytes(row.tobytes(), "little") for row in out
+        carry += acc[:, d]
+        out[:, d] = carry  # the low 32 bits
+        carry >>= 32
+    raw = out.tobytes()
+    w = 4 * acc.shape[1]
+    big = ctx.M
+    return [
+        int.from_bytes(raw[o : o + w], "little") % big
+        for o in range(0, len(raw), w)
     ]
-    return [v % ctx.M for v in vals]
 
 
 class DeferredModexp:
-    """Handle for a non-blocking :func:`power_mod_rns` launch.
+    """Handle for a non-blocking :func:`pow_rows_rns` launch.
 
     The kernel is already on the device stream when this is returned;
     :meth:`wait` materializes the device result, rebuilds the integers,
@@ -678,17 +690,147 @@ def _pow_staging(digits: int, n_bits: int, padded: int):
     return ring, slot
 
 
+class _PowKeyTable:
+    """The pow chain's key rows as the device holds them, at one row
+    width: a prime keeps its slot from launch to launch, so the
+    stacked table is built and uploaded when a new prime enters — a
+    handful of times in the life of a process that signs for 4–10
+    keys — and not once a launch.  The jitted chain does not donate
+    its key operand, so the same device arrays are handed to every
+    launch until the table changes.
+
+    The unique-modulus axis has a fixed floor of 64 rows: cross-request
+    flushes mix many signers' p/q, and every fresh (T, K) pair would
+    recompile the 256-step scan (~15-60 s); 64 padded key rows are
+    < 1 MB.  A launch whose primes do not fit beside the ones placed
+    restarts the table from its own; one with more than 64 distinct
+    primes gets a table of its own, padded to a power of two and not
+    kept."""
+
+    FLOOR = 64
+
+    def __init__(self, ctx: RNSContext):
+        self._ctx = ctx
+        self._lock = named_lock("ops.rns.keytable")
+        self._slots: dict[int, int] = {}
+        self._rows: list = []
+        self._dev: tuple | None = None
+
+    @staticmethod
+    def _upload(rows: list, kpad: int) -> tuple:
+        metrics.incr("pow.keytable.upload")
+        return tuple(
+            jnp.asarray(a) for a in stack_key_rows(rows, pad_to=kpad)
+        )
+
+    def place(self, umods: list[int]):
+        """``(slots, ukey)`` for a launch over the moduli ``umods``:
+        each one's row in the stacked device table ``ukey`` (an int32
+        array beside ``umods``).  None when some modulus has no rows
+        (``RNSContext.key_rows``): the caller falls back."""
+        with self._lock:
+            slots, rows = self._slots, self._rows
+            new = dict.fromkeys(m for m in umods if m not in slots)
+            if len(rows) + len(new) > self.FLOOR:
+                slots, rows = {}, []
+                new = dict.fromkeys(umods)
+            fresh = [self._ctx.key_rows(m) for m in new]
+            if any(r is None for r in fresh):
+                return None
+            for m, r in zip(new, fresh):
+                slots[m] = len(rows)
+                rows.append(r)
+            at = np.fromiter(
+                (slots[m] for m in umods), dtype=np.int32, count=len(umods)
+            )
+            if len(rows) > self.FLOOR:
+                kpad = 1 << (len(rows) - 1).bit_length()
+                return at, self._upload(rows, kpad)
+            if new:
+                self._slots, self._rows = slots, rows
+                self._dev = self._upload(rows, self.FLOOR)
+            return at, self._dev
+
+
+def exp_nibbles(exps: list[int], digits: int) -> np.ndarray:
+    """``(len(exps), 4 * digits)`` uint8: each exponent's 4-bit windows,
+    most significant first, as the pow chain scans them.  A number's
+    little-endian bytes are its nibbles two by two, low one first."""
+    raw = np.frombuffer(
+        b"".join(e.to_bytes(2 * digits, "little") for e in exps),
+        dtype=np.uint8,
+    ).reshape(len(exps), 2 * digits)
+    nib = np.empty((len(exps), 4 * digits), dtype=np.uint8)
+    nib[:, 0::2] = raw & 0xF
+    nib[:, 1::2] = raw >> 4
+    return nib[:, ::-1]
+
+
 def power_mod_rns(
     bases: list[int], exps: list[int], mods: list[int], *,
     n_bits: int = 1024, defer: bool = False, op: str = "modexp",
 ):
-    """Batched x^e mod m with per-row (x, e, m) — the threshold-RSA /
-    CRT-signing workhorse.  Returns a list of ints, or None when any
-    modulus cannot ride the RNS path (caller falls back).
+    """Batched x^e mod m with per-row (x, e, m) — the threshold-RSA
+    workhorse.  Returns a list of ints, or None when any modulus or
+    exponent cannot ride the RNS path (caller falls back).
 
-    ``n_bits`` bounds the modulus/exponent width; 1024 covers the CRT
-    halves of RSA-2048 (reference hot loop: crypto_pgp.go:346-371,
-    threshold fragments rsa.go:140-178).
+    ``n_bits`` bounds the modulus/exponent width (threshold fragments
+    rsa.go:140-178).  The way in for callers whose exponents come a
+    row: it turns the rows' integers into what :func:`pow_rows_rns`
+    takes — the launch itself, ``defer`` and ``op`` are that
+    function's.
+    """
+    if not mods:
+        return []
+    for e in exps:
+        if e < 0 or e.bit_length() > n_bits:
+            return None
+    ctx = pow_context(n_bits)
+    with trace.leaf("flush.stage", op, items=len(mods), bits=n_bits):
+        unique: dict[int, int] = {}
+        row_mod = np.fromiter(
+            (unique.setdefault(m, len(unique)) for m in mods),
+            dtype=np.intp, count=len(mods),
+        )
+        umods = list(unique)
+        if any(
+            m <= 0 or m.bit_length() > 16 * ctx.digits for m in umods
+        ):
+            return None  # no rows for it: asked before its bytes are
+        base_bytes = b"".join(
+            (b % m).to_bytes(2 * ctx.digits, "little")
+            for b, m in zip(bases, mods)
+        )
+        nib_cols = exp_nibbles(exps, ctx.digits)
+    return pow_rows_rns(
+        n_bits, umods, row_mod, base_bytes, nib_cols, None,
+        defer=defer, op=op,
+    )
+
+
+def pow_rows_rns(
+    n_bits: int, umods: list[int], row_mod: np.ndarray, base_bytes: bytes,
+    nib_cols: np.ndarray, row_col: np.ndarray | None, *,
+    defer: bool = False, op: str = "modexp",
+):
+    """One launch of the pow chain at ``n_bits``-bit rows — the CRT
+    signing workhorse (reference hot loop: crypto_pgp.go:346-371) —
+    its operands built in whole-array steps from what the caller holds:
+
+    - ``umods``: the launch's moduli, each once (a signer: p and q of
+      each distinct key); ``row_mod`` (t,): each row's modulus among
+      them;
+    - ``base_bytes``: the rows' bases, reduced by their moduli, as
+      joined ``2 * digits``-byte little-endian strings — the halves of
+      a number's 16-bit little-endian digits, low half first, ARE its
+      little-endian bytes (:func:`digits_to_halves_u8`);
+    - ``nib_cols`` (c, 4 * digits) uint8 from :func:`exp_nibbles` and
+      ``row_col`` (t,): each row's exponent among them — a signer's
+      exponents are constants of its keys, computed once a key; None
+      where the exponents come a row (c = t, in order).
+
+    Returns the rows' ``base^exp mod modulus`` as a list of ints, or
+    None when a modulus has no key rows (caller falls back).
 
     ``defer=True`` returns a :class:`DeferredModexp` instead of a list:
     the launch is dispatched but NOT blocked on, so the caller (the
@@ -699,14 +841,9 @@ def power_mod_rns(
     (``flush.stage`` / ``.launch`` / ``.fetch`` / ``.unpack``): the
     signer passes ``sign``.
     """
-    if not mods:
-        return []
-    for e in exps:
-        if e < 0 or e.bit_length() > n_bits:
-            return None
     ctx = pow_context(n_bits)
     digits = ctx.digits
-    t = len(mods)
+    t = len(row_mod)
     ring = slot = None
     released = False
 
@@ -719,67 +856,38 @@ def power_mod_rns(
 
     try:
         with trace.leaf("flush.stage", op, items=t, bits=n_bits) as sp:
-            unique: dict[int, int] = {}
-            urows: list = []
-            idxs: list[int] = []
-            for m in mods:
-                u = unique.get(m)
-                if u is None:
-                    r = ctx.key_rows(m)
-                    if r is None:
-                        return None
-                    u = unique[m] = len(urows)
-                    urows.append(r)
-                idxs.append(u)
+            placed = ctx.pow_keys.place(umods)
+            if placed is None:
+                return None
+            at, ukey = placed
             # Pad the batch axis (floor 64) to power-of-two buckets so
-            # only a handful of kernel shapes compile.  The
-            # unique-modulus axis gets a fixed floor of 64:
-            # cross-request flushes mix many signers' p/q, and every
-            # fresh (T, K) pair would recompile the 256-step scan
-            # (~15-60 s); 64 padded key rows are < 1 MB of extra
-            # transfer.
+            # only a handful of kernel shapes compile.
             padded = max(64, 1 << (t - 1).bit_length())
             sp.attrs["bucket"] = padded
-            kpad = max(64, 1 << (len(urows) - 1).bit_length())
-            urows += [urows[0]] * (kpad - len(urows))
-            ukey = tuple(jnp.asarray(a) for a in stack_key_rows(urows))
             # Stage operands into a persistent slot (devbuf ring) or
-            # throwaway arrays: ONLY the t live rows ride the
-            # int→limb→half pipeline; the pad region broadcasts row 0 in
-            # place, which is bit-identical to the historical
-            # pad-the-input-lists-with-item-0 convention (pad base =
-            # bases[0] % mods[0] = row 0's conversion; pad unique-index
-            # is 0 = row 0's by construction) without its per-pad-row
-            # bigint conversions or per-launch allocations.
+            # throwaway arrays.  The pad region broadcasts row 0 in
+            # place: its base, its exponent, its modulus.
             ring, slot = _pow_staging(digits, n_bits, padded)
             bh, nt, ix = slot["base_halves"], slot["nib_t"], slot["idx"]
-            base_digits = np.stack(
-                [limb.int_to_limbs(b % m, digits)
-                 for b, m in zip(bases, mods)]
+            bh[:t] = np.frombuffer(base_bytes, dtype=np.uint8).reshape(
+                t, 2 * digits
             )
-            bh[:t, 0::2] = base_digits & 0xFF
-            bh[:t, 1::2] = base_digits >> 8
-            ed = np.stack(
-                [limb.int_to_limbs(e, digits) for e in exps]
-            )  # (t, digits)
-            nib = np.empty((t, digits * 4), dtype=np.uint8)
-            nib[:, 0::4] = ed & 0xF  # little-endian within a 16-bit digit
-            nib[:, 1::4] = (ed >> 4) & 0xF
-            nib[:, 2::4] = (ed >> 8) & 0xF
-            nib[:, 3::4] = (ed >> 12) & 0xF
-            nt[:, :t] = nib[:, ::-1].T  # most-significant nibble first
-            ix[:t] = np.asarray(idxs, dtype=np.int32)
+            nt[:, :t] = (
+                nib_cols if row_col is None else nib_cols[row_col]
+            ).T
+            ix[:t] = at[row_mod]
             if padded > t:
                 bh[t:] = bh[0:1]
                 nt[:, t:] = nt[:, 0:1]
-                ix[t:] = 0
+                ix[t:] = ix[0]
             pow_args = (bh, nt, ix, ukey)
-        mods_live = list(mods)
 
         def unpack(sigma: np.ndarray) -> list[int]:
             with trace.leaf("flush.unpack", op, items=t, bits=n_bits):
                 vals = _sigma_to_ints(ctx, sigma)
-                return [v % m for v, m in zip(vals, mods_live)]
+                return [
+                    v % umods[u] for v, u in zip(vals, row_mod.tolist())
+                ]
 
         sigma = None
         if _use_pallas("BFTKV_RNS_POW_BACKEND"):

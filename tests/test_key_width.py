@@ -189,19 +189,27 @@ def test_sign_batch_at_3072_bits_is_device_rows_fault_checked(keys):
     assert m.get("host.batch.python{op=verify}", 0) == 0
 
 
+def fault_check(checks):
+    """``SignerDomain._fault_check`` of ``[(key, s, em)]``: its maker
+    hands it the integers and their bytes, made once."""
+    keys, ss, ems = (list(c) for c in zip(*checks))
+    return rsa.SignerDomain._fault_check(
+        keys, ss, ems,
+        [s.to_bytes(k.size_bytes, "big") for k, s in zip(keys, ss)],
+        [em.to_bytes(k.size_bytes, "big") for k, em in zip(keys, ems)],
+    )
+
+
 @needs_native
 def test_fault_check_catches_a_wrong_signature_at_either_width(keys):
-    sigs, group = [], []
+    checks = []
     for i, (bits, key) in enumerate(sorted(keys.items()) * 2):
         em = rsa.emsa_pkcs1v15_sha256(b"fc-%d" % i, key.size_bytes)
         s = pow(em, key.d, key.n)
         if i >= 2:
             s ^= 1 << 9  # one faulted CRT half would look like this
-        sigs.append((i, key, s))
-        group.append((i, key, em))
-    assert rsa.SignerDomain._fault_check(sigs, group) == [
-        True, True, False, False,
-    ]
+        checks.append((key, s, em))
+    assert fault_check(checks) == [True, True, False, False]
 
 
 def test_rows_no_base_can_hold_sign_on_the_host(monkeypatch):

@@ -108,12 +108,12 @@ def test_group_the_pow_chain_cannot_serve_is_signed_on_the_host(
     from bftkv_tpu.metrics import registry as metrics
     from bftkv_tpu.ops import bigint, rns
 
-    def power_mod_rns(*_a, **_k):
+    def pow_rows_rns(*_a, **_k):
         if failure == "raises":
             raise RuntimeError("planted kernel failure")
         return None
 
-    monkeypatch.setattr(rns, "power_mod_rns", power_mod_rns)
+    monkeypatch.setattr(rns, "pow_rows_rns", pow_rows_rns)
     monkeypatch.setattr(
         bigint, "mont_exp", lambda *_a: pytest.fail("limb program traced")
     )
@@ -151,13 +151,13 @@ def test_one_malformed_key_costs_its_own_items_the_device(
     items.insert(4, (b"mk-bad", bad))
 
     launches = []
-    real = rns.power_mod_rns
+    real = rns.pow_rows_rns
 
-    def spy(bases, exps, mods, **kw):
-        launches.append(len(mods))
-        return real(bases, exps, mods, **kw)
+    def spy(n_bits, umods, row_mod, *a, **kw):
+        launches.append(len(row_mod))
+        return real(n_bits, umods, row_mod, *a, **kw)
 
-    monkeypatch.setattr(rns, "power_mod_rns", spy)
+    monkeypatch.setattr(rns, "pow_rows_rns", spy)
     metrics.reset()
     sd = rsa.SignerDomain(host_threshold=0)
     sigs = sd.sign_batch(items)

@@ -368,16 +368,25 @@ def test_both_counters_exist_from_the_start():
 # -- the fault check stages through the same builder -----------------------
 
 
+def fault_check(checks):
+    """``SignerDomain._fault_check`` of ``[(key, s, em)]``: its maker
+    hands it the integers and their bytes, made once."""
+    keys, ss, ems = (list(c) for c in zip(*checks))
+    return rsa.SignerDomain._fault_check(
+        keys, ss, ems,
+        [s.to_bytes(k.size_bytes, "big") for k, s in zip(keys, ss)],
+        [em.to_bytes(k.size_bytes, "big") for k, em in zip(keys, ems)],
+    )
+
 def test_fault_check_operands_equal_the_per_item_loop(monkeypatch, keys2048):
     rng = random.Random(31)
     rec = Recorder(monkeypatch)
     ctx = rns.context()
-    sigs, group, dig_s, dig_em, idxs, urows, unique = [], [], [], [], [], [], {}
+    checks, dig_s, dig_em, idxs, urows, unique = [], [], [], [], [], {}
     for i in range(37):
         key = keys2048[rng.randrange(3)]
         s, em = rng.randrange(key.n), rng.randrange(key.n)
-        sigs.append((i, key, s))
-        group.append((i, key, em))
+        checks.append((key, s, em))
         if key.n not in unique:
             unique[key.n] = len(urows)
             urows.append(ctx.key_rows(key.n))
@@ -393,7 +402,7 @@ def test_fault_check_operands_equal_the_per_item_loop(monkeypatch, keys2048):
         np.asarray(idxs + [0] * pad, dtype=np.int32),
         rns.stack_key_rows(urows + [urows[0]] * (64 - len(urows))),
     )
-    ok = rsa.SignerDomain._fault_check(sigs, group)
+    ok = fault_check(checks)
     assert_same_operands(rec.calls[0], want)
     # the recorder's answers, except where the host's spot check of one
     # random item overruled a True
@@ -450,7 +459,7 @@ def test_verdicts_equal_the_host_tier_item_for_item(real_keys):
 
 
 def test_fault_check_catches_a_planted_wrong_crt_half(real_keys):
-    sigs, group = [], []
+    checks = []
     for i in range(12):
         key = real_keys[i % 3]
         em = rsa.emsa_pkcs1v15_sha256(b"fc-%d" % i, key.size_bytes)
@@ -458,9 +467,8 @@ def test_fault_check_catches_a_planted_wrong_crt_half(real_keys):
         if i in (4, 9):
             # a faulted half mod p: right mod q, wrong mod p
             s = (s + key.q * 12345) % key.n
-        sigs.append((i, key, s))
-        group.append((i, key, em))
-    ok = rsa.SignerDomain._fault_check(sigs, group)
+        checks.append((key, s, em))
+    ok = fault_check(checks)
     assert ok == [i not in (4, 9) for i in range(12)]
     snap = metrics.snapshot()
     assert (snap["verify.stage.array"], snap["verify.stage.item"]) == (12, 0)
