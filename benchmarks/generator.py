@@ -9,6 +9,12 @@ for each reply before its next call.  Operations:
 - ``update``  a new version of ``batch`` existing records
 - ``read``    ``batch`` existing records (``read_many``; ``read`` at 1)
 
+A share of any other name is a kind that lives in a file,
+``benchmarks/kinds/<kind>.py`` (its docstring says what a kind provides):
+the caller hands the draw to it.  The harness opens one client per user
+of the configuration (``users``); caller *i* drives the client of user
+*i mod users*.
+
 Every draw comes from ``--seed``; every seed issues the same kinds and
 sizes of work.  The callers keep a history of what they sent and what
 came back — the judge's only input besides the replicas' disks.
@@ -23,7 +29,14 @@ from dataclasses import dataclass, field
 
 from benchmarks import ycsb
 
-KINDS = ("insert", "update", "read")
+KINDS = ("insert", "update", "read")   # the built-ins; the rest are files
+
+
+def assign(clients: list, callers: int) -> list:
+    """Whose client each caller drives: caller *i* the client of user
+    *i mod users*.  Callers of one user share its ONE client object (a
+    server keeps one session per peer); with one user that is all of them."""
+    return [clients[i % len(clients)] for i in range(callers)]
 
 
 @dataclass
@@ -37,6 +50,9 @@ class Call:
     errors: list = field(default_factory=list)   # per item: None or str
     values: list = field(default_factory=list)   # per item, reads only
     phase: str = "window"
+    beside: str = ""             # the kind in a file whose draw issued this
+                                 # built-in call beside its own: judged as
+                                 # any other, counted with the kind's call
 
     def acked(self) -> int:
         return sum(e is None for e in self.errors)
@@ -59,16 +75,18 @@ class KeySpace:
 
 class Caller(threading.Thread):
     def __init__(self, idx: int, api, mix: dict, seed: int, keys: KeySpace,
-                 gate: "Gate"):
+                 gate: "Gate", kinds: dict | None = None):
         super().__init__(name=f"caller-{idx}", daemon=True)
         self.idx, self.api, self.mix, self.seed = idx, api, mix, seed
         self.keys, self.gate = keys, gate
+        self.kinds = kinds or {}   # name -> kinds.Kind, for shares in files
         self.rng = random.Random(f"{seed}|caller|{idx}")
         self.batch = int(mix["batch"])
         self.shares = [(k, float(mix["ops"].get(k, 0.0))) for k in KINDS]
+        self.shares += [(k, float(mix["ops"][k])) for k in self.kinds]
         self.record = mix["record"]
-        self.chooser = None
-        if any(s > 0 for k, s in self.shares if k != "insert"):
+        self.chooser = None   # of existing keys: where the mix preloads some
+        if keys.loaded > 0 and mix["keys"]["distribution"] != "new":
             self.chooser = ycsb.KeyChooser(
                 mix["keys"]["distribution"], keys.loaded,
                 float(mix["keys"].get("theta", 0.99)),
@@ -88,6 +106,10 @@ class Caller(threading.Thread):
         return self.shares[-1][0]
 
     def _existing(self) -> list[int]:
+        if self.chooser is None:
+            raise RuntimeError(
+                "an update or a read in a mix with no existing keys: give it "
+                "preload_records and a keys.distribution to draw them by")
         out: list[int] = []
         while len(out) < self.batch:
             k = self.chooser.draw(self.rng)
@@ -99,8 +121,29 @@ class Caller(threading.Thread):
         return ycsb.record(self.seed, keynum, version,
                            self.record["fields"], self.record["field_bytes"])
 
-    def one_call(self, phase: str) -> Call:
+    def new_call(self, kind: str, keynums: list[int], versions: list[int],
+                 phase: str) -> Call:
+        """A call stamped as sent now (for the kinds that live in files)."""
+        return Call(kind, self.idx, keynums, versions, time.monotonic(),
+                    phase=phase)
+
+    def one_call(self, phase: str) -> list[Call]:
+        """Draw a kind and issue it: one call, or the several that a kind
+        in a file returned."""
         kind = self._kind()
+        if kind in self.kinds:
+            calls = self.kinds[kind].one_call(self, phase)
+            now = time.monotonic()
+            for c in calls:
+                c.t_done = c.t_done or now
+        else:
+            calls = [self.builtin(kind, phase)]
+        self.calls += calls
+        return calls
+
+    def builtin(self, kind: str, phase: str) -> Call:
+        """One ``insert`` / ``update`` / ``read`` as the mix sizes it, not
+        yet filed under ``calls`` (``one_call`` files what it returns)."""
         keynums = (self.keys.fresh(self.batch) if kind == "insert"
                    else self._existing())
         names = [ycsb.key_name(self.seed, k) for k in keynums]
@@ -134,7 +177,6 @@ class Caller(threading.Thread):
             call.errors = [repr(e)] * len(keynums)
             call.values = [None] * len(keynums) if kind == "read" else []
         call.t_done = time.monotonic()
-        self.calls.append(call)
         return call
 
     # -- the thread ---------------------------------------------------------
@@ -147,12 +189,13 @@ class Caller(threading.Thread):
             # on a caller that never got through.
             want, tries = int(self.mix["warm_calls"]), 0
             while want > 0:
-                call = self.one_call("warm")
+                calls = self.one_call("warm")
                 tries += 1
-                want -= call.acked() == len(call.keynums)
+                want -= all(c.acked() == len(c.keynums) for c in calls)
                 if tries >= int(self.mix["warm_calls"]) + 4 and want > 0:
+                    said = [e for c in calls for e in c.errors if e is not None]
                     raise RuntimeError(
-                        f"warm calls keep failing: {call.errors[0]}")
+                        f"warm calls keep failing: {said[0] if said else calls}")
             self.gate.warm_done()
             deadline = self.gate.wait_start()
             while time.monotonic() < deadline:

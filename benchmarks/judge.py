@@ -36,6 +36,11 @@ checked"); none depends on the load.
                           an answer: overload, reported, not compared)
     readback_checked / records_verified / committed_ops / forged_checked
                           floors of 1: something was checked
+
+A kind that lives in a file (``benchmarks/kinds/``) brings numbers of its
+own, each with a limit of 0 or a floor of 1; ``verdict`` holds them beside
+these, after them.  The history knows the built-in kinds only: a call of
+any other kind is the kind's own to judge.
 """
 
 from __future__ import annotations
@@ -45,7 +50,7 @@ import random
 import time
 
 from benchmarks import reference, ycsb
-from benchmarks.generator import Call
+from benchmarks.generator import KINDS, Call
 
 
 class History:
@@ -57,6 +62,8 @@ class History:
         self.reads: list[tuple[int, bytes | None, float, float]] = []
         self.acked_keys: list[int] = []
         for c in calls:
+            if c.kind not in KINDS:
+                continue  # a kind in a file judges its own calls
             for i, k in enumerate(c.keynums):
                 err = c.errors[i] if i < len(c.errors) else "no answer"
                 if c.kind == "read":
@@ -124,12 +131,15 @@ def check_reads(h: History) -> dict:
             "bad_read_samples": samples}
 
 
-def readback(h: History, api, keys: list[int], chunk: int = 256) -> dict:
+def readback(h: History, apis: list, keys: list[int], chunk: int = 256) -> dict:
     """Read the sample back through the client path, once the window
-    has closed; wait for each answer (one retry on an error)."""
+    has closed; wait for each answer (one retry on an error).  ``apis``
+    are the clients of the configuration's users: the chunks go round
+    them, so every user reads some of what all wrote."""
     out = {"bad_reads": 0, "lost_acked_writes": 0, "unanswered_checks": 0,
            "readback_checked": 0, "bad_read_samples": []}
     for off in range(0, len(keys), chunk):
+        api = apis[(off // chunk) % len(apis)]
         part = keys[off : off + chunk]
         names = [ycsb.key_name(h.seed, k) for k in part]
         t0 = time.monotonic()
@@ -242,7 +252,9 @@ def _parse_only(pkt: bytes, name: bytes, suff: int) -> tuple[bool, bytes]:
 
 
 def writeonce(api, seed: int) -> dict:
-    """Write-once honoured: the second write is refused, the first kept."""
+    """Write-once honoured: the second write is refused, the first kept.
+    Both are the same client's: another user's second write is refused
+    as no write of the owner's (TOFU), whether write-once holds or not."""
     name = b"once%d" % seed
     first, second = b"kept-%d" % seed, b"clobbered-%d" % seed
     violations = 0
@@ -274,10 +286,12 @@ LIMITS = [
 ]
 
 
-def verdict(numbers: dict) -> tuple[bool, dict]:
-    """``(correct, compared)``: each number beside its limit."""
+def verdict(numbers: dict, more_limits=()) -> tuple[bool, dict]:
+    """``(correct, compared)``: each number beside its limit.
+    ``more_limits`` are those of the mix's kinds in files, held after the
+    built-in ones (``kinds.load`` has checked that each is exact)."""
     compared, ok = {}, True
-    for name, op, limit in LIMITS:
+    for name, op, limit in [*LIMITS, *more_limits]:
         if name not in numbers:
             continue
         v = numbers[name]

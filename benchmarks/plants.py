@@ -18,9 +18,14 @@ Planted inside the sidecar process, where the verdict is produced
 
 - ``accept_all``  the device verify chain skips the modexp and answers
   True for every row of a launch (under ``--rehearse``, where the CPU
-  sidecar verifies on the host tier, the host oracle too).  The
+  sidecar verifies on the host tier, the host oracle too: the batch form
+  ``rsa.verify_host_many`` and the one-item ``rsa.verify_host``).  The
   daemons' items are all valid, so nothing they see changes; the forged
   items of ``tenant.py`` are accepted.
+
+A kind that lives in a file (``benchmarks/kinds/``) may bring client-facade
+plants of its own in ``_Planted``'s shape; ``plant`` takes a table that
+holds them beside these.
 """
 
 from __future__ import annotations
@@ -70,12 +75,14 @@ PLANTS = {"lost_write": LostWrite, "wrong_read": WrongRead}
 SIDECAR_PLANTS = ("accept_all",)
 
 
-def plant(name: str, api, every: int = 7):
+def plant(name: str, api, every: int = 7, table: dict = PLANTS):
+    """``api`` with ``name`` planted under it (``""``: ``api`` itself).
+    ``table`` is ``PLANTS``, or it with the plants of the mix's kinds."""
     if not name:
         return api
-    if name not in PLANTS:
-        raise ValueError(f"unknown plant {name!r}; known: {sorted(PLANTS)}")
-    return PLANTS[name](api, every)
+    if name not in table:
+        raise ValueError(f"unknown plant {name!r}; known: {sorted(table)}")
+    return table[name](api, every)
 
 
 # -- inside the sidecar process ---------------------------------------------
@@ -103,6 +110,8 @@ def sidecar_plant(name: str, host_tier: bool) -> None:
     if host_tier:
         _restore.append((rsa, "verify_host", rsa.verify_host))
         rsa.verify_host = lambda *_a, **_k: True
+        _restore.append((rsa, "verify_host_many", rsa.verify_host_many))
+        rsa.verify_host_many = lambda items: [True] * len(items)
 
 
 def sidecar_unplant() -> None:

@@ -13,7 +13,8 @@ the children's logs, to standard error and to ``failure.txt`` there.
 
 Without a TPU the command fails; ``--rehearse`` walks the same flow tiny
 on the CPU and prints no device metric.  ``--plant`` and
-``--control-runs`` are for the controls that must come out incorrect.
+``--control-runs`` are for the controls that must come out incorrect
+(``--plant stall``, a host that stands still, for the one that must not).
 """
 
 from __future__ import annotations
@@ -24,6 +25,7 @@ import json
 import os
 import shutil
 import signal
+import subprocess
 import sys
 import threading
 import time
@@ -31,8 +33,20 @@ import time
 T_PROCESS = time.monotonic()
 CALLER_PLATFORMS = os.environ.get("JAX_PLATFORMS")  # the sidecar's, untouched
 os.environ["JAX_PLATFORMS"] = "cpu"  # this process never holds the chip
+# The host of a chip machine stands still now and then, for seconds, or its
+# disk holds an fsync back, and every process of a run is on it.  The
+# program's adaptive RPC deadline (8 x a peer's recent p99 + 0.1 s, from 1 s
+# up: 1.1-2.7 s for a storage node of q4-rsa2048.load) then runs out on a
+# replica that answers late, and a whole call fails with "rpc timeout" (the
+# driver's check of PR 32: 512 of 448,000 operations in one set, 0 in the
+# other).  So every process of a run keeps the fixed deadline, upstream's
+# 10 s (http.go:39-50): a late answer is late, and the call's time counts
+# the wait.  The caller's environment can say otherwise (the controls do:
+# `--plant stall`, `stall_storage`); a configuration's `environment`
+# reaches the children alone, and the deadline that matters is the client's.
+os.environ.setdefault("BFTKV_ADAPTIVE_TIMEOUT", "off")
 
-from benchmarks import generator, harness, judge, plants, tenant  # noqa: E402
+from benchmarks import generator, harness, judge, kinds, plants, tenant  # noqa: E402
 from benchmarks.harness import ROOT, BenchFailure  # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -47,6 +61,7 @@ DRAIN_TIMEOUT_S = 90.0    # for a caller's last call after the window closed
 TRACE_AT = 0.3            # share of the window gone when tracing starts
 TRACE_MIN_S, TRACE_MAX_S = 2.0, 4.0
 STOP_TRACE_TIMEOUT_S = 200.0
+STALLS = ("stall", "stall_storage")   # --plant: what must fail no operation
 
 
 def load_manifest(path: str = "") -> dict:
@@ -98,14 +113,48 @@ class Run:
         self.mix = load_json("benchmarks", "traffic", self.cell["traffic"] + ".json")
         if args.rehearse:
             self.mix.update(self.mix.get("rehearse", {}))
+        self.check_mix()
         self.run_dir = os.path.join(HERE, ".run", f"{args.workload}-{args.seed}")
         self.cluster = harness.Cluster(self.run_dir, self.config,
                                        rehearse=args.rehearse,
                                        chip_platforms=CALLER_PLATFORMS)
         self.timing: dict = {}
-        self.api = None
+        self.apis: list = []   # one client per user of the configuration
+        self.api = None        # u01's: the preload and the write-once pair
         self.pool = None
         self._down = False
+
+    def check_mix(self) -> None:
+        """What a mix says about its operations and its callers, checked
+        before any child starts: kinds that live in files are loaded
+        (their limits and plants with them), the names of ``--plant`` and
+        of the mix's ``controls`` are known ones, there is a user to drive
+        the callers and, where the mix updates or reads, keys to draw."""
+        mix, name = self.mix, self.cell["traffic"]
+        self.kinds = kinds.load(k for k, share in mix["ops"].items() if share)
+        self.limits = [lim for k in self.kinds.values() for lim in k.limits]
+        self.plants = dict(plants.PLANTS)
+        for k in self.kinds.values():
+            self.plants.update(k.plants)
+        known = {"dead_child", *STALLS, *self.plants, *plants.SIDECAR_PLANTS}
+        for plant in (getattr(self.args, "plant", ""),
+                      *mix.get("controls", {})):
+            if plant and plant not in known:
+                raise BenchFailure(f"no plant '{plant}' (--plant, or the "
+                                   f"controls of mix '{name}'): known are "
+                                   f"{sorted(known)}")
+        if int(self.config["users"]) < 1:
+            raise BenchFailure(f"configuration '{self.cell['config']}' has no "
+                               "user: users is the number of clients that "
+                               "drive the callers, one at least")
+        if (any(mix["ops"].get(k) for k in ("update", "read"))
+                and int(mix.get("preload_records", 0)) < 1):
+            raise BenchFailure(f"mix '{name}' updates or reads and preloads "
+                               "no record: preload_records is 0")
+
+    def kind_ctx(self) -> dict:
+        return {"clients": self.apis, "config": self.config, "mix": self.mix,
+                "seed": self.args.seed, "rehearse": bool(self.args.rehearse)}
 
     # -- output -------------------------------------------------------------
 
@@ -141,15 +190,27 @@ class Run:
             from bftkv_tpu.api import open_client
         except ImportError as e:
             raise BenchFailure(f"the program is not in this checkout: {e}")
-        home = os.path.join(cl.keys, "u01")
         # One user, one client: the callers are threads of one process
         # sharing it, as the daemon's own client API does.  (Several
         # clients of one identity fight over the one session a server
-        # keeps per peer: "unknown transport session".)
-        self.api = open_client(home)
+        # keeps per peer: "unknown transport session".)  Every user of
+        # the configuration is opened, u01 ... uNN; caller i drives the
+        # client of user i mod users (``generator.assign``).
+        for n in range(1, int(self.config["users"]) + 1):
+            home = os.path.join(cl.keys, f"u{n:02d}")
+            try:
+                self.apis.append(open_client(home))
+            except Exception as e:
+                raise BenchFailure(f"user home '{home}' does not open: {e!r}")
+        self.api = self.apis[0]
         self.timing["clients_open"] = time.monotonic() - t0
         if self.pool is not None:
             self.pool.wait(120)
+        if self.kinds:
+            t0 = time.monotonic()
+            for kind in self.kinds.values():
+                kind.prepare(self.kind_ctx())
+            self.timing["kinds_prepare"] = time.monotonic() - t0
 
     # -- one window ---------------------------------------------------------
 
@@ -181,14 +242,16 @@ class Run:
             self.timing["preload"] = time.monotonic() - t0
         keys = generator.KeySpace(int(mix.get("preload_records", 0)))
         gate = generator.Gate(int(mix["callers"]))
-        planted = plants.plant(plant if plant in plants.PLANTS else "", self.api,
-                               self.every(plant))
+        planted = [plants.plant(plant if plant in self.plants else "", api,
+                                self.every(plant), self.plants)
+                   for api in self.apis]
+        facades = generator.assign(planted, int(mix["callers"]))
         forger = None
         if self.pool is not None:
             forger = tenant.Tenant("unix:" + cl.sock, self.pool, seed, gate)
             forger.start()
         callers = [
-            generator.Caller(i, planted, mix, seed, keys, gate)
+            generator.Caller(i, facades[i], mix, seed, keys, gate, self.kinds)
             for i in range(int(mix["callers"]))
         ]
         t0 = time.monotonic()
@@ -207,6 +270,12 @@ class Run:
         if plant == "dead_child":  # a replica daemon dies mid-window
             victim = cl.daemons[-1]["pid"]
             threading.Timer(seconds / 2, os.kill, (victim, signal.SIGKILL)).start()
+        if plant in STALLS:        # the host, or its disk, stands still
+            n = self.args.stalls
+            for k in range(1, n + 1):
+                threading.Timer(seconds * k / (n + 1), self.stall,
+                                (self.args.stall_seconds,
+                                 plant == "stall_storage")).start()
         if trace:
             tracer = threading.Thread(
                 target=self._trace, args=(t_open, seconds, callers, trace_out),
@@ -253,6 +322,22 @@ class Run:
                 "counters": Counters(before, after), "cpu_s": cpu,
                 "trace": trace_out, "after": after, "tenant": requests}
 
+    def stall(self, seconds: float, storage_only: bool) -> None:
+        """Every process of the run stands still for ``seconds``, this one
+        included, as the host of a chip machine does now and then — or the
+        storage nodes alone, as under a disk that holds an fsync back: a
+        helper outside them all stops them and lets them go on."""
+        cl = self.cluster
+        if storage_only:
+            pids = [d["pid"] for d in cl.daemons[cl.n_quorum:]]
+        else:
+            pids = [os.getpid(), cl.procs["sidecar"].pid,
+                    *(d["pid"] for d in cl.daemons)]
+        pids = " ".join(map(str, pids))
+        subprocess.Popen(
+            ["sh", "-c", f"kill -STOP {pids}; sleep {seconds}; kill -CONT {pids}"],
+            start_new_session=True).wait()
+
     def _trace(self, t_open: float, seconds: float, callers: list,
                out: dict) -> None:
         """Trace one call cycle of the steady part of the window."""
@@ -276,7 +361,9 @@ class Run:
     # -- reduction ----------------------------------------------------------
 
     def end_to_end(self, w: dict) -> dict:
-        win = w["window"]
+        # a draw counts once: a built-in call that a kind in a file issued
+        # beside its own is judged as any other and not counted again
+        win = w["counted"] = [c for c in w["window"] if not c.beside]
         ops = sum(c.acked() for c in win)
         span = max(c.t_done for c in win) - min(c.t_send for c in win)
         m = {"committed_ops_per_s": (ops / span, "ops/s")}
@@ -340,13 +427,16 @@ class Run:
         h = judge.History(w["seed"], self.mix["record"], w["calls"])
         sample = h.sample(int(self.mix["check_sample"]))
         # a wrong read is planted where reads happen: here too
-        api = plants.plant(w["plant"] if w["plant"] == "wrong_read" else "",
-                           self.api, self.every("wrong_read"))
+        apis = [plants.plant(w["plant"] if w["plant"] == "wrong_read" else "",
+                             api, self.every("wrong_read"))
+                for api in self.apis]
         nums = judge.check_reads(h)
-        for k, v in judge.readback(h, api, sample).items():
+        for k, v in judge.readback(h, apis, sample).items():
             nums[k] = nums[k] + v if k in nums else v
         if self.pool is not None:
             nums.update(tenant.judge(w["tenant"], self.pool.key))
+        for kind in self.kinds.values():  # their own calls, their own numbers
+            nums.update(kind.judge(w["calls"], self.kind_ctx()))
         w.update(history=h, sample=sample)
         return nums
 
@@ -362,9 +452,9 @@ class Run:
         if self._down:
             return
         self._down = True
-        if self.api is not None:
+        for api in self.apis:
             try:
-                self.api.tr.stop()
+                api.tr.stop()
             except Exception:
                 pass
         self.cluster.stop()
@@ -392,6 +482,20 @@ def error_counts(calls: list) -> dict:
     return dict(sorted(counts.items(), key=lambda kv: -kv[1])[:8])
 
 
+def client_transport() -> dict:
+    """What the client's transport says of its peers at the end of a run,
+    from this process's own registry: the deadline it gives each peer's
+    next RPC (adaptive: 8 x the peer's recent p99 + 0.1 s, between 1 s and
+    the fixed 10 s), its retries and its peers flagged slow."""
+    try:
+        from bftkv_tpu.metrics import registry
+    except ImportError:
+        return {}
+    return {k: v for k, v in sorted(registry.snapshot().items())
+            if k.startswith(("transport.peer.deadline_ms", "transport.retries",
+                             "transport.peer.slow"))}
+
+
 def metrics_json(m: dict) -> dict:
     return {k: {"value": v, "unit": u} for k, (v, u) in m.items()}
 
@@ -405,9 +509,18 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--rehearse", action="store_true",
                     help="tiny, on the CPU; prints no device metric")
     ap.add_argument("--plant", default="",
-                    choices=["", "dead_child", *plants.PLANTS,
-                             *plants.SIDECAR_PLANTS],
-                    help="control: a fault planted under the timed path")
+                    help="control: a fault planted under the timed path "
+                         f"(dead_child, {', '.join(STALLS)}, "
+                         f"{', '.join(plants.PLANTS)}, "
+                         f"{', '.join(plants.SIDECAR_PLANTS)}, or a plant of "
+                         "one of the mix's kinds)")
+    ap.add_argument("--stall-seconds", type=float, default=4.0,
+                    help="how long --plant stall stops every process of the "
+                         "run, this one included (stall_storage: the storage "
+                         "nodes alone)")
+    ap.add_argument("--stalls", type=int, default=1,
+                    help="how many times, evenly spaced inside the window "
+                         "(one: at half of it)")
     ap.add_argument("--control-runs", type=int, default=0,
                     help="after the window, this many short planted windows "
                          "per plant of the mix's 'controls', on seeds seed+i")
@@ -482,10 +595,10 @@ def measure(run: Run) -> int:
     t0 = time.monotonic()
     disks = run.judge_disks(w)
     numbers.update(disks)
-    correct, compared = judge.verdict(numbers)
+    correct, compared = judge.verdict(numbers, run.limits)
     for cw, cn in controls:
         cn.update(run.judge_disks(cw))
-        ok, ccompared = judge.verdict(cn)
+        ok, ccompared = judge.verdict(cn, run.limits)
         run.note(control=cw["plant"], seed=cw["seed"], correct=ok,
                  ops=cw["ops"], compared=ccompared)
     run.timing["check_disks"] = time.monotonic() - t0
@@ -522,9 +635,13 @@ def measure(run: Run) -> int:
     run.note(timing=run.timing)
     run.note(window={"ops": w["ops"], "span_s": w["span_s"],
                      "calls": len(w["window"]),
-                     "first_half_ops": sum(c.acked() for c in w["window"] if c.t_done <= half),
+                     "first_half_ops": sum(c.acked() for c in w["counted"] if c.t_done <= half),
                      "cpu_s": w["cpu_s"]},
              errors=error_counts(w["calls"]))
+    run.note(client_transport=client_transport(),
+             slowest_calls_ms=sorted(
+                 (round(1000.0 * (c.t_done - c.t_send), 1) for c in w["counted"]),
+                 reverse=True)[:8])
     run.note(admission={k: v for k, v in w["counters"].items("sidecar")
                         if k.startswith(("admission.wait.bucket",
                                          "admission.wait.count", "sidecar.shed"))},

@@ -219,9 +219,9 @@ class FakeApi:
 
 def test_readback_counts_lost_and_wrong():
     h = history()
-    assert judge.readback(h, FakeApi(h), [0, 1, 2, 3])["readback_checked"] == 4
-    assert judge.readback(h, FakeApi(h, lose=2), [0, 1, 2, 3])["lost_acked_writes"] == 1
-    assert judge.readback(h, FakeApi(h, bend=1), [0, 1, 2, 3])["bad_reads"] == 1
+    assert judge.readback(h, [FakeApi(h)], [0, 1, 2, 3])["readback_checked"] == 4
+    assert judge.readback(h, [FakeApi(h, lose=2)], [0, 1, 2, 3])["lost_acked_writes"] == 1
+    assert judge.readback(h, [FakeApi(h, bend=1)], [0, 1, 2, 3])["bad_reads"] == 1
 
 
 def test_stale_read_is_a_bad_read():

@@ -1,7 +1,8 @@
 """Whole runs on the CPU (``--rehearse``: tiny, no device metric): a
 sound run is correct; a planted lost write, a planted wrong read, a
 sidecar that accepts every signature and a dead child each make the run
-say so, legibly; without a TPU, and in a
+say so, legibly; a host that stands still for seconds fails no operation
+(and does under the program's adaptive RPC deadline); without a TPU, and in a
 directory without the program, the command fails and prints no result.
 
 Slow (about 25 s a run): ``python3 -m pytest benchmarks/tests -q``.
@@ -20,8 +21,8 @@ from benchmarks.harness import ROOT
 CELL = "q4-rsa2048.load"
 
 
-def bench(*argv, cwd=ROOT, timeout=300):
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
+def bench(*argv, cwd=ROOT, timeout=300, **environ):
+    env = dict(os.environ, JAX_PLATFORMS="cpu", **environ)
     return subprocess.run(
         [sys.executable, "-m", "benchmarks.run", "--workload", CELL, *argv],
         cwd=cwd, env=env, capture_output=True, text=True, timeout=timeout)
@@ -82,6 +83,31 @@ def test_dead_child_exits_legibly():
         assert f.read().startswith("FAILED: ")
     left = subprocess.run(["pgrep", "-f", "[2]147483780"], capture_output=True)
     assert left.returncode != 0, "a failed run left processes behind"
+
+
+@pytest.mark.parametrize("plant,deadline,fails", [
+    ("stall", "", False), ("stall_storage", "", False),
+    ("stall_storage", "on", True)])   # every call meets a stopped storage node
+def test_a_host_that_stands_still_fails_no_operation(plant, deadline, fails):
+    """Every process of the run (``stall``), or the storage nodes alone
+    (``stall_storage``: a disk that holds an fsync back), stops for 2.5 s
+    mid-window.  Under the harness's fixed RPC deadline the calls in flight
+    come back late and whole; under the program's adaptive one (from 1 s
+    up), which the harness switches off, they fail with "rpc timeout":
+    the control."""
+    environ = {"BFTKV_ADAPTIVE_TIMEOUT": deadline} if deadline else {}
+    p = bench("--seed", "2147483782", "--seconds", "6", "--trace", "0",
+              "--rehearse", "--plant", plant, "--stall-seconds", "2.5",
+              **environ)
+    r = result(p)
+    assert r["correct"] is True and r["attempted"] > 0
+    assert (r["failed"] > 0) is fails
+    notes = [json.loads(l) for l in p.stdout.splitlines()[:-1]]
+    slowest = next(n for n in notes if "slowest_calls_ms" in n)
+    if not fails:   # the wait is counted
+        assert slowest["slowest_calls_ms"][0] >= 2000.0
+    errors = next(n for n in notes if "errors" in n)["errors"]
+    assert any("rpc timeout" in k for k in errors) is fails
 
 
 def test_without_a_tpu_the_command_fails_and_prints_no_result():
