@@ -412,14 +412,17 @@ def main(argv: list[str] | None = None) -> int:
                          "daemons with JAX_PLATFORMS=cpu")
     ap.add_argument("--sidecar", default="",
                     help="host:port or unix:/path of a shared CRYPTO "
-                         "sidecar (cmd.verify_sidecar): verification AND "
-                         "RSA signing batch across every co-located "
-                         "tenant process.  Results are never trusted — "
+                         "sidecar (cmd.verify_sidecar): verification, "
+                         "RSA signing AND the server-side modexps "
+                         "(threshold-CA fragments, threshold DSA, TPA) "
+                         "batch across every co-located tenant "
+                         "process.  Results are never trusted — "
                          "signatures are self-checked with the public "
-                         "exponent and verdicts spot-checked locally "
-                         "(BFTKV_SIDECAR_SPOT_RATE); sign keys only "
-                         "cross a unix: socket or an HMAC channel "
-                         "(--sidecar-secret), else signing stays local")
+                         "exponent, verdicts and modexps spot-checked "
+                         "locally (BFTKV_SIDECAR_SPOT_RATE); sign keys "
+                         "and fragment exponents only cross a unix: "
+                         "socket or an HMAC channel (--sidecar-secret), "
+                         "else they stay local")
     ap.add_argument("--sidecar-secret", default="",
                     help="file with a shared secret: HMAC-authenticate "
                          "sidecar frames both ways (enables remote "
@@ -460,6 +463,7 @@ def main(argv: list[str] | None = None) -> int:
         from bftkv_tpu.ops import dispatch
 
         from bftkv_tpu.crypto.remote_verify import (
+            RemoteModexpDomain,
             RemoteSignerDomain,
             RemoteVerifierDomain,
             SidecarChannel,
@@ -470,8 +474,8 @@ def main(argv: list[str] | None = None) -> int:
             from bftkv_tpu.cmd.verify_sidecar import load_secret
 
             secret = load_secret(args.sidecar_secret)
-        # ONE channel for both domains: a dishonest verdict on either
-        # op benches the service for both.  calibrate=False on the
+        # ONE channel for all three domains: a dishonest verdict on any
+        # op benches the service for all.  calibrate=False on the
         # sign dispatcher: the CPU prefer_host bypass would keep
         # Signer.issue_many from ever reaching the remote domain (the
         # sidecar's own dispatchers re-apply the measured crossover
@@ -490,11 +494,26 @@ def main(argv: list[str] | None = None) -> int:
                 max_wait=0.002,
             )
         )
+        # The server-side modexps (threshold-RSA fragments, threshold
+        # DSA, TPA: everything that calls ops.modexp.BatchModExp) leave
+        # the same way.  The collector turns this daemon's concurrent
+        # DISTSIGN handlers into ONE request on its one-at-a-time
+        # channel; the batch is made in the sidecar, across daemons.
+        # Fragment exponents are key material: on a channel that
+        # cannot carry keys they stay here (modexp.local_secret).
+        dispatch.install_modexp(
+            dispatch.ModexpDispatcher(
+                remote=RemoteModexpDomain(channel=chan),
+                calibrate=False,
+                max_wait=0.002,
+            )
+        )
         if not chan.carries_keys:
             print(
                 "bftkv: sidecar channel cannot carry sign keys "
-                "(plain TCP without --sidecar-secret); signing stays "
-                "local, verification remotes", flush=True,
+                "(plain TCP without --sidecar-secret); signing and "
+                "server-side modexps stay local, verification remotes",
+                flush=True,
             )
     elif args.verify_sidecar:
         from bftkv_tpu.ops import dispatch

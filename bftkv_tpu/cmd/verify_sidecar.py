@@ -353,20 +353,33 @@ def _chunks(payload: bytes, count: int) -> list:
 # -- the service ------------------------------------------------------------
 
 
-def identity_bits() -> list[int]:
-    """The deployment's RSA identity widths (``BFTKV_IDENTITY_BITS``),
-    ascending.  A value that is no list of widths fails the start."""
-    raw = flags.get("BFTKV_IDENTITY_BITS") or ""
+def _declared_widths(flag: str) -> list[int]:
+    """RSA widths a deployment declared in ``flag``, ascending.  A set
+    value that is no list of widths fails the start."""
+    raw = flags.get(flag) or ""
     try:
         widths = sorted({int(w) for w in raw.split(",") if w.strip()})
     except ValueError:
         widths = []
     if not widths or widths[0] < 512 or widths[-1] > 16384:
         raise ValueError(
-            f"BFTKV_IDENTITY_BITS={raw!r}: want RSA widths, such as "
-            "'2048' or '2048,3072'"
+            f"{flag}={raw!r}: want RSA widths, such as '2048' or "
+            "'2048,3072'"
         )
     return widths
+
+
+def identity_bits() -> list[int]:
+    """The deployment's RSA identity widths (``BFTKV_IDENTITY_BITS``)."""
+    return _declared_widths("BFTKV_IDENTITY_BITS")
+
+
+def ca_bits() -> list[int]:
+    """The key widths of the threshold CAs the deployment deals to its
+    quorum (``BFTKV_CA_BITS``); none where it declares none."""
+    if not flags.get("BFTKV_CA_BITS"):
+        return []
+    return _declared_widths("BFTKV_CA_BITS")
 
 
 def _phase_sum(snap: dict, name: str) -> float:
@@ -447,9 +460,11 @@ class SidecarService:
         # the recalibration loop exists, so its compile-laden round
         # trips can never price the crossover.
         self.warmup = self._warm()
-        # Exists from the start, so that 0 reads as 0 and not as a
-        # program without the counter.
-        metrics.incr("sidecar.unwarmed_width", 0)
+        # Exist from the start, so that 0 reads as 0 and not as a
+        # program without the counter (the warm-up ended on a reset).
+        for name in ("sidecar.unwarmed_width", "modexp.device",
+                     "modexp.host"):
+            metrics.incr(name, 0)
         # After the warm-up, which passes no admission: the queue's
         # "empty since" is then the moment the service can first serve.
         self.admission = admission or AdmissionQueue(
@@ -493,8 +508,14 @@ class SidecarService:
         rides too; where the pow chain takes the CRT halves, the sign
         buckets — a flush of n signatures is 2n rows, buckets 64 …
         2·``max_batch`` — and one modexp launch at that row width,
-        which shares the sign programs.  A wrong result or a device
-        error raises: a sidecar that cannot launch does not start.
+        which shares the sign programs.  And its threshold CAs' key
+        widths (``BFTKV_CA_BITS``, default none): per width the
+        programs a first-level fragment rides — a whole modulus of that
+        width under the longer exponent class (``rns.long_exp_bits``),
+        buckets 64 and 128 (``ModexpDispatcher.
+        LONG_EXP_MAX_ROWS``), each launch checked against the host.  A
+        wrong result or a device error raises: a sidecar that cannot
+        launch does not start.
 
         Afterwards the domains know what was built
         (``VerifierDomain.chain_warm``, ``warm_rows`` of the signer and
@@ -631,9 +652,52 @@ class SidecarService:
                     self.modexp.max_batch)
             timed("modexp", m, warm_modexp)
         self.verify.verifier.chain_warm = verify_warm
-        self.sign.signer.warm_rows = self.modexp.warm_rows = frozenset(
-            rows.values()
+        self.sign.signer.warm_rows = frozenset(rows.values())
+        # A declared CA's first-level fragments: whole-modulus rows
+        # under the longer exponent class, entered in ``warm_rows``
+        # before their buckets run, so that each warm launch passes the
+        # dispatcher as a tenant's will.
+        ca_rows: dict[int, tuple[int, int]] = {}
+        for bits in ca_bits():
+            n_bits = 16 * -(-bits // 16)
+            cls = (n_bits, rns.long_exp_bits(n_bits))
+            if rns.chains(*cls).pow:
+                ca_rows[bits] = cls
+        self.modexp.warm_rows = frozenset(rows.values()) | frozenset(
+            ca_rows.values()
         )
+
+        def fragment_rows() -> tuple[list, list]:
+            # eight distinct rows and their residues: a first-level
+            # fragment is d minus nine random values of 2 x bits - 1 bits
+            mod = (1 << (bits - 1)) + 973
+            while any(mod % p == 0 for p in rns._gen_primes(1 << 10, rns.PR)):
+                mod += 2  # the chain has rows for it
+            few = [
+                (i + 2, (1 << (2 * bits)) + (i + 1) * 0x9E3779B97F4A7C15, mod)
+                for i in range(8)
+            ]
+            return few, [pow(*it) for it in few]
+
+        def warm_fragments(n: int) -> None:
+            items = [few[i % 8] for i in range(n)]
+            on_device = metrics.snapshot().get("modexp.device", 0)
+            if self.modexp.submit(items) != [want[i % 8] for i in range(n)]:
+                raise RuntimeError(
+                    f"sidecar warm-up: fragment batch of {n} at {bits} "
+                    "bits returned a wrong residue"
+                )
+            if metrics.snapshot().get("modexp.device", 0) - on_device != n:
+                raise RuntimeError(
+                    f"sidecar warm-up: fragment batch of {n} at {bits} "
+                    "bits did not ride the device"
+                )
+
+        for bits in ca_rows:
+            few, want = fragment_rows()
+            top = self.modexp.LONG_EXP_MAX_ROWS
+            for n in buckets(min(64, top), top):
+                timed("fragment", n, warm_fragments)
         # Warm-up is not traffic.  Its round trips included compilation
         # (or a cache load) and say nothing about what a launch costs;
         # its items are not any tenant's.  The observed-RTT series and
@@ -651,6 +715,11 @@ class SidecarService:
             "identity_bits": list(keys),
             "verify_chain": verify_warm,
             "pow_rows": sorted(set(rows.values())),
+            "ca_bits": list(ca_rows),
+            "fragment_rows": sorted(set(ca_rows.values())),
+            # which chain those rode: "ok" the fused one, "unused" or
+            # "fallback: <error>" the XLA one (rns.pallas_status)
+            "fragment_chain": rns.pallas_status()["pow"],
             "compile_cache": {
                 "dir": ops.compile_cache_dir(),
                 **counts,

@@ -585,7 +585,17 @@ class RemoteModexpDomain:
     """Raw batched modexp through the sidecar, locally re-checked at
     the sampled rate (one recompute per sampled batch — the only
     oracle a generic modexp has is itself, so the spot-check pays one
-    local op to keep the service honest in expectation)."""
+    local op to keep the service honest in expectation).
+
+    Its callers' exponents are key material — a replica daemon's are
+    threshold fragments (``cmd/bftkv.py``) — so they travel only on a
+    channel that ``carries_keys`` (the unix socket, HMAC-keyed TCP: the
+    rule ``RemoteSignerDomain`` follows); on any other they stay in
+    this process, on its host tier, counted (``modexp.local_secret``).
+
+    Whatever does not come back from the service — a shed, a tripped
+    channel, a failed spot check — is computed here, on the native
+    host tier where it is built."""
 
     def __init__(
         self,
@@ -605,25 +615,31 @@ class RemoteModexpDomain:
             else flags.get_float("BFTKV_SIDECAR_SPOT_RATE")
         )
         self._rng = random.Random()
+        for name in ("remote", "remote_fallback", "remote_shed",
+                     "local_secret"):
+            metrics.incr("modexp." + name, 0)  # a ratio has its denominator
 
     def powmod_batch(self, items: list) -> list:
-        """[(base, exp, mod)] → [int], falling back to local ``pow``."""
+        """[(base, exp, mod)] → [int], falling back to the local host
+        tier."""
         if not items:
             return []
+        if not self.channel.carries_keys:
+            metrics.incr("modexp.local_secret", len(items))
+            return rsa.powmod_host_many(items)
         vals = None
         if not self.channel.tripped():
             vals = self._remote(items)
         if vals is None:
             metrics.incr("modexp.remote_fallback", len(items))
-            return [pow(b, e, m) for b, e, m in items]
+            return rsa.powmod_host_many(items)
         if self.spot_rate > 0 and self._rng.random() < self.spot_rate:
             i = self._rng.randrange(len(items))
-            b, e, m = items[i]
-            if vals[i] != pow(b, e, m):
+            if vals[i] != rsa.powmod_host_many([items[i]])[0]:
                 metrics.incr("crypto.sidecar.dishonest")
                 self.channel.trip()
                 metrics.incr("modexp.remote_fallback", len(items))
-                return [pow(b, e, m) for b, e, m in items]
+                return rsa.powmod_host_many(items)
         metrics.incr("modexp.remote", len(items))
         return vals
 

@@ -478,6 +478,24 @@ def _pub_params(n: int) -> tuple:
     return p
 
 
+def powmod_host_many(items: list) -> list[int]:
+    """``[(base, exp, mod)]`` → ``[base^exp mod mod]`` on this process's
+    host tier: one native batch (:func:`_powmod_rows`) for the rows the
+    extension takes, ``pow`` for the rest (an even or oversized
+    modulus, a negative exponent, no extension)."""
+    out: list = [None] * len(items)
+    rows, at = [], []
+    for i, (b, e, m) in enumerate(items):
+        if _MM is not None and e >= 0 and _native_ok(m):
+            rows.append((b % m, e, _pub_params(m)))
+            at.append(i)
+        else:
+            out[i] = pow(b, e, m)
+    for i, v in zip(at, _powmod_rows(rows)):
+        out[i] = v
+    return out
+
+
 def _count_host_batch(op: str, native: int, python: int, t0: float) -> None:
     if native:
         metrics.incr("host.batch.native", native, labels={"op": op})

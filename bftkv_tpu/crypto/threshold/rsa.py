@@ -16,12 +16,27 @@ Capability parity with the reference (crypto/threshold/rsa/rsa.go):
 "(7,10) seems practical" — fragment count grows combinatorially with
 n-k (reference: docs/tex/method.tex:374-377).
 
-TPU redesign: a server's per-request fragment exponentiations — up to
-C(n-1, n-k)-ish modexps with exponents that *grow past the key size* at
-each tree level — run as ONE ``ops.modexp.BatchModExp`` launch (the
-RNS pow chain up to 2,048-bit operands, ``ops.modexp.power_batch`` over
-``(nfrag, L)`` limb arrays beyond) instead of the reference's
-sequential ``big.Int.Exp`` loop.
+What a request holds.  A server's share of a 2,048-bit d dealt (7,10)
+is 586 fragments (1 + 9 + 72 + 504 down the tree levels, 2.2 MB
+serialized), their exponents doubling in width per level (~4,100,
+8,192, 16,384, 32,768 bits).  When all n servers answer, a signature
+is ONE round and ONE modexp a server: the client asks for fragment 0
+and each server raises the EMSA block to its first-level fragment — a
+2,048-bit modulus (no CRT: a server knows N, not p and q) under an
+exponent of 2 x 2,048 + up to ~3 bits.  With one / two / three servers
+silent it is 2 / 3 / 4 rounds and 18 / 40 / 112 modexps a signature
+over all servers, the later rounds at the wider levels.
+
+TPU redesign: a server's per-request fragment exponentiations go out as
+ONE ``ops.modexp.BatchModExp`` request instead of the reference's
+sequential ``big.Int.Exp`` loop.  In a replica daemon started with
+``--sidecar`` that request leaves for the sidecar, where the first-level
+rows of all servers and callers ride one launch of the RNS pow chain's
+longer exponent class (``ops.rns.chains``); the wider levels are the
+sidecar's native host tier, counted by class.  Elsewhere the launch is
+the process's own (the RNS chain for the first level,
+``ops.modexp.power_batch`` over ``(nfrag, L)`` limb arrays up to
+4,096-bit exponents, host ``pow`` beyond).
 """
 
 from __future__ import annotations
@@ -30,6 +45,7 @@ import hashlib
 import io
 import struct
 
+from bftkv_tpu.metrics import registry as metrics
 from bftkv_tpu.crypto import rsa as rsakeys
 from bftkv_tpu.errors import (
     ERR_INSUFFICIENT_NUMBER_OF_RESPONSES,
@@ -364,9 +380,11 @@ class RSAThreshold:
     def sign(
         self, sec: bytes, req: bytes | None, peer_id: int, self_id: int
     ) -> bytes | None:
-        """One batched kernel launch over every requested fragment."""
+        """One ``BatchModExp`` request over every requested fragment
+        this server holds."""
         kids, prefix, dgst, = _parse_sign_request(req or b"")
-        keys, n_mod, sid, n = _parse_partial_param(sec)
+        with metrics.timer("threshold.rsa.parse"):
+            keys, n_mod, sid, n = _parse_partial_param(sec)
         m = emsa_encode(prefix, dgst, (n_mod.bit_length() + 7) // 8)
         held = [(kid, keys[kid]) for kid in kids if kid in keys]
         if not held:

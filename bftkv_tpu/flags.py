@@ -238,6 +238,14 @@ _flag("BFTKV_IDENTITY_BITS", "2048", "str",
       "widths before it listens; a sign or modexp at any other width is "
       "served from the host tier, never compiled inside a request. "
       "Chooses no tier: calibration does.")
+_flag("BFTKV_CA_BITS", None, "str",
+      "The key widths of the threshold CAs this deployment deals to its "
+      "quorum, comma-separated (`2048`): the sidecar then also builds the "
+      "pow programs a first-level threshold fragment rides (a whole "
+      "modulus of that width under an exponent of twice that width + 64 "
+      "bits, buckets 64 and 128 rows) before it listens. Unset: no such "
+      "program is built and a fragment row is served from the host tier, "
+      "counted (`sidecar.unwarmed_width`).")
 
 _begin("Device kernels & dispatch")
 _flag("BFTKV_DISPATCH_CALIBRATE", "1", "switch",
@@ -273,7 +281,8 @@ _flag("BFTKV_TPU_MIN_MODEXP_BATCH", "4", "int",
 _flag("BFTKV_RNS_POW_BACKEND", "auto", "str",
       "`pallas` forces the fused Pallas RNS pow chain, `xla` the "
       "lowered one; `auto` picks by platform and device count "
-      "(today: always `xla`).")
+      "(today: `xla`, but the longer exponent class on one TPU chip, "
+      "which rides the fused chain).")
 _flag("BFTKV_RNS_VERIFY_BACKEND", "auto", "str",
       "Same switch for the RNS verify kernel.")
 _flag("BFTKV_PALLAS_TILE_POW", "256", "int",

@@ -57,6 +57,10 @@ __all__ = [
     "install_signer",
     "uninstall_signer",
     "get_signer",
+    "install_modexp",
+    "uninstall_modexp",
+    "get_modexp",
+    "modexp_work",
     "uninstall_all",
     "note_launch_rtt",
     "observed_launch_rtt",
@@ -838,49 +842,100 @@ class SignDispatcher(_BatchDispatcher):
         return self.submit([(message, key)])[0]
 
 
+def modexp_work(n_bits: int, exp_bits: int) -> float:
+    """What one modexp row costs, in RSA-2048 e = 65537 verify items
+    (19 Montgomery products of 2,048 bits, what the calibrated
+    crossover counts): five products a 4-bit window and the 19 of
+    table and framing, each ``(n_bits / 2048)^2`` of a verify's.  A
+    1,024-bit CRT-half row is ~17, a first-level threshold fragment
+    (2,048-bit modulus, ~4,100-bit exponent) ~270."""
+    return (5 * exp_bits / 4 + 19) / 19 * (n_bits / 2048) ** 2
+
+
+def _row_class(e: int, m: int) -> tuple[int, int | None]:
+    """``(n_bits, exp_bits)`` of the pow rows that hold ``x^e mod m``:
+    the modulus's row width and the exponent's class at that width
+    (None: past both, ``ops.rns.exp_class``)."""
+    from bftkv_tpu.ops import rns as rns_ops
+
+    n_bits = 16 * -(-m.bit_length() // 16)
+    return n_bits, rns_ops.exp_class(n_bits, e.bit_length())
+
+
 class ModexpDispatcher(_BatchDispatcher):
     """Batched raw modular exponentiation (items: (base, exp, mod) ints).
 
     The sidecar's third op class: tenants outsource arbitrary modexps
-    (threshold-share combination, protocol experiments) and spot-check
-    the answers themselves — the service is untrusted by construction,
-    so correctness never depends on it (DESIGN.md §17.3).  Odd moduli
+    (a replica daemon's threshold-fragment exponentiations, TPA and
+    threshold-DSA rounds, protocol experiments) and spot-check the
+    answers themselves — the service is untrusted by construction, so
+    correctness never depends on it (DESIGN.md §17.3).  Odd moduli
     go through the Montgomery native kernel (GIL-releasing host tier);
-    everything else falls back to ``pow``.  Batches at or above
-    ``device_threshold`` attempt one RNS device launch per width group
-    first — on an accelerator that is the shard_map fan-out path the
-    sign dispatcher already uses.
+    everything else falls back to ``pow``.  A flush is grouped by row
+    class — (modulus width, exponent class), ``ops.rns.chains`` — and a
+    group whose WORK (:func:`modexp_work`, in verify items) reaches
+    ``device_threshold`` rides one RNS device launch — on an
+    accelerator that is the shard_map fan-out path the sign dispatcher
+    already uses.  An exponent past its modulus's classes (a
+    threshold fragment of the second tree level and below) is the host
+    tier's, counted by class (``modexp.host.class{bits}``).
+
+    With ``remote`` (a ``RemoteModexpDomain``) this is a replica
+    daemon's collector instead: the concurrent handlers' items leave
+    as ONE request on the daemon's one-at-a-time channel, and the
+    batch is made in the sidecar, across daemons
+    (:func:`install_modexp`).
     """
 
     name = "modexpdispatch"
     op = "modexp"
+
+    #: Rows one launch of a longer-exponent class holds at most: one
+    #: tile of the fused chain at 2,048-bit rows (``ops.pallas_rns``;
+    #: more rows are more tiles in a row, the device time of as many
+    #: launches).  The sidecar builds that class's buckets 64 and 128
+    #: and no more, so a larger group is several launches and never a
+    #: fresh program.
+    LONG_EXP_MAX_ROWS = 128
 
     def __init__(
         self,
         *,
         max_batch: int = 1024,
         max_wait: float = 0.002,
-        pipeline: int | None = None,
         calibrate: bool | None = None,
         device_threshold: int | None = None,
+        remote=None,
     ):
+        # One flush at a time, on the collector's own thread: a pow
+        # launch (a sequential scan of its windows) or a daemon's
+        # request on its one-at-a-time channel is long against the
+        # linger, and what is popped while one is out could only queue
+        # behind it.  So nothing is popped meanwhile — the rows that
+        # arrive during a flush leave together as the next one.
         super().__init__(
             max_batch=max_batch,
             max_wait=max_wait,
-            pipeline=pipeline,
+            pipeline=1,
             calibrate=calibrate,
         )
-        # Same crossover semantics as the signer: below it, one native
-        # host modexp per item beats any launch.  ALWAYS_HOST on CPU
-        # backends (set by the sidecar from calibration()).
+        # The signer's crossover semantics, counted in work: a group
+        # whose rows cost less than this many verify items runs on the
+        # native host tier.  ALWAYS_HOST on CPU backends (set by the
+        # sidecar from calibration()).
         self.device_threshold = (
             device_threshold
             if device_threshold is not None
             else ALWAYS_HOST
         )
-        #: Row widths (bits) whose pow programs are built; None:
-        #: nobody said (``SignerDomain.warm_rows`` has the rule).
+        #: Row classes whose pow programs are built — ``n`` for rows
+        #: whose exponents are no wider than they, ``(n, e)`` for a
+        #: longer exponent class; None: nobody said
+        #: (``ops.rns.pow_rows_warm`` has the rule).
         self.warm_rows: frozenset | None = None
+        self.remote = remote
+        for name in ("modexp.device", "modexp.host"):
+            metrics.incr(name, 0)  # a ratio has its denominator
 
     def apply_calibration(self, cal: dict) -> None:
         self._prefer_host = cal["prefer_host"]
@@ -888,139 +943,133 @@ class ModexpDispatcher(_BatchDispatcher):
             ALWAYS_HOST if cal["prefer_host"] else cal["verify_crossover"]
         )
 
-    def _width_groups(self, items: list, device_idx: list[int]):
-        from bftkv_tpu.ops import limb as limb_ops
+    def _width_groups(self, items: list, device_idx: list[int]) -> list:
+        """``[(n_bits, exp_bits, idxs)]``: one launch each.  A class the
+        chains cannot hold, or whose rows together cost less than the
+        crossover, gets none: its items are the host tier's (as are
+        those of a class whose program nobody built:
+        :meth:`_launch_group`)."""
         from bftkv_tpu.ops import rns as rns_ops
 
-        # One launch per limb-width group (uniform kernel shapes); a
-        # width the bases cannot hold, or whose program nobody built,
-        # gets none: its items are the host tier's.
-        by_width: dict[int, list[int]] = {}
+        by_class: dict[tuple[int, int], list[int]] = {}
         for i in device_idx:
-            w = limb_ops.nlimbs_for_bits(items[i][2].bit_length())
-            by_width.setdefault(w, []).append(i)
-        return {
-            w: idxs for w, idxs in by_width.items()
-            if rns_ops.chains(16 * w).pow
-            and rns_ops.pow_rows_warm(16 * w, self.warm_rows, len(idxs))
-        }
+            cls = _row_class(*items[i][1:])
+            if cls[1] is not None:
+                by_class.setdefault(cls, []).append(i)
+        groups = []
+        for (n_bits, exp_bits), idxs in by_class.items():
+            if (
+                len(idxs) * modexp_work(n_bits, exp_bits)
+                < self.device_threshold
+                or not rns_ops.chains(n_bits, exp_bits).pow
+            ):
+                continue
+            step = (
+                len(idxs) if exp_bits == n_bits else self.LONG_EXP_MAX_ROWS
+            )
+            groups += [
+                (n_bits, exp_bits, idxs[o : o + step])
+                for o in range(0, len(idxs), step)
+            ]
+        return groups
 
-    def _note_device_group(self, w: int, idxs: list[int]) -> None:
+    def _note_device_group(self, n_bits: int, idxs: list[int]) -> None:
         metrics.incr("modexp.device", len(idxs))
+        metrics.observe("modexp.device_batch", len(idxs))
         # Per-limb-width device occupancy: widths are the handful of
         # deployed modulus sizes, so the label stays bounded (capacity
         # plane joins on `width`).
         metrics.gauge(
             "modexpdispatch.device_occupancy",
             min(1.0, len(idxs) / self.max_batch),
-            labels={"width": str(w)},
+            labels={"width": str(n_bits // 16)},
+        )
+
+    @staticmethod
+    def _device_idx(items: list) -> list[int]:
+        return [
+            i
+            for i, (b, e, m) in enumerate(items)
+            if m > 2 and m % 2 == 1 and e >= 0 and 0 <= b
+        ]
+
+    def _launch_group(self, items: list, group: tuple):
+        """One group's launch, dispatched and not waited for (None: no
+        program was built for its class, counted, or the chain has no
+        rows for a modulus)."""
+        from bftkv_tpu.ops import rns as rns_ops
+
+        n_bits, exp_bits, idxs = group
+        if not rns_ops.pow_rows_warm(
+            n_bits, self.warm_rows, len(idxs), exp_bits
+        ):
+            return None
+        return rns_ops.power_mod_rns(
+            [items[i][0] for i in idxs],
+            [items[i][1] for i in idxs],
+            [items[i][2] for i in idxs],
+            n_bits=n_bits,
+            exp_bits=exp_bits,
+            defer=True,
         )
 
     def _run_batch(self, items: list) -> list[int]:
+        """EVERY group's launch is dispatched before ANY is waited for
+        (row classes ride the device stream back to back), then the
+        native host tier answers whatever no launch did: a group under
+        the crossover, an exponent past the classes, an even modulus, a
+        launch that failed."""
+        if self.remote is not None:
+            return self.remote.powmod_batch(items)
+        groups = []
+        if self.device_threshold < ALWAYS_HOST:  # else: nothing to ask
+            groups = self._width_groups(items, self._device_idx(items))
+        launches = []
+        for group in groups:
+            try:
+                launches.append(self._launch_group(items, group))
+            except Exception:
+                launches.append(None)  # incapable/hostile moduli: host
         out: list[int | None] = [None] * len(items)
-        device_idx: list[int] = []
-        if len(items) >= self.device_threshold:
-            device_idx = [
-                i
-                for i, (b, e, m) in enumerate(items)
-                if m > 2 and m % 2 == 1 and e >= 0 and 0 <= b
-            ]
-        if device_idx:
-            from bftkv_tpu.ops import rns as rns_ops
-
-            for w, idxs in self._width_groups(items, device_idx).items():
-                try:
-                    vals = rns_ops.power_mod_rns(
-                        [items[i][0] for i in idxs],
-                        [items[i][1] for i in idxs],
-                        [items[i][2] for i in idxs],
-                        n_bits=w * 16,
-                    )
-                except Exception:
-                    vals = None  # incapable/hostile moduli: host below
-                if vals is not None:
-                    self._note_device_group(w, idxs)
-                    for i, v in zip(idxs, vals):
-                        out[i] = int(v)
+        for (n_bits, _e, idxs), d in zip(groups, launches):
+            try:
+                vals = None if d is None else d.wait()
+            except Exception:
+                vals = None  # device failure: host fallback
+            if vals is not None:
+                self._note_device_group(n_bits, idxs)
+                for i, v in zip(idxs, vals):
+                    out[i] = int(v)
         self._host_fill(items, out)
         return out  # type: ignore[return-value]
 
-    def _launch_batch(self, items: list):
-        """Async tier: dispatch EVERY width group's launch before
-        blocking on ANY — RSA-2048 and RSA-3072 super-flushes ride the
-        device stream back to back instead of round-tripping one group
-        at a time.  Declines (``None`` → sync path) below the device
-        threshold or when the batch mixes in device-ineligible items,
-        so the host tier's behavior is untouched on calibrated-host
-        backends."""
-        if len(items) < self.device_threshold:
-            return None
-        if not all(
-            m > 2 and m % 2 == 1 and e >= 0 and 0 <= b
-            for b, e, m in items
-        ):
-            return None
-        from bftkv_tpu.ops import rns as rns_ops
-
-        launches: list[tuple[int, list[int], object]] = []
-        for w, idxs in self._width_groups(
-            items, list(range(len(items)))
-        ).items():
-            try:
-                d = rns_ops.power_mod_rns(
-                    [items[i][0] for i in idxs],
-                    [items[i][1] for i in idxs],
-                    [items[i][2] for i in idxs],
-                    n_bits=w * 16,
-                    defer=True,
-                )
-            except Exception:
-                d = None  # incapable moduli: host fallback on complete
-            launches.append((w, idxs, d))
-
-        def complete() -> list[int]:
-            out: list[int | None] = [None] * len(items)
-            for w, idxs, d in launches:
-                vals = None
-                if d is not None:
-                    try:
-                        vals = d.wait()
-                    except Exception:
-                        vals = None  # device failure: host fallback
-                if vals is not None:
-                    self._note_device_group(w, idxs)
-                    for i, v in zip(idxs, vals):
-                        out[i] = int(v)
-            self._host_fill(items, out)
-            return out  # type: ignore[return-value]
-
-        return complete
-
     def _host_fill(self, items: list, out: list) -> None:
-        """Host tier for every item the device didn't answer."""
+        """Host tier for every item the device didn't answer: one
+        native batch (``rsa.powmod_host_many``)."""
         from bftkv_tpu.crypto import rsa as rsamod
 
-        host = 0
-        for i, (b, e, m) in enumerate(items):
-            if out[i] is not None:
-                continue
-            host += 1
+        left = [i for i, v in enumerate(out) if v is None]
+        if not left:
+            return
+        for i in left:
+            _b, e, m = items[i]
             if m <= 0:
                 raise ValueError("modexp: modulus must be positive")
-            if (
-                rsamod._MM is not None
-                and m % 2 == 1
-                and m > 2
-                and e >= 0
-                and 0 <= b
-            ):
-                out[i] = rsamod._native_powmod(
-                    b % m, e, rsamod._mont_params(m)
+            if _row_class(e, m)[1] is None:
+                # past both exponent classes of its modulus (a
+                # threshold fragment of the second tree level and
+                # below): no chain holds it, counted by class
+                metrics.incr(
+                    "modexp.host.class",
+                    labels={
+                        "bits": str(1 << (e.bit_length() - 1).bit_length())
+                    },
                 )
-            else:
-                out[i] = pow(b, e, m)
-        if host:
-            metrics.incr("modexp.host", host)
+        for i, v in zip(
+            left, rsamod.powmod_host_many([items[i] for i in left])
+        ):
+            out[i] = v
+        metrics.incr("modexp.host", len(left))
 
     def _combine(self, chunks: list):
         return [v for chunk in chunks for v in chunk]
@@ -1084,6 +1133,35 @@ def get_signer() -> SignDispatcher | None:
     return _global_signer
 
 
+_global_modexp: ModexpDispatcher | None = None
+
+
+def install_modexp(dispatcher: ModexpDispatcher) -> ModexpDispatcher:
+    """Install (and start) the process-wide modexp domain: whoever
+    calls ``ops.modexp.BatchModExp`` (threshold RSA and DSA, TPA) is
+    asked for here first.  A replica daemon started with ``--sidecar``
+    installs a collector over its ``RemoteModexpDomain``
+    (``ModexpDispatcher(remote=...)``)."""
+    global _global_modexp
+    with _global_lock:
+        if _global_modexp is not None:
+            _global_modexp.stop()
+        _global_modexp = dispatcher.start()
+        return _global_modexp
+
+
+def uninstall_modexp() -> None:
+    global _global_modexp
+    with _global_lock:
+        if _global_modexp is not None:
+            _global_modexp.stop()
+            _global_modexp = None
+
+
+def get_modexp() -> ModexpDispatcher | None:
+    return _global_modexp
+
+
 def recalibrate() -> dict:
     """Force a fresh calibration and re-apply it to the installed
     dispatchers.
@@ -1105,3 +1183,4 @@ def recalibrate() -> dict:
 def uninstall_all() -> None:
     uninstall()
     uninstall_signer()
+    uninstall_modexp()

@@ -4,15 +4,26 @@ Every distributed-crypto subsystem in the reference bottoms out in
 ``big.Int.Exp`` loops — TPA's DH rounds (crypto/auth/auth.go), threshold
 RSA's per-fragment signing (crypto/threshold/rsa/rsa.go:140-178), and
 threshold DSA's partial-R combination (crypto/threshold/dsa/dsa.go:33-52).
-This engine replaces those per-item loops with one launch per request
-batch: the RNS pow chain (``ops.rns.power_mod_rns``) for operands up to
-2,048 bits, :func:`power_batch` (the limb Montgomery engine) for the
-wider ones, which no RNS width class is built for.
+This engine replaces those per-item loops with one batch per request.
 
-Policy: batches below ``min_batch`` (default 4, override with
-``BFTKV_TPU_MIN_MODEXP_BATCH``) run as host ``pow`` — a single modexp
-doesn't amortize a kernel launch. Per-modulus Montgomery precomputation
-is LRU-bounded since moduli can be influenced by remote peers.
+Where the process has a modexp domain installed
+(``ops.dispatch.install_modexp``: a replica daemon started with
+``--sidecar``), every request goes there first, whatever its size — a
+healthy threshold-RSA request holds ONE fragment, and the batch is made
+in the sidecar, across the daemon's handlers and across daemons.
+
+Otherwise the launch is this process's own: the RNS pow chain
+(``ops.rns.power_mod_rns``) for moduli up to 2,048 bits under
+exponents up to twice the modulus class + 64 bits (``ops.rns.chains``:
+a first-level threshold fragment of a 2,048-bit key, ~4,100 bits),
+:func:`power_batch` (the limb Montgomery engine) for wider operands
+that no RNS class is built for, up to ``MAX_EXP_LIMBS``.
+
+Policy of the local path: batches below ``min_batch`` (default 4,
+override with ``BFTKV_TPU_MIN_MODEXP_BATCH``) run as host ``pow`` — a
+single modexp doesn't amortize a kernel launch. Per-modulus Montgomery
+precomputation is LRU-bounded since moduli can be influenced by remote
+peers.
 """
 
 from __future__ import annotations
@@ -27,7 +38,23 @@ import numpy as np
 from bftkv_tpu import flags
 from bftkv_tpu.ops import bigint
 
-__all__ = ["BatchModExp", "power_batch"]
+__all__ = ["BatchModExp", "power_batch", "remote_route"]
+
+
+def remote_route(bits: int, exp_bits: int) -> bool:
+    """Whether ``x^e mod m`` (``m`` of ``bits`` bits, ``e`` of
+    ``exp_bits``), asked for in a replica daemon started with
+    ``--sidecar``, leaves for the sidecar and finds a device chain
+    there.  The way out is :meth:`BatchModExp.modexp`'s first question
+    (``ops.dispatch.install_modexp``), so what is left to answer is the
+    class: ``ops.rns.chains``, THE capability rule.  A deployment that
+    states one chip-owning sidecar for its quorum's modexps asks this
+    before it starts a process (``benchmarks/kinds/ca_issue.py``); a
+    program without the function runs them in the replica, on the
+    host."""
+    from bftkv_tpu.ops import rns
+
+    return rns.chains(16 * -(-bits // 16), exp_bits).pow
 
 
 @jax.jit
@@ -81,9 +108,12 @@ class BatchModExp:
             self._domains.move_to_end(key)
         return dom
 
-    # Exponents can outgrow the modulus (threshold-RSA fragments double
-    # in width per tree level — rsa.go:97-117). Past this limb width the
-    # window loop dominates and host pow wins; cap the device path.
+    # Exponents outgrow the modulus (threshold-RSA fragments double in
+    # width per tree level — rsa.go:97-117: ~4,100 bits at the first
+    # level of a 2,048-bit key, 8,192 / 16,384 / 32,768 below).  The
+    # first level rides the RNS chain's longer exponent class; past
+    # this limb width of the LIMB engine the window loop dominates and
+    # host pow wins: cap its device path.
     MAX_EXP_LIMBS = 256  # 4096 bits
 
     def modexp(self, pairs: list[tuple[int, int]], n: int) -> list[int]:
@@ -91,6 +121,12 @@ class BatchModExp:
         batch is big enough and ``n`` is odd (Montgomery-compatible)."""
         if not pairs:
             return []
+        from bftkv_tpu.ops import dispatch
+
+        installed = dispatch.get_modexp()
+        if installed is not None and n > 1:
+            # before min_batch: one remote-bound item is no host pow
+            return installed.submit([(b % n, e, n) for b, e in pairs])
         if len(pairs) < self.min_batch or n % 2 == 0 or n <= 1:
             return [pow(b % n, e, n) for b, e in pairs]
         from bftkv_tpu.ops import limb
@@ -98,19 +134,21 @@ class BatchModExp:
         nlimbs = limb.nlimbs_for_bits(n.bit_length())
         max_e = max(e for _, e in pairs)
 
-        # Prefer the RNS windowed-modexp kernel: it covers
-        # moduli/exponents up to the context width.
+        # Prefer the RNS windowed-modexp kernel: it covers moduli up
+        # to 2,048 bits under either exponent class of their row width
+        # (``rns.exp_class``: up to the width, or up to twice it + 64).
         # Sub-2^12 primes cannot fund a 4096-bit base pair, so wider
-        # operands (threshold-RSA fragment exponents grow past the key
-        # size per tree level, rsa.go:97-117) stay on the limb path.
+        # moduli, and the exponents of the second tree level and below
+        # (rsa.go:97-117), stay on the limb path.
         # power_mod_rns stages operands through the persistent devbuf
-        # ring for its width class, so per-call marshalling here is
-        # just the list splits below.
-        width = max(n.bit_length(), max_e.bit_length())
-        nb = next((w for w in (1024, 2048) if width <= w), None)
-        if nb is not None:
+        # ring for its class, so per-call marshalling here is just the
+        # list splits below.
+        from bftkv_tpu.ops import rns
+
+        nb = next((w for w in (1024, 2048) if n.bit_length() <= w), None)
+        eb = nb and rns.exp_class(nb, max_e.bit_length())
+        if eb:
             from bftkv_tpu.metrics import registry as metrics
-            from bftkv_tpu.ops import rns
 
             try:
                 vals = rns.power_mod_rns(
@@ -118,6 +156,7 @@ class BatchModExp:
                     [e for _, e in pairs],
                     [n] * len(pairs),
                     n_bits=nb,
+                    exp_bits=eb,
                 )
             except Exception:
                 # power_mod_rns signals every *legitimately* incapable

@@ -36,7 +36,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from bftkv_tpu.ops import rns
 
-__all__ = ["pow_pallas", "verify_pallas", "TILE_POW", "TILE_VERIFY"]
+__all__ = [
+    "pow_pallas", "jitted_pow", "verify_pallas", "TILE_POW", "TILE_VERIFY",
+]
 
 from bftkv_tpu import flags
 
@@ -65,6 +67,15 @@ def _tile_env(name: str, default: str) -> int:
 
 
 TILE_POW = _tile_env("BFTKV_PALLAS_TILE_POW", "256")
+
+
+def _pow_tile(kpad: int) -> int:
+    """``TILE_POW`` is budgeted at kpad = 128 (rows to 1,024 bits);
+    wider rows take as many fewer a tile (2,048-bit rows: kpad 256,
+    tile 128 — 256 passes the scoped 16 MB by 0.5 MB on a v5e)."""
+    return max(8, TILE_POW * 128 // kpad)
+
+
 TILE_VERIFY = _tile_env("BFTKV_PALLAS_TILE_VERIFY", "128")
 PR = rns.PR
 _PRF = np.float32(PR)
@@ -382,9 +393,12 @@ def _verify_prep(k: int, kpad: int):
 
 
 @functools.lru_cache(maxsize=8)
-def _pow_call(digits: int, n_bits: int, tile: int, interpret: bool):
+def _pow_call(
+    digits: int, n_bits: int, tile: int, interpret: bool,
+    windows: int | None = None,
+):
     pc = _pad_consts(digits, n_bits)
-    kpad, w_steps = pc.kpad, digits * 4
+    kpad, w_steps = pc.kpad, windows or digits * 4
     consts = tuple(jnp.asarray(a) for a in pc.arrays())
     kernel = functools.partial(
         _pow_body, pc.invMq_pr, pc.invM_pr, w_steps
@@ -422,6 +436,36 @@ def _pow_call(digits: int, n_bits: int, tile: int, interpret: bool):
     return rns_pow_pallas
 
 
+@functools.lru_cache(maxsize=8)
+def jitted_pow(
+    digits: int, n_bits: int, windows: int, rows: int, name: str,
+    interpret: bool = False,
+):
+    """The fused chain as ONE program under ``rns._jitted_pow``'s
+    signature and result — uint8 operands, the moduli's rows gathered
+    on the device, (rows, k) σ — so that a launch of it is dispatched
+    and fetched as a launch of the XLA chain is.  ``name`` is the
+    program's (``rns._pow_name``): a device trace shows one module a
+    launch and a kernel a tile, not a fusion a Barrett link."""
+    pc = _pad_consts(digits, n_bits)
+    k, kpad = pc.k, pc.kpad
+    # built here, outside any trace: the call's constants are arrays
+    run = _pow_call(
+        digits, n_bits, min(_pow_tile(kpad), rows), interpret, windows
+    )
+    prep = _pow_prep(k, kpad)
+
+    def rns_pow(base_halves_u8, exp_nibbles_t_u8, idx, ukey):
+        return run(
+            base_halves_u8.astype(jnp.float32),
+            exp_nibbles_t_u8.astype(jnp.float32),
+            *prep(idx, ukey),
+        )[:, :k]
+
+    rns_pow.__name__ = name
+    return jax.jit(rns_pow)
+
+
 def pow_pallas(
     base_halves_u8: np.ndarray,  # (T, 2·digits) uint8
     exp_nibbles_t_u8: np.ndarray,  # (W, T) uint8, MS nibble first
@@ -433,11 +477,13 @@ def pow_pallas(
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Drop-in for the XLA ``_jitted_pow`` path: returns (T, kpad) σ
-    whose first k columns match ``rns._pow_kernel``'s output."""
+    whose first k columns match ``rns._pow_kernel``'s output.  The
+    step count is the staged window array's."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     t = base_halves_u8.shape[0]
-    tile = min(TILE_POW, t)
+    windows = exp_nibbles_t_u8.shape[0]
+    tile = min(_pow_tile(_pad_consts(digits, n_bits).kpad), t)
     if t % tile:
         # grid = t // tile would silently drop the tail rows; in-repo
         # callers pad to powers of two, but this is a documented
@@ -445,7 +491,7 @@ def pow_pallas(
         raise ValueError(f"batch {t} not a multiple of tile {tile}")
     pc = _pad_consts(digits, n_bits)
     k, kpad = pc.k, pc.kpad
-    run = _pow_call(digits, n_bits, tile, interpret)
+    run = _pow_call(digits, n_bits, tile, interpret, windows)
 
     # Gather + pad per-row key tensors on device (XLA, outside pallas).
     nb, nq, nr, ninvb, m2b, m2q, m2r = _pow_prep(k, kpad)(

@@ -47,6 +47,8 @@ from bftkv_tpu.metrics import registry as metrics
 __all__ = [
     "Chains",
     "chains",
+    "exp_class",
+    "long_exp_bits",
     "RNSContext",
     "context",
     "pow_context",
@@ -118,14 +120,45 @@ class Chains(NamedTuple):
     pow: bool  # a row (modulus and exponent) this wide rides the pow chain
 
 
+def long_exp_bits(n_bits: int) -> int:
+    """The longer of a modulus class's two exponent classes, in bits:
+    ``2 * n_bits + 64``.  A threshold-RSA first-level fragment of an
+    ``n_bits``-bit d dealt (., n) is d minus n - 1 random values below
+    ``2^(2 * n_bits - 1)``: up to ``2 * n_bits + ceil(log2 n) + 1``
+    bits (crypto/threshold/rsa.py:_split_key); the 64 is that for any
+    n the tree can be dealt for, rounded up to whole bytes of 4-bit
+    windows."""
+    return 2 * n_bits + 64
+
+
+def exp_class(n_bits: int, exp_bits: int) -> int | None:
+    """The exponent class (bits) of the pow chain at ``n_bits``-bit
+    rows that holds an exponent of ``exp_bits`` bits: ``n_bits`` itself
+    (every sign row: dp, dq; whole-modulus rows whose exponents are no
+    wider), :func:`long_exp_bits` beyond it, None past that."""
+    if exp_bits <= n_bits:
+        return n_bits
+    if exp_bits <= long_exp_bits(n_bits):
+        return long_exp_bits(n_bits)
+    return None
+
+
 @functools.lru_cache(maxsize=256)
-def chains(bits: int) -> Chains:
-    """THE capability rule: what the RNS chains can take at ``bits``.
+def chains(bits: int, exp_bits: int | None = None) -> Chains:
+    """THE capability rule: what the RNS chains can take at ``bits``
+    (the modulus) and, for the pow chain, ``exp_bits`` (the exponent;
+    None: no wider than the modulus).
 
     The pow chain is compiled per row width (``context(digits,
-    n_bits)``), so it takes any width the prime supply can build two
+    n_bits)``), so it takes any modulus the prime supply can build two
     bases for — about 2,130 bits: the CRT halves of RSA-2048, -3072
-    and -4096, whole moduli up to 2048 bits.  The verify chain works
+    and -4096, whole moduli up to 2048 bits.  Its window count is a
+    shape of its own: at each row width two exponent classes have
+    programs, exponents up to the row width (``rns_pow_<bits>``) and
+    exponents up to ``long_exp_bits(bits)`` = 2 x bits + 64
+    (``rns_pow_<bits>_e<exp bits>``: 4,160 bits at 2,048-bit rows, what
+    a first-level threshold-RSA fragment of a 2,048-bit key needs);
+    a longer exponent rides no chain.  The verify chain works
     on whole moduli in the one context ``context()`` and takes what
     fits its digits.  A wider modulus is not hostile, it is beyond the
     f32-exact design (channel products < 2^24), and belongs to the
@@ -137,7 +170,9 @@ def chains(bits: int) -> Chains:
         return Chains(False, False)
     return Chains(
         verify=bits <= 16 * DIGITS and _bases_hold(16 * DIGITS),
-        pow=_bases_hold(bits),
+        pow=_bases_hold(bits) and (
+            exp_bits is None or exp_class(bits, exp_bits) is not None
+        ),
     )
 
 
@@ -147,24 +182,30 @@ _unwarmed_logged: set = set()
 def note_unwarmed(what: str, bits: int, items: int) -> None:
     """Items of a width whose device program the owner did not build
     (the sidecar's warm-up, by the deployment's declared identity
-    widths): they are served from the host tier — counted per item
-    (``sidecar.unwarmed_width``), logged once a kind and width — and
-    never compile inside a request."""
+    widths and CA width): they are served from the host tier — counted
+    per item (``sidecar.unwarmed_width``), logged once a kind and width
+    — and never compile inside a request."""
     metrics.incr("sidecar.unwarmed_width", items)
     if (what, bits) not in _unwarmed_logged and len(_unwarmed_logged) < 64:
         _unwarmed_logged.add((what, bits))
         logging.getLogger("bftkv_tpu.ops.rns").warning(
             "%s of %d bits arrived, and this deployment's declared "
-            "identity widths (BFTKV_IDENTITY_BITS) built no device "
+            "widths (BFTKV_IDENTITY_BITS, BFTKV_CA_BITS) built no device "
             "program for them: served on the host tier", what, bits,
         )
 
 
-def pow_rows_warm(n_bits: int, warm_rows, items: int = 1) -> bool:
-    """Whether a pow launch at ``n_bits`` may go to the device now.
-    ``warm_rows`` is the owner's word on which row widths have their
-    programs built (``None``: nobody said, compile on first use)."""
-    if warm_rows is None or n_bits in warm_rows:
+def pow_rows_warm(
+    n_bits: int, warm_rows, items: int = 1, exp_bits: int | None = None
+) -> bool:
+    """Whether a pow launch at ``n_bits``-bit rows (exponent class
+    ``exp_bits``; None: the rows' own width) may go to the device now.
+    ``warm_rows`` is the owner's word on which row classes have their
+    programs built — ``n`` for rows whose exponents are no wider than
+    they, ``(n, e)`` for a longer exponent class — or ``None``: nobody
+    said, compile on first use."""
+    cls = n_bits if exp_bits in (None, n_bits) else (n_bits, exp_bits)
+    if warm_rows is None or cls in warm_rows:
         return True
     note_unwarmed("pow rows", n_bits, items)
     return False
@@ -574,8 +615,19 @@ def _pow_kernel(cn: _Consts, base_halves, exp_nibbles_t, key):
     return _mulmod(vb, cn.invMi_b, cn.ib, cn.pb)
 
 
-@functools.lru_cache(maxsize=4)
-def _jitted_pow(digits: int, n_bits: int, donate: bool = False):
+def _pow_name(n_bits: int, exp_bits: int | None) -> str:
+    """A pow program's name: its row width and, where the exponent
+    class is not the rows' own, that too (``rns_pow_2048_e4160``)."""
+    if exp_bits in (None, n_bits):
+        return f"rns_pow_{n_bits}"
+    return f"rns_pow_{n_bits}_e{exp_bits}"
+
+
+@functools.lru_cache(maxsize=8)
+def _jitted_pow(
+    digits: int, n_bits: int, donate: bool = False,
+    exp_bits: int | None = None,
+):
     """uint8 operands + device-side gather of the (few) unique moduli —
     same transfer-lean scheme as the verify path.
 
@@ -587,7 +639,11 @@ def _jitted_pow(digits: int, n_bits: int, donate: bool = False):
     warning, so callers gate it on the backend.
 
     The program is named after its width (``rns_pow_1024``: the CRT
-    halves of RSA-2048), so a device trace tells the chains apart."""
+    halves of RSA-2048) and, where the exponent class is the longer
+    one (``exp_bits``), after that too (``rns_pow_2048_e4160``: a
+    first-level threshold fragment), so a device trace tells the chains
+    apart.  The window count is the staged array's: one function a
+    class, one program a class and bucket."""
     cn = _Consts(context(digits, n_bits))
 
     def rns_pow(base_halves_u8, exp_nibbles_t_u8, idx, ukey):
@@ -599,7 +655,7 @@ def _jitted_pow(digits: int, n_bits: int, donate: bool = False):
             key,
         )
 
-    rns_pow.__name__ = f"rns_pow_{n_bits}"
+    rns_pow.__name__ = _pow_name(n_bits, exp_bits)
     return jax.jit(rns_pow, donate_argnums=(0, 1, 2) if donate else ())
 
 
@@ -667,12 +723,13 @@ class DeferredModexp:
         return self._value
 
 
-def _pow_staging(digits: int, n_bits: int, padded: int):
+def _pow_staging(digits: int, n_bits: int, padded: int, windows: int):
     """One launch's operand arrays — a persistent devbuf slot when the
-    rings are on (``None`` ring → plain throwaway arrays)."""
+    rings are on (``None`` ring → plain throwaway arrays).  ``windows``
+    is the exponent class in 4-bit windows: a ring a class."""
     shapes = {
         "base_halves": ((padded, 2 * digits), np.uint8),
-        "nib_t": ((4 * digits, padded), np.uint8),
+        "nib_t": ((windows, padded), np.uint8),
         "idx": ((padded,), np.int32),
     }
 
@@ -681,9 +738,10 @@ def _pow_staging(digits: int, n_bits: int, padded: int):
 
     if not devbuf.enabled():
         return None, devbuf.Slot(make())
-    ring = devbuf.ring_for(
-        f"pow:{digits}:{n_bits}:{padded}", make, width=str(digits)
-    )
+    name = f"pow:{digits}:{n_bits}:{padded}"
+    if windows != 4 * digits:
+        name += f":e{4 * windows}"
+    ring = devbuf.ring_for(name, make, width=str(digits))
     slot = ring.acquire()
     if slot is None:
         return None, ring.fresh()  # ring saturated: unpooled fallback
@@ -752,15 +810,21 @@ class _PowKeyTable:
             return at, self._dev
 
 
-def exp_nibbles(exps: list[int], digits: int) -> np.ndarray:
-    """``(len(exps), 4 * digits)`` uint8: each exponent's 4-bit windows,
-    most significant first, as the pow chain scans them.  A number's
-    little-endian bytes are its nibbles two by two, low one first."""
+def exp_nibbles(
+    exps: list[int], digits: int, windows: int | None = None
+) -> np.ndarray:
+    """``(len(exps), windows)`` uint8: each exponent's 4-bit windows,
+    most significant first, as the pow chain scans them — ``windows``
+    (even) is the exponent class, ``4 * digits`` (exponents as wide as
+    the rows) where none is given.  A number's little-endian bytes are
+    its nibbles two by two, low one first."""
+    if windows is None:
+        windows = 4 * digits
     raw = np.frombuffer(
-        b"".join(e.to_bytes(2 * digits, "little") for e in exps),
+        b"".join(e.to_bytes(windows // 2, "little") for e in exps),
         dtype=np.uint8,
-    ).reshape(len(exps), 2 * digits)
-    nib = np.empty((len(exps), 4 * digits), dtype=np.uint8)
+    ).reshape(len(exps), windows // 2)
+    nib = np.empty((len(exps), windows), dtype=np.uint8)
     nib[:, 0::2] = raw & 0xF
     nib[:, 1::2] = raw >> 4
     return nib[:, ::-1]
@@ -768,22 +832,31 @@ def exp_nibbles(exps: list[int], digits: int) -> np.ndarray:
 
 def power_mod_rns(
     bases: list[int], exps: list[int], mods: list[int], *,
-    n_bits: int = 1024, defer: bool = False, op: str = "modexp",
+    n_bits: int = 1024, exp_bits: int | None = None,
+    defer: bool = False, op: str = "modexp",
 ):
     """Batched x^e mod m with per-row (x, e, m) — the threshold-RSA
     workhorse.  Returns a list of ints, or None when any modulus or
     exponent cannot ride the RNS path (caller falls back).
 
-    ``n_bits`` bounds the modulus/exponent width (threshold fragments
-    rsa.go:140-178).  The way in for callers whose exponents come a
-    row: it turns the rows' integers into what :func:`pow_rows_rns`
-    takes — the launch itself, ``defer`` and ``op`` are that
-    function's.
+    ``n_bits`` is the modulus class (the row width) and ``exp_bits``
+    the exponent class, a shape of its own: ``n_bits`` where none is
+    given, or :func:`long_exp_bits` of it (a first-level threshold
+    fragment is about twice its modulus: rsa.go:97-117, 140-178); an
+    exponent one bit past its class, or a class :func:`chains` does
+    not hold, answers None.  The way in for callers whose exponents
+    come a row: it turns the rows' integers into what
+    :func:`pow_rows_rns` takes — the launch itself, ``defer`` and
+    ``op`` are that function's.
     """
     if not mods:
         return []
+    if exp_bits is None:
+        exp_bits = n_bits
+    if exp_class(n_bits, exp_bits) != exp_bits:
+        return None
     for e in exps:
-        if e < 0 or e.bit_length() > n_bits:
+        if e < 0 or e.bit_length() > exp_bits:
             return None
     ctx = pow_context(n_bits)
     with trace.leaf("flush.stage", op, items=len(mods), bits=n_bits):
@@ -801,7 +874,11 @@ def power_mod_rns(
             (b % m).to_bytes(2 * ctx.digits, "little")
             for b, m in zip(bases, mods)
         )
-        nib_cols = exp_nibbles(exps, ctx.digits)
+        nib_cols = exp_nibbles(
+            exps, ctx.digits,
+            # the rows' own width: the context's digits, as ever
+            None if exp_bits == n_bits else -(-exp_bits // 8) * 2,
+        )
     return pow_rows_rns(
         n_bits, umods, row_mod, base_bytes, nib_cols, None,
         defer=defer, op=op,
@@ -824,10 +901,14 @@ def pow_rows_rns(
       joined ``2 * digits``-byte little-endian strings — the halves of
       a number's 16-bit little-endian digits, low half first, ARE its
       little-endian bytes (:func:`digits_to_halves_u8`);
-    - ``nib_cols`` (c, 4 * digits) uint8 from :func:`exp_nibbles` and
+    - ``nib_cols`` (c, windows) uint8 from :func:`exp_nibbles` and
       ``row_col`` (t,): each row's exponent among them — a signer's
       exponents are constants of its keys, computed once a key; None
-      where the exponents come a row (c = t, in order).
+      where the exponents come a row (c = t, in order).  Its second
+      axis IS the launch's exponent class: ``4 * digits`` windows for
+      exponents as wide as the rows (every sign), more for the longer
+      class — another staging ring, another program
+      (:func:`_jitted_pow`), the same kernel.
 
     Returns the rows' ``base^exp mod modulus`` as a list of ints, or
     None when a modulus has no key rows (caller falls back).
@@ -844,6 +925,12 @@ def pow_rows_rns(
     ctx = pow_context(n_bits)
     digits = ctx.digits
     t = len(row_mod)
+    windows = nib_cols.shape[1]
+    # None for rows whose exponents are as wide as they: the programs,
+    # rings and span attributes of every sign launch stay as they were.
+    exp_bits = None if windows == 4 * digits else 4 * windows
+    long_exp = {} if exp_bits is None else {"exp_bits": exp_bits}
+    attrs = {"bits": n_bits, **long_exp}
     ring = slot = None
     released = False
 
@@ -855,7 +942,7 @@ def pow_rows_rns(
                 ring.release(slot)
 
     try:
-        with trace.leaf("flush.stage", op, items=t, bits=n_bits) as sp:
+        with trace.leaf("flush.stage", op, items=t, **attrs) as sp:
             placed = ctx.pow_keys.place(umods)
             if placed is None:
                 return None
@@ -867,7 +954,7 @@ def pow_rows_rns(
             # Stage operands into a persistent slot (devbuf ring) or
             # throwaway arrays.  The pad region broadcasts row 0 in
             # place: its base, its exponent, its modulus.
-            ring, slot = _pow_staging(digits, n_bits, padded)
+            ring, slot = _pow_staging(digits, n_bits, padded, windows)
             bh, nt, ix = slot["base_halves"], slot["nib_t"], slot["idx"]
             bh[:t] = np.frombuffer(base_bytes, dtype=np.uint8).reshape(
                 t, 2 * digits
@@ -883,14 +970,16 @@ def pow_rows_rns(
             pow_args = (bh, nt, ix, ukey)
 
         def unpack(sigma: np.ndarray) -> list[int]:
-            with trace.leaf("flush.unpack", op, items=t, bits=n_bits):
+            with trace.leaf("flush.unpack", op, items=t, **attrs):
                 vals = _sigma_to_ints(ctx, sigma)
                 return [
                     v % umods[u] for v, u in zip(vals, row_mod.tolist())
                 ]
 
         sigma = None
-        if _use_pallas("BFTKV_RNS_POW_BACKEND"):
+        # the rows' own class (every sign) rides the fused chain only
+        # where it is forced: no measurement has judged it there yet
+        if exp_bits is None and _use_pallas("BFTKV_RNS_POW_BACKEND"):
             try:
                 from bftkv_tpu.ops import pallas_rns
 
@@ -911,23 +1000,45 @@ def pow_rows_rns(
             _release()
             res = unpack(sigma)
             return DeferredModexp(lambda: res) if defer else res
-        if _shardable(padded):
-            fn = _jitted_pow_sharded(digits, n_bits)
-        else:
+
+        def xla_chain():
+            if _shardable(padded):
+                return _jitted_pow_sharded(digits, n_bits, **long_exp)
             # Donation only pays (and only works) on real accelerators;
             # see _jitted_pow.
-            fn = _jitted_pow(
+            return _jitted_pow(
                 digits, n_bits,
                 donate=jax.default_backend() in ("tpu", "gpu"),
+                **long_exp,
             )
+
         with trace.leaf(
-            "flush.launch", op, items=t, bucket=padded, bits=n_bits
+            "flush.launch", op, items=t, bucket=padded, **attrs
         ):
-            dev = fn(*pow_args)  # jax dispatch is async: not a result yet
+            # jax dispatch is async: ``dev`` is not a result yet
+            dev = None
+            if exp_bits is not None and _use_pallas(
+                "BFTKV_RNS_POW_BACKEND", long_exp=True
+            ):
+                # the longer class on one chip: the fused chain as one
+                # program, launched and fetched as the XLA chain is
+                try:
+                    from bftkv_tpu.ops import pallas_rns
+
+                    dev = pallas_rns.jitted_pow(
+                        digits, n_bits, windows, padded,
+                        _pow_name(n_bits, exp_bits),
+                        jax.default_backend() != "tpu",
+                    )(*pow_args)
+                    _PALLAS_STATUS["pow"] = "ok"
+                except Exception as e:
+                    _pallas_fell_back("pow", e)
+            if dev is None:
+                dev = xla_chain()(*pow_args)
 
         def finish() -> list[int]:
             try:
-                with trace.leaf("flush.fetch", op, items=t, bits=n_bits):
+                with trace.leaf("flush.fetch", op, items=t, **attrs):
                     s = np.asarray(dev)[:t]
             finally:
                 # Materialized (or launch failed): the device no longer
@@ -1007,25 +1118,35 @@ def _pallas_fell_back(which: str, e: Exception) -> None:
     )
 
 
-def _auto_backend(platform: str, n_devices: int) -> str:
+def _auto_backend(
+    platform: str, n_devices: int, long_exp: bool = False
+) -> str:
     """What ``auto`` resolves to, from what the process can observe.
 
     Off TPU the fused chains would run in interpret mode, far slower
     than the XLA kernels; on a multi-chip host the sharded XLA path
     spreads the batch over every device (see :func:`_mesh`).  On one
-    TPU chip the fused chains are the candidate, but no measurement
-    has judged them against the XLA chains yet (ROADMAP S4), so every
-    case resolves to ``xla`` — what a fresh machine has always run."""
+    TPU chip the fused chains are the candidate.  The pow chain's
+    LONGER exponent class (``long_exp``) has its measurement — 10.0 /
+    17.3 ms a launch at 64 / 128 rows against the XLA chain's 37.7 /
+    36.6 (PERF.md §6, PR 33) — and rides the fused chain there; no
+    measurement has judged the verify chain and the signs' pow class
+    yet (ROADMAP S4), so every other case resolves to ``xla`` — what a
+    fresh machine has always run."""
+    if long_exp and platform == "tpu" and n_devices == 1:
+        return "pallas"
     return "xla"
 
 
-def _use_pallas(env: str) -> bool:
+def _use_pallas(env: str, long_exp: bool = False) -> bool:
     """Backend choice for the fused VMEM-resident Pallas chains
     (:mod:`bftkv_tpu.ops.pallas_rns`): ``pallas``/``xla`` force,
     ``auto`` (default) is :func:`_auto_backend`."""
     mode = flags.raw(env, "auto")
     if mode == "auto":
-        mode = _auto_backend(jax.default_backend(), len(jax.devices()))
+        mode = _auto_backend(
+            jax.default_backend(), len(jax.devices()), long_exp
+        )
     return mode == "pallas"
 
 
@@ -1081,8 +1202,10 @@ def _jitted_verify_gather_sharded():
     )
 
 
-@functools.lru_cache(maxsize=4)
-def _jitted_pow_sharded(digits: int, n_bits: int):
+@functools.lru_cache(maxsize=8)
+def _jitted_pow_sharded(
+    digits: int, n_bits: int, exp_bits: int | None = None
+):
     from jax.sharding import PartitionSpec as P
 
     cn = _Consts(context(digits, n_bits))
@@ -1097,7 +1220,7 @@ def _jitted_pow_sharded(digits: int, n_bits: int):
             key,
         )
 
-    rns_pow_sharded.__name__ = f"rns_pow_{n_bits}_sharded"
+    rns_pow_sharded.__name__ = _pow_name(n_bits, exp_bits) + "_sharded"
     b = P("batch")
     return jax.jit(
         _shard_map(

@@ -2264,37 +2264,52 @@ class Client(Protocol):
         """Threshold-sign ``tbs`` with the CA key dealt under ``caname``;
         loops phases until the signature completes
         (reference: client.go:509-546)."""
+        with metrics.timer("client.dist_sign"):
+            return self._dist_sign(caname, tbs, algo, hash_name)
+
+    def _dist_sign(
+        self, caname: str, tbs: bytes, algo: ThresholdAlgo, hash_name: str
+    ) -> bytes:
         proc = self.threshold.new_process(tbs, algo, hash_name)
         while True:
-            nodes, req = proc.make_request()
-            if not nodes:
-                raise ERR_INSUFFICIENT_NUMBER_OF_RESPONSES
-            data = pkt.serialize(caname.encode(), req, nfields=2)
-            sig_out = None
-            err_out: Exception | None = None
-            succ = 0
-            errs: list = []
+            with metrics.timer("client.dist_sign.round"):
+                sig = self._dist_sign_round(caname, proc)
+            if sig is not None:
+                return sig
 
-            def cb(res: tp.MulticastResponse) -> bool:
-                nonlocal sig_out, err_out, succ
-                if res.err is None and res.data is not None:
-                    succ += 1
-                    try:
-                        sig_out = proc.process_response(res.data, res.peer)
-                    except Exception as e:
-                        err_out = e
-                        return True
-                    return sig_out is not None
-                if res.err is not None:
-                    errs.append(res.err)
-                return False
+    def _dist_sign_round(self, caname: str, proc) -> bytes | None:
+        """One round of :meth:`dist_sign`: the signature, or None where
+        another round has to ask for more fragments;
+        ``client.dist_sign.round`` counts them (1 a signature when all
+        servers answer)."""
+        nodes, req = proc.make_request()
+        if not nodes:
+            raise ERR_INSUFFICIENT_NUMBER_OF_RESPONSES
+        data = pkt.serialize(caname.encode(), req, nfields=2)
+        sig_out = None
+        err_out: Exception | None = None
+        succ = 0
+        errs: list = []
 
-            self.tr.multicast(tp.DISTSIGN, nodes, data, cb)
-            if isinstance(err_out, ERR_CONTINUE):
-                continue
-            if err_out is not None:
-                raise err_out
-            if sig_out is not None:
-                return sig_out
-            if succ == 0:  # no more new responses
-                raise majority_error(errs, ERR_INSUFFICIENT_NUMBER_OF_RESPONSES)
+        def cb(res: tp.MulticastResponse) -> bool:
+            nonlocal sig_out, err_out, succ
+            if res.err is None and res.data is not None:
+                succ += 1
+                try:
+                    sig_out = proc.process_response(res.data, res.peer)
+                except Exception as e:
+                    err_out = e
+                    return True
+                return sig_out is not None
+            if res.err is not None:
+                errs.append(res.err)
+            return False
+
+        self.tr.multicast(tp.DISTSIGN, nodes, data, cb)
+        if isinstance(err_out, ERR_CONTINUE):
+            return None
+        if err_out is not None:
+            raise err_out
+        if sig_out is None and succ == 0:  # no more new responses
+            raise majority_error(errs, ERR_INSUFFICIENT_NUMBER_OF_RESPONSES)
+        return sig_out

@@ -1313,11 +1313,19 @@ class Server(Protocol):
         return None
 
     def _dist_sign(self, req: bytes, peer, sender) -> bytes | None:
-        p = pkt.parse(req)
-        params = self.storage.read(HIDDEN_PREFIX + (p.variable or b""), 0)
-        return self.threshold.sign(
-            params, p.value, (peer or sender).id, self.self_node.id
-        )
+        """One DISTSIGN request.  ``server.dist_sign.share`` is the
+        storage read of the share (2.2 MB at (7,10)), inside the
+        handler's ``server.dist_sign.handler``; its parse is timed
+        where it happens (``threshold.rsa.parse``)."""
+        with metrics.timer("server.dist_sign.handler"):
+            p = pkt.parse(req)
+            with metrics.timer("server.dist_sign.share"):
+                params = self.storage.read(
+                    HIDDEN_PREFIX + (p.variable or b""), 0
+                )
+            return self.threshold.sign(
+                params, p.value, (peer or sender).id, self.self_node.id
+            )
 
     # -- revocation (reference: server.go:543-560) ------------------------
 

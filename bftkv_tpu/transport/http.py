@@ -71,6 +71,19 @@ class _Handler(BaseHTTPRequestHandler):
     #: clients pool persistent connections now, and an idle connection
     #: must release its server thread instead of parking it forever.
     timeout = 60.0
+    #: A response leaves as ONE write, on a TCP_NODELAY socket.
+    #: http.server writes the headers and then the body; unbuffered
+    #: (the default) that is two segments, and with Nagle's algorithm
+    #: on, the second — under one segment (64 KB on loopback) — waits
+    #: for the ACK of the first, which the client, having nothing to
+    #: send, delays ~40 ms: every RPC with a small answer stood still
+    #: that long (measured: 44.0 ms a post of a 900 B answer against
+    #: 0.23 with TCP_NODELAY, 0.19 with one write).  Buffered, the
+    #: headers and a body under the buffer's size go out together when
+    #: ``handle_one_request`` flushes, and the client — one interpreter
+    #: for all of a caller's fan-out — reads an answer in one ``recv``.
+    disable_nagle_algorithm = True
+    wbufsize = 64 * 1024
 
     def log_message(self, fmt, *args):  # quiet; observability lives upstream
         pass

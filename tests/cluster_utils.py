@@ -7,6 +7,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field
 
+import contextlib
 import itertools
 import os
 
@@ -160,3 +161,35 @@ def start_cluster(
         cluster.gateways.append(gw)
         cluster.gateway_addrs[ident.name] = dial
     return cluster
+
+
+@contextlib.contextmanager
+def modexp_route(route: str, sock_dir):
+    """The way a server's ``BatchModExp`` requests leave: ``"local"``
+    (no domain installed: this process's own engine) or ``"sidecar"``
+    (what a daemon started with ``--sidecar`` installs: a collector
+    over a ``RemoteModexpDomain`` on a key-carrying unix socket, here
+    against an in-process sidecar service)."""
+    if route == "local":
+        yield None
+        return
+    from bftkv_tpu.cmd import verify_sidecar as vs
+    from bftkv_tpu.crypto.remote_verify import RemoteModexpDomain
+    from bftkv_tpu.ops import dispatch
+
+    addr = f"unix:{sock_dir}/modexp.sock"
+    srv, _t = vs.serve(addr)
+    domain = RemoteModexpDomain(addr)
+    dispatch.install_modexp(
+        dispatch.ModexpDispatcher(
+            remote=domain, calibrate=False, max_wait=0.002
+        )
+    )
+    try:
+        yield domain
+    finally:
+        dispatch.uninstall_modexp()
+        domain.channel.close()
+        srv.service.stop()
+        srv.shutdown()
+        srv.server_close()

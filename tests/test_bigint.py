@@ -119,13 +119,15 @@ def test_mont_exp(bits, ebits):
         assert g == pow(x, ei, n)
 
 
-@pytest.mark.parametrize("ebits", [2049, 4096])
+@pytest.mark.parametrize("ebits", [2113, 4096])
 def test_modexp_engine_takes_exponents_no_rns_context_holds(
     ebits, monkeypatch
 ):
-    """The use that keeps ``ops.modexp.power_batch``: threshold-RSA
-    fragment exponents wider than 2,048 bits (up to 4,096) stay on the
-    device through the limb Montgomery engine, and equal ``pow``."""
+    """The use that keeps ``ops.modexp.power_batch``: exponents past
+    both classes of their modulus's RNS rows (a 512-bit modulus rides
+    1,024-bit rows: up to 2 x 1,024 + 64 = 2,112 bits) stay on the
+    device through the limb Montgomery engine up to 4,096 bits, and
+    equal ``pow``."""
     from bftkv_tpu.ops import modexp, rns
 
     rng = random.Random(ebits)

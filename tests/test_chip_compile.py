@@ -74,11 +74,18 @@ def _operands(kind: str, rows: int, place, place_t, place_key):
             _key_shapes(rns.context(), place_key),
         )
     # CRT halves: RSA-2048's are 64 digits with 1024-bit exponents (256
-    # nibbles), RSA-3072's 96 digits with 1536-bit ones (384).
-    digits = {"pow": 64, "pow1536": 96}[kind]
+    # nibbles), RSA-3072's 96 digits with 1536-bit ones (384).  A
+    # first-level threshold fragment of a 2,048-bit CA key: a whole
+    # modulus (128 digits) under the longer exponent class (4,160 bits:
+    # 1,040 nibbles).
+    digits = {"pow": 64, "pow1536": 96, "fragment": 128}[kind]
+    windows = (
+        rns.long_exp_bits(16 * digits) // 4 if kind == "fragment"
+        else 4 * digits
+    )
     return (
         place((rows, 2 * digits), jnp.uint8),
-        place_t((4 * digits, rows), jnp.uint8),
+        place_t((windows, rows), jnp.uint8),
         place((rows,), jnp.int32),
         _key_shapes(rns.context(digits, 16 * digits), place_key),
     )
@@ -106,6 +113,8 @@ def _compiled_ok(compiled, *, kernel: bool = False) -> None:
         ("pow", 512),      # 256 share signs = 512 CRT-half rows
         ("pow", 2048),     # four servers' 256-sign batches coalesced
         ("pow1536", 2048),  # the same flush on RSA-3072 identities
+        ("fragment", 64),   # one caller's certificate over ten servers
+        ("fragment", 256),  # sixteen callers': the class's largest bucket
     ],
 )
 def test_xla_chain_compiles_for_one_chip(topo, kind, rows):
@@ -114,6 +123,7 @@ def test_xla_chain_compiles_for_one_chip(topo, kind, rows):
         "verify": rns._jitted_verify_gather,
         "pow": lambda: rns._jitted_pow(64, 1024, True),
         "pow1536": lambda: rns._jitted_pow(96, 1536, True),
+        "fragment": lambda: rns._jitted_pow(128, 2048, True, 4160),
     }[kind]()
     with warnings.catch_warnings():
         # ``_jitted_pow`` donates uint8 operands that no f32 output
@@ -152,6 +162,21 @@ def test_pallas_pow_chain_compiles_at_its_tile(topo):
         row(pc.kpad), row(pc.kpad), row(1),
     ).compile()
     _compiled_ok(compiled, kernel=True)
+
+
+@pytest.mark.parametrize("rows", [64, 128])
+def test_fused_fragment_chain_compiles_at_both_buckets(topo, rows):
+    """What one chip launches for a first-level threshold fragment:
+    the fused chain at 2,048-bit rows and 1,040 windows, one tile a
+    bucket (128 rows is the most the scoped VMEM holds at kpad 256)."""
+    one = _on(SingleDeviceSharding(topo.devices[0]))
+    windows = rns.long_exp_bits(2048) // 4
+    fn = pallas_rns.jitted_pow(
+        128, 2048, windows, rows, rns._pow_name(2048, 4 * windows)
+    )
+    compiled = fn.lower(*_operands("fragment", rows, one, one, one)).compile()
+    _compiled_ok(compiled, kernel=True)
+    assert pallas_rns._pow_tile(256) == 128
 
 
 @pytest.mark.parametrize(

@@ -122,3 +122,46 @@ def test_http_transport_is_really_used(http_cluster):
     """Guard against the fixture silently falling back to loopback."""
     assert isinstance(http_cluster.clients[0].tr, TrHTTP)
     assert http_cluster.universe.servers[0].cert.address.startswith("http://127.0.0.1:")
+
+
+# -- a small answer does not wait for a delayed ACK ---------------------------
+
+
+class _Echo:
+    """A transport server whose answer is ``size`` bytes."""
+
+    def __init__(self, size: int):
+        self.size = size
+
+    def handler(self, cmd, data):
+        return bytes([len(data) % 251]) * self.size
+
+
+@pytest.mark.parametrize("size", [0, 900, 70_000, 300_000])
+def test_an_answer_of_any_size_comes_back_whole_and_at_once(size):
+    """The server's sockets are TCP_NODELAY: a write-write-read
+    exchange under Nagle's algorithm holds the second write of an
+    answer under one segment (64 KB on loopback) until the client's
+    delayed ACK, ~40 ms an RPC — what every post of a small answer
+    took before PR 34.  The median of 21 posts is held to 20 ms,
+    which one stall in a post fails and a busy sandbox does not."""
+    import statistics
+    import time
+
+    from bftkv_tpu import transport as tp
+
+    srv = TrHTTP(None)
+    srv.start(_Echo(size), "127.0.0.1:0")
+    try:
+        port = srv._server.server_address[1]
+        url = f"http://127.0.0.1:{port}{tp.PREFIX}time"
+        cli = TrHTTP(None)
+        took = []
+        for i in range(21):
+            t0 = time.perf_counter()
+            got = cli.post(url, b"q" * (100 + i))
+            took.append(time.perf_counter() - t0)
+            assert got == bytes([(100 + i) % 251]) * size
+        assert statistics.median(took) < 0.020, sorted(took)
+    finally:
+        srv.stop()

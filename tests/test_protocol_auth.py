@@ -11,7 +11,7 @@ from bftkv_tpu.crypto import rsa
 from bftkv_tpu.crypto.threshold import ThresholdAlgo
 from bftkv_tpu.errors import Error
 
-from cluster_utils import start_cluster
+from cluster_utils import modexp_route, start_cluster
 
 BITS = 2048
 
@@ -21,6 +21,20 @@ def cluster():
     c = start_cluster(n_servers=4, n_users=2, n_rw=4, bits=BITS)
     yield c
     c.stop()
+
+
+@pytest.fixture(params=["local", "sidecar"])
+def modexp_domain(request, tmp_path):
+    """The servers' modexps in-process, and with the modexp domain a
+    ``--sidecar`` daemon installs (None / the ``RemoteModexpDomain``)."""
+    with modexp_route(request.param, tmp_path) as domain:
+        yield domain
+
+
+def _remote_items() -> float:
+    from bftkv_tpu.metrics import registry
+
+    return registry.snapshot().get("modexp.remote", 0)
 
 
 def test_tpa_roundtrip(cluster):
@@ -71,15 +85,19 @@ def test_tpa_protected_write_read(cluster):
         assert p.ss is not None and not p.ss.completed
 
 
-def test_threshold_rsa_ca(cluster):
+def test_threshold_rsa_ca(cluster, modexp_domain):
     """Distribute an RSA CA key, threshold-sign, verify against the
     public key (reference: dist_test.go:29-105)."""
     cli = cluster.clients[0]
     key = rsa.generate(2048)
     cli.distribute("ca-rsa", key)
     tbs = b"an X.509 to-be-signed blob"
+    sent = _remote_items()
     sig = cli.dist_sign("ca-rsa", tbs, ThresholdAlgo.RSA, "sha256")
     assert rsa.verify_host(tbs, sig, key.public)
+    assert sig == rsa.sign(tbs, key)  # the undealt key's, byte for byte
+    # a 2,048-bit CA's first-level fragments went the installed way
+    assert (_remote_items() - sent >= 3) is (modexp_domain is not None)
 
 
 def test_threshold_dsa_ca(cluster):
@@ -161,7 +179,7 @@ def test_threshold_repeated_rounds_5_of_9():
         c.stop()
 
 
-def test_threshold_x509_issuance(cluster):
+def test_threshold_x509_issuance(cluster, modexp_domain):
     """The threshold CA issues a real X.509 certificate: template TBS
     threshold-signed, certificate reassembled, verifiable with the
     standard library against the CA public key

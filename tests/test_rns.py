@@ -143,9 +143,9 @@ def test_auto_backend_goes_by_platform_and_device_count(
     seen = []
     real = rns._auto_backend
 
-    def spy(p, n):
+    def spy(p, n, long_exp=False):
         seen.append((p, n))
-        return real(p, n)
+        return real(p, n, long_exp)
 
     monkeypatch.setattr(rns.jax, "default_backend", lambda: platform)
     monkeypatch.setattr(rns.jax, "devices", lambda: ["chip"] * n_devices)
@@ -163,7 +163,7 @@ def test_auto_backend_goes_by_platform_and_device_count(
 def test_forced_backend_never_consults_auto(monkeypatch, mode, want):
     monkeypatch.setattr(
         rns, "_auto_backend",
-        lambda p, n: pytest.fail("auto consulted for a forced backend"),
+        lambda *a: pytest.fail("auto consulted for a forced backend"),
     )
     for env in ("BFTKV_RNS_POW_BACKEND", "BFTKV_RNS_VERIFY_BACKEND"):
         monkeypatch.setenv(env, mode)

@@ -56,7 +56,8 @@ def _service(*, max_batch=4096, verify_crossover=16, sign_threshold=16,
         signer=types.SimpleNamespace(host_threshold=sign_threshold),
     )
     svc.modexp = _HostDispatcher(
-        pow, max_batch, device_threshold=max(16, verify_crossover)
+        pow, max_batch, device_threshold=max(16, verify_crossover),
+        LONG_EXP_MAX_ROWS=dispatch.ModexpDispatcher.LONG_EXP_MAX_ROWS,
     )
     return svc
 
@@ -133,6 +134,53 @@ def test_warmup_splits_each_shape_by_what_jax_reported(monkeypatch):
     assert by_role["verify"] == [0.25, 0.5, 1.5, 0.5, 0.125]
     assert by_role["sign"] == by_role["modexp"] == [0.0] * 5
     assert all(s["seconds"] >= 0 for s in shapes)
+
+
+def _counting_device_rows(svc):
+    """The stand-in's launches count as a dispatcher's would."""
+    inner = svc.modexp.submit
+
+    def submit(items):
+        metrics.incr("modexp.device", len(items))
+        return inner(items)
+
+    svc.modexp.submit = submit
+
+
+def test_a_declared_ca_builds_its_fragment_class(monkeypatch):
+    """``BFTKV_CA_BITS=2048``: the two buckets of 2,048-bit rows under
+    the longer exponent class, after everything the identities need;
+    undeclared, no such program and no such class in ``warm_rows``."""
+    svc = _service(max_batch=64)
+    svc.warmup = svc._warm()
+    assert _warmed(svc, "fragment") == []
+    assert svc.modexp.warm_rows == frozenset({1024})
+    assert svc.warmup["ca_bits"] == svc.warmup["fragment_rows"] == []
+
+    monkeypatch.setenv("BFTKV_CA_BITS", "2048")
+    svc = _service(max_batch=64)
+    _counting_device_rows(svc)
+    svc.warmup = svc._warm()
+    assert _warmed(svc, "fragment") == [64, 128]
+    assert svc.modexp.batches[-2:] == [64, 128]
+    assert {s["bits"] for s in svc.warmup["shapes"]
+            if s["role"] == "fragment"} == {2048}
+    assert svc.modexp.warm_rows == frozenset({1024, (2048, 4160)})
+    assert svc.sign.signer.warm_rows == frozenset({1024})
+    assert svc.warmup["ca_bits"] == [2048]
+    assert svc.warmup["fragment_rows"] == [(2048, 4160)]
+
+
+def test_a_fragment_launch_that_misses_the_device_refuses_to_start(
+    monkeypatch,
+):
+    monkeypatch.setenv("BFTKV_CA_BITS", "2048")
+    svc = _service(max_batch=64)  # answers right, counts no device row
+    with pytest.raises(RuntimeError, match="did not ride the device"):
+        svc._warm()
+    monkeypatch.setenv("BFTKV_CA_BITS", "a-width")
+    with pytest.raises(ValueError, match="BFTKV_CA_BITS"):
+        _service(max_batch=64)._warm()
 
 
 def test_wrong_result_in_warmup_refuses_to_start():
