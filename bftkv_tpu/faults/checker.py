@@ -198,7 +198,18 @@ class RecordingStorage:
         # Optional-seam passthrough (sorted_keys / snapshot_records /
         # reopen / close / ...): capability detection on the wrapper
         # must reflect the inner engine's true surface.
-        return getattr(self.inner, name)
+        attr = getattr(self.inner, name)
+        if name != "append":
+            return attr
+
+        def append(variable: bytes, t: int, value: bytes):
+            # The split write (append now, barrier later): an appended
+            # record is a persist, recorded like write's.
+            pos = attr(variable, t, value)
+            self._record_persist(variable, t, value)
+            return pos
+
+        return append
 
     # MalStorage pass-through so byzantine programs keep their side area.
     def mal_write(self, variable: bytes, t: int, value: bytes) -> None:

@@ -93,6 +93,10 @@ class Server(Protocol):
         # digests stay incremental.
         self._sync = None
         self._sync_lock = named_lock("server.sync")
+        # A ratio has its denominator: how batch_sign's records became
+        # durable, on the frame's one barrier or each on its own.
+        metrics.incr("server.batch_sign.deferred", 0)
+        metrics.incr("server.batch_sign.direct", 0)
 
     # -- anti-entropy plumbing (bftkv_tpu/sync) ---------------------------
 
@@ -1435,7 +1439,9 @@ class Server(Protocol):
         verification and share issuance each run as ONE device batch;
         the per-variable checks run sequentially in item order with
         persist-as-you-go, so intra-batch conflicts hit exactly the
-        single-``sign`` equivocation path."""
+        single-``sign`` equivocation path; on a backend with
+        ``append`` / ``barrier`` the frame's records share one
+        durability barrier, taken before any share is issued."""
         with metrics.timer("server.batch_sign.handler"):
             return self._batch_sign_inner(req, peer, sender)
 
@@ -1564,7 +1570,13 @@ class Server(Protocol):
                 parsed[i] = None
 
         # Per-variable checks + persist-without-ss, sequentially: each
-        # item's check sees the previous item's persisted record.
+        # item's check sees the previous item's persisted record.  A
+        # backend that splits its write (the §19 log engine) appends
+        # each record here — readable at once, by this loop and by
+        # every concurrent handler — and makes the frame durable with
+        # ONE barrier below; any other backend persists item by item.
+        append = getattr(self.storage, "append", None)
+        last_pos = None
         tbss_list: list[bytes] = []
         tbss_idx: list[int] = []
         for i in range(n):
@@ -1591,9 +1603,27 @@ class Server(Protocol):
             if not sig.cert and self.crypt.keyring.get(issuer.id) is None:
                 sig.cert = issuer.serialize()
             stored = pkt.serialize(variable, val, t, sig, None, proof)
-            self._persist(variable, t, stored)
+            if append is None:
+                self._persist(variable, t, stored)
+            else:
+                last_pos = append(variable, t, stored)
+                tree = self._sync
+                if tree is not None:
+                    tree.mark(variable)
             tbss_list.append(pkt.tbss(r))
             tbss_idx.append(i)
+
+        # No share leaves before every record of the frame is durable
+        # (a failed barrier raises, as a failed persist does).
+        if last_pos is not None:
+            with metrics.timer("server.batch_sign.barrier"), trace.span(
+                "server.batch_sign.barrier",
+                attrs={"items": len(tbss_idx)},
+            ):
+                self.storage.barrier(last_pos)
+            metrics.incr("server.batch_sign.deferred", len(tbss_idx))
+        elif tbss_idx:
+            metrics.incr("server.batch_sign.direct", len(tbss_idx))
 
         # One device batch for every collective-signature share.  The
         # certificate is embedded ONCE (first share of the frame), not

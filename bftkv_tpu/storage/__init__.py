@@ -26,6 +26,10 @@ the Protocol below stays the contract every backend must meet):
   durability barrier (group commit).  The server's persist-many path
   and ``admit_records`` use it when present and fall back to per-item
   ``write`` when not;
+- ``append(variable, t, value) -> pos`` / ``barrier(pos)`` — ``write``
+  split in two: the record is readable once appended and durable once
+  a barrier reaches its position.  ``BATCH_SIGN`` appends each record
+  as it admits it and takes one barrier a frame, before any share;
 - ``sorted_keys(after=None, limit=None)`` — a cheap sorted-keyspace
   cursor for the windowed ``pending_variables`` repair scan, replacing
   a full ``sorted(keys())`` per round;

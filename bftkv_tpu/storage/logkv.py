@@ -283,10 +283,14 @@ class LogStorage:
                     # ``storage.fsync`` failpoint: a stalled durability
                     # barrier — every writer joined on this group
                     # commit waits it out (slow-disk model; the
-                    # capacity plane must name log_commit for it).
+                    # capacity plane must name log_commit for it) — or
+                    # a failed one (the disk answers EIO): the leader
+                    # raises, the mark stays, the waiters re-elect.
                     act = fp.fire("storage.fsync", backend="log")
                     if act is not None and act.kind == "stall":
                         time.sleep(fp.delay_seconds(act))
+                    if act is not None and act.kind == "io_error":
+                        raise OSError("injected fsync I/O error")
                 try:
                     os.fsync(f.fileno())
                 except ValueError:
@@ -304,12 +308,23 @@ class LogStorage:
 
     # -- storage contract ---------------------------------------------------
 
-    def write(self, variable: bytes, t: int, value: bytes) -> None:
+    def append(self, variable: bytes, t: int, value: bytes) -> tuple[int, int]:
+        """The first half of :meth:`write`: append and index one record
+        — readable at once — and return the log position a
+        :meth:`barrier` has to reach before the record is durable."""
         with self._lock:
             self._append_locked(variable, t, value)
-            pos = (self._seq, self._size)
+            return (self._seq, self._size)
+
+    def barrier(self, pos: tuple[int, int]) -> None:
+        """The second half of :meth:`write`: return once every record
+        appended up to ``pos`` — by any thread — is fsynced.  One
+        barrier at the last position of a frame covers the frame."""
         if self.fsync:
             self._commit(pos)
+
+    def write(self, variable: bytes, t: int, value: bytes) -> None:
+        self.barrier(self.append(variable, t, value))
 
     def write_batch(self, items) -> None:
         """The group-commit seam: append every ``(variable, t, value)``
@@ -332,8 +347,7 @@ class LogStorage:
                 self._append_locked(variable, t, value)
             pos = (self._seq, self._size)
         metrics.observe("storage.log.batch", len(items))
-        if self.fsync:
-            self._commit(pos)
+        self.barrier(pos)
 
     def read(self, variable: bytes, t: int = 0) -> bytes:
         with self._lock:

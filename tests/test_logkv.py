@@ -9,6 +9,7 @@ import time
 import pytest
 
 from bftkv_tpu import packet as pkt
+from bftkv_tpu.storage import segment as seg
 from bftkv_tpu.storage.logkv import LogStorage
 
 
@@ -83,6 +84,74 @@ def test_single_writes_durable_and_concurrent(tmp_path, monkeypatch):
         for i in range(10):
             assert s.read(b"w%d-%d" % (w, i)) == b"x"
     s.close()
+
+
+def _counting_fsync(monkeypatch) -> list:
+    import os as os_mod
+
+    calls = []
+    real = os_mod.fsync
+    monkeypatch.setattr(
+        os_mod, "fsync", lambda fd: (calls.append(fd), real(fd))[1]
+    )
+    return calls
+
+
+def test_append_is_readable_before_its_barrier(tmp_path, monkeypatch):
+    calls = _counting_fsync(monkeypatch)
+    s = LogStorage(str(tmp_path / "db"), group_commit_s=0)
+    calls.clear()
+    pos = s.append(b"k", 1, b"v")
+    assert s.read(b"k") == b"v" and s.versions(b"k") == [1]
+    assert calls == []  # nothing durable yet
+    s.barrier(pos)
+    assert len(calls) == 1
+    s.close()
+
+
+def test_one_barrier_covers_every_earlier_append(tmp_path, monkeypatch):
+    """Barriers are positions in one file: a barrier at the last
+    position covers what other threads appended before it, and a later
+    barrier at one of their positions costs no fsync."""
+    calls = _counting_fsync(monkeypatch)
+    s = LogStorage(str(tmp_path / "db"), group_commit_s=0)
+    theirs = []
+    threads = [
+        threading.Thread(
+            target=lambda w=w: theirs.extend(
+                s.append(b"t%d-%d" % (w, i), 1, b"x") for i in range(8)))
+        for w in range(4)
+    ]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    calls.clear()
+    s.barrier(s.append(b"mine", 1, b"y"))
+    assert len(calls) == 1
+    for pos in theirs:
+        s.barrier(pos)
+    assert len(calls) == 1
+    s.close()
+
+
+@pytest.mark.parametrize("form", ["write", "append+barrier"])
+def test_write_is_append_then_barrier(tmp_path, monkeypatch, form):
+    """Both forms leave the same bytes on disk, one fsync a record."""
+    calls = _counting_fsync(monkeypatch)
+    s = LogStorage(str(tmp_path / "db"), group_commit_s=0)
+    calls.clear()
+    for i in range(3):
+        rec = (b"k%d" % i, i + 1, b"v%d" % i)
+        if form == "write":
+            s.write(*rec)
+        else:
+            s.barrier(s.append(*rec))
+    assert len(calls) == 3
+    s.close()
+    (seg_file,) = (tmp_path / "db").iterdir()
+    assert seg_file.read_bytes() == b"".join(
+        seg.encode_record(b"k%d" % i, i + 1, b"v%d" % i) for i in range(3))
 
 
 # -- compaction --------------------------------------------------------------
