@@ -512,8 +512,9 @@ class SidecarService:
         widths (``BFTKV_CA_BITS``, default none): per width the
         programs a first-level fragment rides — a whole modulus of that
         width under the longer exponent class (``rns.long_exp_bits``),
-        buckets 64 and 128 (``ModexpDispatcher.
-        LONG_EXP_MAX_ROWS``), each launch checked against the host.  A
+        buckets 64 and 128 (64 alone at the wide chain's widths: a
+        launch holds one fused-chain tile, ``rns.long_exp_rows``), each
+        launch checked against the host.  A
         wrong result or a device error raises: a sidecar that cannot
         launch does not start.
 
@@ -671,7 +672,8 @@ class SidecarService:
             # eight distinct rows and their residues: a first-level
             # fragment is d minus nine random values of 2 x bits - 1 bits
             mod = (1 << (bits - 1)) + 973
-            while any(mod % p == 0 for p in rns._gen_primes(1 << 10, rns.PR)):
+            ctx = rns.pow_context(ca_rows[bits][0])
+            while ctx.key_rows(mod) is None:
                 mod += 2  # the chain has rows for it
             few = [
                 (i + 2, (1 << (2 * bits)) + (i + 1) * 0x9E3779B97F4A7C15, mod)
@@ -695,7 +697,7 @@ class SidecarService:
 
         for bits in ca_rows:
             few, want = fragment_rows()
-            top = self.modexp.LONG_EXP_MAX_ROWS
+            top = rns.long_exp_rows(ca_rows[bits][0])
             for n in buckets(min(64, top), top):
                 timed("fragment", n, warm_fragments)
         # Warm-up is not traffic.  Its round trips included compilation

@@ -243,7 +243,8 @@ _flag("BFTKV_CA_BITS", None, "str",
       "quorum, comma-separated (`2048`): the sidecar then also builds the "
       "pow programs a first-level threshold fragment rides (a whole "
       "modulus of that width under an exponent of twice that width + 64 "
-      "bits, buckets 64 and 128 rows) before it listens. Unset: no such "
+      "bits, buckets 64 and 128 rows; 64 alone on the wide chain, widths "
+      "past 2,130 bits up to 4096) before it listens. Unset: no such "
       "program is built and a fragment row is served from the host tier, "
       "counted (`sidecar.unwarmed_width`).")
 

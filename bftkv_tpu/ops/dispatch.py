@@ -890,14 +890,6 @@ class ModexpDispatcher(_BatchDispatcher):
     name = "modexpdispatch"
     op = "modexp"
 
-    #: Rows one launch of a longer-exponent class holds at most: one
-    #: tile of the fused chain at 2,048-bit rows (``ops.pallas_rns``;
-    #: more rows are more tiles in a row, the device time of as many
-    #: launches).  The sidecar builds that class's buckets 64 and 128
-    #: and no more, so a larger group is several launches and never a
-    #: fresh program.
-    LONG_EXP_MAX_ROWS = 128
-
     def __init__(
         self,
         *,
@@ -964,8 +956,12 @@ class ModexpDispatcher(_BatchDispatcher):
                 or not rns_ops.chains(n_bits, exp_bits).pow
             ):
                 continue
+            # a longer class: launches of one fused-chain tile at most
+            # (the sidecar builds those buckets and no more, so a larger
+            # group is several launches, never a fresh program)
             step = (
-                len(idxs) if exp_bits == n_bits else self.LONG_EXP_MAX_ROWS
+                len(idxs) if exp_bits == n_bits
+                else rns_ops.long_exp_rows(n_bits)
             )
             groups += [
                 (n_bits, exp_bits, idxs[o : o + step])
@@ -975,6 +971,10 @@ class ModexpDispatcher(_BatchDispatcher):
 
     def _note_device_group(self, n_bits: int, idxs: list[int]) -> None:
         metrics.incr("modexp.device", len(idxs))
+        # beside ``modexp.host.class``: which row width the device took
+        metrics.incr(
+            "modexp.device.class", len(idxs), labels={"bits": str(n_bits)}
+        )
         metrics.observe("modexp.device_batch", len(idxs))
         # Per-limb-width device occupancy: widths are the handful of
         # deployed modulus sizes, so the label stays bounded (capacity

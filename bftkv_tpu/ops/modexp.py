@@ -16,8 +16,10 @@ Otherwise the launch is this process's own: the RNS pow chain
 (``ops.rns.power_mod_rns``) for moduli up to 2,048 bits under
 exponents up to twice the modulus class + 64 bits (``ops.rns.chains``:
 a first-level threshold fragment of a 2,048-bit key, ~4,100 bits),
-:func:`power_batch` (the limb Montgomery engine) for wider operands
-that no RNS class is built for, up to ``MAX_EXP_LIMBS``.
+:func:`power_batch` (the limb Montgomery engine) for wider operands,
+up to ``MAX_EXP_LIMBS``.  The wide chain's classes (moduli to 4,096
+bits, ``ops.rns.WIDE_MAX_BITS``) are built in the sidecar that a
+deployment declares them to (``BFTKV_CA_BITS``), not here.
 
 Policy of the local path: batches below ``min_batch`` (default 4,
 override with ``BFTKV_TPU_MIN_MODEXP_BATCH``) run as host ``pow`` — a
@@ -137,8 +139,8 @@ class BatchModExp:
         # Prefer the RNS windowed-modexp kernel: it covers moduli up
         # to 2,048 bits under either exponent class of their row width
         # (``rns.exp_class``: up to the width, or up to twice it + 64).
-        # Sub-2^12 primes cannot fund a 4096-bit base pair, so wider
-        # moduli, and the exponents of the second tree level and below
+        # Wider moduli (the wide chain's classes are the sidecar's),
+        # and the exponents of the second tree level and below
         # (rsa.go:97-117), stay on the limb path.
         # power_mod_rns stages operands through the persistent devbuf
         # ring for its class, so per-call marshalling here is just the

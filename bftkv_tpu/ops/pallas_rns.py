@@ -72,15 +72,16 @@ TILE_POW = _tile_env("BFTKV_PALLAS_TILE_POW", "256")
 def _pow_tile(kpad: int) -> int:
     """``TILE_POW`` is budgeted at kpad = 128 (rows to 1,024 bits);
     wider rows take as many fewer a tile (2,048-bit rows: kpad 256,
-    tile 128 — 256 passes the scoped 16 MB by 0.5 MB on a v5e)."""
-    return max(8, TILE_POW * 128 // kpad)
+    tile 128 — 256 passes the scoped 16 MB by 0.5 MB on a v5e), down
+    to a power of two (4,096-bit rows: kpad 384, tile 64), so that a
+    padded batch is whole tiles."""
+    return max(8, 1 << (TILE_POW * 128 // kpad).bit_length() - 1)
 
 
 TILE_VERIFY = _tile_env("BFTKV_PALLAS_TILE_VERIFY", "128")
 PR = rns.PR
 _PRF = np.float32(PR)
 _INV_PRF = np.float32(1.0 / PR)
-_I64 = np.float32(1.0 / 64.0)
 
 
 # ---------------------------------------------------------------------------
@@ -98,6 +99,7 @@ class _PadConsts:
         k, digits = ctx.k, ctx.digits
         kpad = -(-k // 128) * 128
         self.k, self.kpad, self.digits = k, kpad, digits
+        self.wide, sp = ctx.wide, ctx.split
 
         def padv(v, fill=0.0):
             out = np.full((1, kpad), fill, dtype=np.float32)
@@ -115,10 +117,11 @@ class _PadConsts:
         self.invMq_pr = float(ctx.invMq_pr)
         self.invM_pr = float(ctx.invM_pr)
 
-        # Rebuild integer matrices from the stored exact 6-bit planes.
-        E1 = (ctx._E1[0] + 64.0 * ctx._E1[1]).astype(np.int64)  # (k, k+1)
-        E2 = (ctx._E2[0] + 64.0 * ctx._E2[1]).astype(np.int64)
-        D = (ctx._D[0] + 64.0 * ctx._D[1]).astype(np.int64)  # (2d, 2k+1)
+        # Rebuild integer matrices from the stored exact planes.
+        sh = float(1 << sp)
+        E1 = (ctx._E1[0] + sh * ctx._E1[1]).astype(np.int64)  # (k, k+1)
+        E2 = (ctx._E2[0] + sh * ctx._E2[1]).astype(np.int64)
+        D = (ctx._D[0] + sh * ctx._D[1]).astype(np.int64)  # (2d, 2k+1)
 
         def padm(m, rows, cols):
             out = np.zeros((rows, cols), dtype=np.int64)
@@ -126,8 +129,8 @@ class _PadConsts:
             return out
 
         split = lambda m: (
-            (m & 63).astype(np.float32),
-            (m >> 6).astype(np.float32),
+            (m & ((1 << sp) - 1)).astype(np.float32),
+            (m >> sp).astype(np.float32),
         )
         self.E1q = split(padm(E1[:, :k], kpad, kpad))
         self.E1r = split(padm(E1[:, k:].T, 1, kpad))  # (1, kpad)
@@ -147,7 +150,7 @@ class _PadConsts:
         )
 
 
-@functools.lru_cache(maxsize=4)
+@functools.lru_cache(maxsize=8)
 def _pad_consts(digits: int, n_bits: int) -> _PadConsts:
     return _PadConsts(rns.context(digits, n_bits))
 
@@ -171,6 +174,13 @@ def _mulmod(a, b, inv_p, p):
     return _barrett(a * b, inv_p, p)
 
 
+def _mulmod_wide(a, b, inv_p, p):
+    """``rns._mulmod_wide``: 13-bit channels, ``b`` in 7-bit halves."""
+    bh = jnp.floor(b * np.float32(1 / 128))
+    bl = b - bh * 128.0
+    return _barrett(a * bl + _barrett(a * bh, inv_p, p) * 128.0, inv_p, p)
+
+
 def _addmod(a, b, p):
     s = a + b
     return jnp.where(s >= p, s - p, s)
@@ -185,9 +195,9 @@ def _mod_r(x):
     return x - jnp.floor(x * _INV_PRF) * _PRF
 
 
-def _split6(x):
-    hi = jnp.floor(x * _I64)
-    return x - hi * 64.0, hi
+def _split(x, s: int = 6):
+    hi = jnp.floor(x * np.float32(1.0 / (1 << s)))
+    return x - hi * float(1 << s), hi
 
 
 def _dot(a, b):
@@ -199,44 +209,51 @@ def _dot(a, b):
     )
 
 
-def _dot6(x, mlo, mhi):
+def _dot6(x, mlo, mhi, s: int = 6):
     """Exact x @ M for 12-bit integral operands via 6-bit bf16 planes.
-    Returns the (ll, mid, hh) partial planes (each < 2^22)."""
-    xlo, xhi = _split6(x)
+    Returns the (ll, mid, hh) partial planes (each < 2^22).  ``s`` 7:
+    13-bit operands in 7 + 6-bit planes (the wide chain; < 2^23)."""
+    xlo, xhi = _split(x, s)
     return _dot(xlo, mlo), _dot(xlo, mhi) + _dot(xhi, mlo), _dot(xhi, mhi)
 
 
-def _red6(x, rlo, rhi):
+def _red6(x, rlo, rhi, s: int = 6):
     """Row-reduce variant for the redundant channel: Σ_i x[:,i]·r[i]
     as exact partial planes, (T, 1) each."""
-    xlo, xhi = _split6(x)
-    s = lambda v: jnp.sum(v, axis=1, keepdims=True)
+    xlo, xhi = _split(x, s)
+    rsum = lambda v: jnp.sum(v, axis=1, keepdims=True)
     return (
-        s(xlo * rlo),
-        s(xlo * rhi) + s(xhi * rlo),
-        s(xhi * rhi),
+        rsum(xlo * rlo),
+        rsum(xlo * rhi) + rsum(xhi * rlo),
+        rsum(xhi * rhi),
     )
 
 
-def _combine(sll, smid, shh, inv_p, p):
+def _combine(sll, smid, shh, inv_p, p, s: int = 6):
+    sh = float(1 << s)
     a = _barrett(sll, inv_p, p)
     b = _barrett(smid, inv_p, p)
     d = _barrett(shh, inv_p, p)
-    b6 = _barrett(b * 64.0, inv_p, p)
-    d12 = _barrett(_barrett(d * 64.0, inv_p, p) * 64.0, inv_p, p)
+    b6 = _barrett(b * sh, inv_p, p)
+    d12 = _barrett(_barrett(d * sh, inv_p, p) * sh, inv_p, p)
     return _addmod(_addmod(a, b6, p), d12, p)
 
 
-def _combine_r(sll, smid, shh):
+def _combine_r(sll, smid, shh, s: int = 6):
+    sh = float(1 << s)
     return _mod_r(
-        _mod_r(sll) + _mod_r(smid * 64.0) + _mod_r(_mod_r(shh * 64.0) * 64.0)
+        _mod_r(sll) + _mod_r(smid * sh) + _mod_r(_mod_r(shh * sh) * sh)
     )
 
 
 class _Ctx:
     """Constants loaded from refs once per kernel invocation."""
 
-    def __init__(self, refs, invMq_pr, invM_pr):
+    def __init__(self, refs, invMq_pr, invM_pr, wide: bool = False):
+        # the wide chain's 13-bit channels: 7 + 6-bit planes and split
+        # channel products (``rns.WIDE_BITS``)
+        self.s = rns.WIDE_SPLIT if wide else rns.SPLIT
+        self.mul = _mulmod_wide if wide else _mulmod
         (
             self.pb, self.ib, self.pq, self.iq,
             self.invMi_b, self.invMi_q, self.Mq_mod_b, self.invM_q,
@@ -259,39 +276,41 @@ class _Ctx:
         ab, aq, ar = a
         bb, bq, br = b
         nb, nq, nr, ninvb = key[:4]
-        db = _mulmod(ab, bb, self.ib, self.pb)
-        dq = _mulmod(aq, bq, self.iq, self.pq)
+        s, mul = self.s, self.mul
+        db = mul(ab, bb, self.ib, self.pb)
+        dq = mul(aq, bq, self.iq, self.pq)
         dr = _mod_r(ar * br)
 
-        qb = _mulmod(db, ninvb, self.ib, self.pb)
-        sigma = _mulmod(qb, self.invMi_b, self.ib, self.pb)
-        sll, smid, shh = _dot6(sigma, *self.E1q)
-        qhat_q = _combine(sll, smid, shh, self.iq, self.pq)
-        rll, rmid, rhh = _red6(sigma, *self.E1r)
-        qhat_r = _combine_r(rll, rmid, rhh)
+        qb = mul(db, ninvb, self.ib, self.pb)
+        sigma = mul(qb, self.invMi_b, self.ib, self.pb)
+        sll, smid, shh = _dot6(sigma, *self.E1q, s)
+        qhat_q = _combine(sll, smid, shh, self.iq, self.pq, s)
+        rll, rmid, rhh = _red6(sigma, *self.E1r, s)
+        qhat_r = _combine_r(rll, rmid, rhh, s)
 
-        t = _mulmod(qhat_q, nq, self.iq, self.pq)
-        rq = _mulmod(_addmod(dq, t, self.pq), self.invM_q, self.iq, self.pq)
+        t = mul(qhat_q, nq, self.iq, self.pq)
+        rq = mul(_addmod(dq, t, self.pq), self.invM_q, self.iq, self.pq)
         rr = _mod_r(_mod_r(dr + _mod_r(qhat_r * nr)) * self.invM_pr)
 
-        sigma2 = _mulmod(rq, self.invMi_q, self.iq, self.pq)
-        zll, zmid, zhh = _dot6(sigma2, *self.E2b)
-        ext_b = _combine(zll, zmid, zhh, self.ib, self.pb)
-        wll, wmid, whh = _red6(sigma2, *self.E2r)
-        ext_r = _combine_r(wll, wmid, whh)
+        sigma2 = mul(rq, self.invMi_q, self.iq, self.pq)
+        zll, zmid, zhh = _dot6(sigma2, *self.E2b, s)
+        ext_b = _combine(zll, zmid, zhh, self.ib, self.pb, s)
+        wll, wmid, whh = _red6(sigma2, *self.E2r, s)
+        ext_r = _combine_r(wll, wmid, whh, s)
         alpha = _mod_r(_mod_r(ext_r - rr + _PRF) * self.invMq_pr)
-        corr = _barrett(alpha * self.Mq_mod_b, self.ib, self.pb)
+        corr = mul(alpha, self.Mq_mod_b, self.ib, self.pb)
         rb = _submod(ext_b, corr, self.pb)
         return rb, rq, rr
 
     def to_residues(self, halves):
         """(T, 2·digits) 8-bit halves → residue triplet."""
-        sll, smid, shh = _dot6(halves, *self.Db)
-        xb = _combine(sll, smid, shh, self.ib, self.pb)
-        tll, tmid, thh = _dot6(halves, *self.Dq)
-        xq = _combine(tll, tmid, thh, self.iq, self.pq)
-        rll, rmid, rhh = _red6(halves, *self.Dr)
-        xr = _combine_r(rll, rmid, rhh)
+        s = self.s
+        sll, smid, shh = _dot6(halves, *self.Db, s)
+        xb = _combine(sll, smid, shh, self.ib, self.pb, s)
+        tll, tmid, thh = _dot6(halves, *self.Dq, s)
+        xq = _combine(tll, tmid, thh, self.iq, self.pq, s)
+        rll, rmid, rhh = _red6(halves, *self.Dr, s)
+        xr = _combine_r(rll, rmid, rhh, s)
         return xb, xq, xr
 
     def ones_like(self, x):
@@ -307,11 +326,11 @@ class _Ctx:
 # ---------------------------------------------------------------------------
 
 
-def _pow_body(invMq_pr, invM_pr, w_steps, *refs):
+def _pow_body(invMq_pr, invM_pr, w_steps, wide, *refs):
     (base_ref, nib_ref, nb_ref, nq_ref, nr_ref, ninvb_ref,
      m2b_ref, m2q_ref, m2r_ref, *const_refs) = refs[:-1]
     out_ref = refs[-1]
-    cx = _Ctx(const_refs, invMq_pr, invM_pr)
+    cx = _Ctx(const_refs, invMq_pr, invM_pr, wide)
 
     key = (nb_ref[:], nq_ref[:], nr_ref[:], ninvb_ref[:])
     m2 = (m2b_ref[:], m2q_ref[:], m2r_ref[:])
@@ -345,7 +364,7 @@ def _pow_body(invMq_pr, invM_pr, w_steps, *refs):
 
     acc = lax.fori_loop(0, w_steps, step, one_m)
     vb, _vq, _vr = cx.mont_mul(acc, ones, key)  # out of Montgomery form
-    out_ref[:] = _mulmod(vb, cx.invMi_b, cx.ib, cx.pb)  # CRT σ over B
+    out_ref[:] = cx.mul(vb, cx.invMi_b, cx.ib, cx.pb)  # CRT σ over B
 
 
 @functools.lru_cache(maxsize=8)
@@ -401,7 +420,7 @@ def _pow_call(
     kpad, w_steps = pc.kpad, windows or digits * 4
     consts = tuple(jnp.asarray(a) for a in pc.arrays())
     kernel = functools.partial(
-        _pow_body, pc.invMq_pr, pc.invM_pr, w_steps
+        _pow_body, pc.invMq_pr, pc.invM_pr, w_steps, pc.wide
     )
 
     @jax.jit

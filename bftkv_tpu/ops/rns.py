@@ -63,6 +63,15 @@ PR_BITS = 12
 PR = 1 << PR_BITS  # redundant modulus (power of two)
 DIGITS = 128  # 16-bit digits per 2048-bit number
 SPLIT = 6  # matmul operand split (values < 64: f32 partials stay exact)
+#: The wide pow chain, for rows the 12-bit supply cannot hold: channels
+#: are the primes of [2^10, 2^13), channel products are taken in 7-bit
+#: halves of one operand and matmul operands split 7 + 6 bits, so that
+#: every f32 intermediate stays an exact integer below 2^24
+#: (docs/DESIGN.md, "The wide pow chain").  Its bound is checked for
+#: rows up to ``WIDE_MAX_BITS``.
+WIDE_BITS = 13
+WIDE_SPLIT = 7
+WIDE_MAX_BITS = 4096
 
 
 def _gen_primes(lo: int, hi: int) -> list[int]:
@@ -73,19 +82,22 @@ def _gen_primes(lo: int, hi: int) -> list[int]:
     return [int(lo + i) for i in np.nonzero(sieve)[0]]
 
 
-def _deal_bases(n_bits: int) -> tuple[list[int], list[int]]:
+def _deal_bases(
+    n_bits: int, top_bits: int = PR_BITS
+) -> tuple[list[int], list[int]]:
     """The two bases for numbers of ``n_bits`` bits: all primes of
-    [2^10, 2^12), largest first, dealt alternately so both get ~equal
-    bit mass, until each clears ``n_bits`` by a healthy margin (the
-    AMM slack analysis needs M > (k+2)^2 N), then cut to equal channel
-    counts.  The supply is finite — 392 primes, 4,391 bits — so this
-    is where a width the chains cannot hold is found out: ValueError.
+    [2^10, 2^top_bits), largest first, dealt alternately so both get
+    ~equal bit mass, until each clears ``n_bits`` by a healthy margin
+    (the AMM slack analysis needs M > (k+2)^2 N), then cut to equal
+    channel counts.  The supply is finite — below 2^12, 392 primes,
+    4,391 bits; below 2^13, 856 primes, 10,214 bits — so this is where
+    a width the chains cannot hold is found out: ValueError.
     """
     need = n_bits + 64
     pb: list[int] = []
     pq: list[int] = []
     bits_b = bits_q = 0.0
-    for p in _gen_primes(1 << 10, 1 << PR_BITS)[::-1]:
+    for p in _gen_primes(1 << 10, 1 << top_bits)[::-1]:
         if bits_b <= bits_q:
             pb.append(p)
             bits_b += np.log2(p)
@@ -105,12 +117,24 @@ def _deal_bases(n_bits: int) -> tuple[list[int], list[int]]:
 
 
 @functools.lru_cache(maxsize=256)
-def _bases_hold(n_bits: int) -> bool:
+def _bases_hold(n_bits: int, top_bits: int = PR_BITS) -> bool:
     try:
-        _deal_bases(n_bits)
+        _deal_bases(n_bits, top_bits)
     except ValueError:
         return False
     return True
+
+
+def _channel_bits(n_bits: int) -> int | None:
+    """The channel width of the pow chain at ``n_bits``-bit rows: 12
+    where the sub-2^12 primes hold two bases (every class that rode
+    before the wide chain keeps them), 13 for wider rows up to
+    ``WIDE_MAX_BITS``, None past that."""
+    if _bases_hold(n_bits):
+        return PR_BITS
+    if n_bits <= WIDE_MAX_BITS and _bases_hold(n_bits, WIDE_BITS):
+        return WIDE_BITS
+    return None
 
 
 class Chains(NamedTuple):
@@ -129,6 +153,16 @@ def long_exp_bits(n_bits: int) -> int:
     n the tree can be dealt for, rounded up to whole bytes of 4-bit
     windows."""
     return 2 * n_bits + 64
+
+
+def long_exp_rows(n_bits: int) -> int:
+    """Rows one launch of the longer exponent class at ``n_bits``-bit
+    rows holds at most: one tile of the fused chain — 128 on 12-bit
+    channels (2,048-bit rows: kpad 256), 64 on the wide chain's (4,096
+    bits: kpad 384; a 128-row launch of 64-row tiles would block its
+    window array 64 lanes wide, which Mosaic refuses).  More rows are
+    more launches, the device time of as many tiles."""
+    return 64 if _channel_bits(n_bits) == WIDE_BITS else 128
 
 
 def exp_class(n_bits: int, exp_bits: int) -> int | None:
@@ -150,27 +184,29 @@ def chains(bits: int, exp_bits: int | None = None) -> Chains:
     None: no wider than the modulus).
 
     The pow chain is compiled per row width (``context(digits,
-    n_bits)``), so it takes any modulus the prime supply can build two
-    bases for — about 2,130 bits: the CRT halves of RSA-2048, -3072
-    and -4096, whole moduli up to 2048 bits.  Its window count is a
-    shape of its own: at each row width two exponent classes have
-    programs, exponents up to the row width (``rns_pow_<bits>``) and
-    exponents up to ``long_exp_bits(bits)`` = 2 x bits + 64
-    (``rns_pow_<bits>_e<exp bits>``: 4,160 bits at 2,048-bit rows, what
-    a first-level threshold-RSA fragment of a 2,048-bit key needs);
-    a longer exponent rides no chain.  The verify chain works
-    on whole moduli in the one context ``context()`` and takes what
-    fits its digits.  A wider modulus is not hostile, it is beyond the
-    f32-exact design (channel products < 2^24), and belongs to the
-    native host tier (``crypto/rsa.py:verify_host_many``).  Everyone
-    who routes by width — verifier, fault check, signer, modexp
+    n_bits)``), so it takes any modulus two bases can be built for:
+    from the primes below 2^12 up to about 2,130 bits (the CRT halves
+    of RSA-2048, -3072 and -4096, whole moduli up to 2048 bits), and
+    past that, on the wide chain's 13-bit channels, up to 4,096 bits
+    (``WIDE_MAX_BITS``: whole RSA-3072 and RSA-4096 moduli).  Its
+    window count is a shape of its own: at each row width two exponent
+    classes have programs, exponents up to the row width
+    (``rns_pow_<bits>``) and exponents up to ``long_exp_bits(bits)`` =
+    2 x bits + 64 (``rns_pow_<bits>_e<exp bits>``: 4,160 bits at
+    2,048-bit rows, 8,256 at 4,096, what a first-level threshold-RSA
+    fragment of a key of that width needs); a longer exponent rides no
+    chain.  The verify chain works on whole moduli in the one context
+    ``context()`` and takes what fits its digits.  A wider modulus is
+    not hostile, it is beyond the chains, and belongs to the native
+    host tier (``crypto/rsa.py:verify_host_many``).  Everyone who
+    routes by width — verifier, fault check, signer, modexp
     dispatcher, tenant channel, the sidecar's warm-up — asks here.
     """
     if bits <= 0:
         return Chains(False, False)
     return Chains(
         verify=bits <= 16 * DIGITS and _bases_hold(16 * DIGITS),
-        pow=_bases_hold(bits) and (
+        pow=_channel_bits(bits) is not None and (
             exp_bits is None or exp_class(bits, exp_bits) is not None
         ),
     )
@@ -215,7 +251,12 @@ class RNSContext:
     """Shared (key-independent) precomputation for one digit width."""
 
     def __init__(self, digits: int = DIGITS, n_bits: int = 2048):
-        self.pb, self.pq = _deal_bases(n_bits)
+        # past the wide chain's range this deals from the 12-bit supply
+        # and raises, as any width beyond the chains does
+        top = _channel_bits(n_bits) or PR_BITS
+        self.wide = top == WIDE_BITS
+        self.split = WIDE_SPLIT if self.wide else SPLIT
+        self.pb, self.pq = _deal_bases(n_bits, top)
         k = self.k = len(self.pb)
         self.digits = digits
         self.M = math.prod(self.pb)
@@ -233,7 +274,7 @@ class RNSContext:
             for j, q in enumerate(self.pq):
                 E1[i, j] = Mi[i] % q
             E1[i, k] = Mi[i] % PR
-        self._E1 = self._split6(E1)
+        self._E1 = self._split(E1)
 
         # --- extension B' -> B (+ redundant channel, Shenoy) ----------
         Mqj = [self.Mq // q for q in self.pq]
@@ -243,7 +284,7 @@ class RNSContext:
             for i, p in enumerate(self.pb):
                 E2[j, i] = Mqj[j] % p
             E2[j, k] = Mqj[j] % PR
-        self._E2 = self._split6(E2)
+        self._E2 = self._split(E2)
         self.Mq_mod_b = f([self.Mq % p for p in self.pb])
         self.invMq_pr = np.float32(pow(self.Mq % PR, -1, PR))
         self.invM_q = f([pow(self.M % q, -1, q) for q in self.pq])
@@ -264,15 +305,17 @@ class RNSContext:
                 D[2 * d + 1, ch] = w_hi % p
             D[2 * d, 2 * k] = w_lo % PR
             D[2 * d + 1, 2 * k] = w_hi % PR
-        self._D = self._split6(D)
+        self._D = self._split(D)
         self.pow_keys = _PowKeyTable(self)
 
-    @staticmethod
-    def _split6(m: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
-        """12-bit entries → two 6-bit f32 planes."""
+    def _split(self, m: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+        """Channel-width entries → two f32 planes, the low ``split``
+        bits and the rest: 6 + 6 on 12-bit channels, 7 + 6 on the wide
+        chain's 13-bit ones."""
+        s = self.split
         return (
-            (m & 63).astype(np.float32),
-            (m >> 6).astype(np.float32),
+            (m & ((1 << s) - 1)).astype(np.float32),
+            (m >> s).astype(np.float32),
         )
 
     # -- per-key (per modulus N) data, host side ------------------------
@@ -306,7 +349,7 @@ class RNSContext:
         return n_all, n_r, neg_ninv_b, ninv_all, m2_all, m2_r
 
 
-@functools.lru_cache(maxsize=4)
+@functools.lru_cache(maxsize=8)
 def context(digits: int = DIGITS, n_bits: int = 2048) -> RNSContext:
     return RNSContext(digits, n_bits)
 
@@ -342,6 +385,15 @@ def _mulmod(a, b, inv_p, p):
     return _barrett(a * b, inv_p, p)
 
 
+def _mulmod_wide(a, b, inv_p, p):
+    """a·b mod p on the wide chain's 13-bit channels, where a·b (up to
+    2^26) is no exact f32: ``b`` in 7-bit halves, each partial product
+    and their sum below 2^21."""
+    bh = jnp.floor(b * np.float32(1 / 128))
+    bl = b - bh * 128
+    return _barrett(a * bl + _barrett(a * bh, inv_p, p) * 128, inv_p, p)
+
+
 def _addmod(a, b, p):
     s = a + b
     return jnp.where(s >= p, s - p, s)
@@ -361,7 +413,7 @@ def _mulmod_r(a, b):
     return _mod_r(a * b)
 
 
-def _matmul_f32(x, m_split):
+def _matmul_f32(x, m_split, split: int = SPLIT):
     """Exact Σ_i x[i]·M[i,j] via bf16 MXU matmuls with f32 accumulate.
 
     ``x`` (T,rows) f32 integral < 2^12, split into 6-bit halves; the
@@ -370,10 +422,13 @@ def _matmul_f32(x, m_split):
     into an f32 accumulator — one systolic pass per dot instead of
     XLA's multi-pass f32 emulation.  Partial products < 2^12, summed
     over ≤ 400 rows < 2^21 — exact.  Returns (s_ll, s_mid, s_hh).
+    On the wide chain (``split`` 7) ``x`` < 2^13 and the low planes
+    hold 7 bits: partials < 2^14, summed over ≤ 512 rows < 2^23.
     """
     mlo, mhi = m_split
-    xlo = x - jnp.floor(x * np.float32(1 / 64)) * 64  # x & 63, f32-exact
-    xhi = jnp.floor(x * np.float32(1 / 64))
+    s = 1 << split
+    xlo = x - jnp.floor(x * np.float32(1 / s)) * s  # x & (s-1), f32-exact
+    xhi = jnp.floor(x * np.float32(1 / s))
     dot = lambda a, b: jax.lax.dot_general(
         a.astype(jnp.bfloat16),
         b.astype(jnp.bfloat16),
@@ -386,21 +441,24 @@ def _matmul_f32(x, m_split):
     return s_ll, s_mid, s_hh
 
 
-def _combine_mod(s_ll, s_mid, s_hh, inv_p, p):
+def _combine_mod(s_ll, s_mid, s_hh, inv_p, p, split: int = SPLIT):
     """(s_ll + 2^6·s_mid + 2^12·s_hh) mod p, channelwise, f32-exact.
 
     Partials < 2^22; reduce each below p (< 2^12) before shifting so
-    every intermediate stays < 2^24."""
+    every intermediate stays < 2^24.  (Wide chain: shifts of 2^7 and
+    2^14, p < 2^13, intermediates < 2^20.)"""
+    s = 1 << split
     a = _barrett(s_ll, inv_p, p)
     b = _barrett(s_mid, inv_p, p)
     d = _barrett(s_hh, inv_p, p)
-    b6 = _barrett(b * 64, inv_p, p)
-    d12 = _barrett(_barrett(d * 64, inv_p, p) * 64, inv_p, p)
+    b6 = _barrett(b * s, inv_p, p)
+    d12 = _barrett(_barrett(d * s, inv_p, p) * s, inv_p, p)
     return _addmod(_addmod(a, b6, p), d12, p)
 
 
-def _combine_mod_r(s_ll, s_mid, s_hh):
-    return _mod_r(_mod_r(s_ll) + _mod_r(s_mid * 64) + _mod_r(_mod_r(s_hh * 64) * 64))
+def _combine_mod_r(s_ll, s_mid, s_hh, split: int = SPLIT):
+    s = 1 << split
+    return _mod_r(_mod_r(s_ll) + _mod_r(s_mid * s) + _mod_r(_mod_r(s_hh * s) * s))
 
 
 class _Consts:
@@ -408,6 +466,8 @@ class _Consts:
 
     def __init__(self, ctx: RNSContext):
         self.k = ctx.k
+        self.split = ctx.split
+        self.mul = _mulmod_wide if ctx.wide else _mulmod
         j = jnp.asarray
         self.pb = j(ctx.p_all[: ctx.k])
         self.pq = j(ctx.p_all[ctx.k :])
@@ -429,34 +489,38 @@ def _mont_mul(cn, a, b, key):
     ab, aq, ar = a
     bb, bq, br = b
     n_all, n_r, neg_ninv_b, _ninv, _m2, _m2r = key
-    k = cn.k
+    k, sp, mul = cn.k, cn.split, cn.mul
     nq = n_all[:, k:]
 
-    db = _mulmod(ab, bb, cn.ib, cn.pb)
-    dq = _mulmod(aq, bq, cn.iq, cn.pq)
+    db = mul(ab, bb, cn.ib, cn.pb)
+    dq = mul(aq, bq, cn.iq, cn.pq)
     dr = _mulmod_r(ar, br)
 
     # q = d·(−N⁻¹) mod M, channelwise in B.
-    qb = _mulmod(db, neg_ninv_b, cn.ib, cn.pb)
+    qb = mul(db, neg_ninv_b, cn.ib, cn.pb)
     # Approximate extension of q̂ = Σ σ_i·M_i (= q + α₁M) to B' ∪ {2^12}.
-    sigma = _mulmod(qb, cn.invMi_b, cn.ib, cn.pb)
-    s_ll, s_mid, s_hh = _matmul_f32(sigma, cn.E1)
-    qhat_q = _combine_mod(s_ll[:, :k], s_mid[:, :k], s_hh[:, :k], cn.iq, cn.pq)
-    qhat_r = _combine_mod_r(s_ll[:, k:], s_mid[:, k:], s_hh[:, k:])
+    sigma = mul(qb, cn.invMi_b, cn.ib, cn.pb)
+    s_ll, s_mid, s_hh = _matmul_f32(sigma, cn.E1, sp)
+    qhat_q = _combine_mod(
+        s_ll[:, :k], s_mid[:, :k], s_hh[:, :k], cn.iq, cn.pq, sp
+    )
+    qhat_r = _combine_mod_r(s_ll[:, k:], s_mid[:, k:], s_hh[:, k:], sp)
 
     # r = (d + q̂·N)/M in B' and the redundant channel.
-    t = _mulmod(qhat_q, nq, cn.iq, cn.pq)
-    rq = _mulmod(_addmod(dq, t, cn.pq), cn.invM_q, cn.iq, cn.pq)
+    t = mul(qhat_q, nq, cn.iq, cn.pq)
+    rq = mul(_addmod(dq, t, cn.pq), cn.invM_q, cn.iq, cn.pq)
     tr = _mulmod_r(qhat_r, n_r)
     rr = _mulmod_r(_mod_r(dr + tr), cn.invM_pr)
 
     # Exact extension of r from B' back to B (Shenoy via 2^12 channel).
-    sigma2 = _mulmod(rq, cn.invMi_q, cn.iq, cn.pq)
-    z_ll, z_mid, z_hh = _matmul_f32(sigma2, cn.E2)
-    ext_b = _combine_mod(z_ll[:, :k], z_mid[:, :k], z_hh[:, :k], cn.ib, cn.pb)
-    ext_r = _combine_mod_r(z_ll[:, k:], z_mid[:, k:], z_hh[:, k:])
+    sigma2 = mul(rq, cn.invMi_q, cn.iq, cn.pq)
+    z_ll, z_mid, z_hh = _matmul_f32(sigma2, cn.E2, sp)
+    ext_b = _combine_mod(
+        z_ll[:, :k], z_mid[:, :k], z_hh[:, :k], cn.ib, cn.pb, sp
+    )
+    ext_r = _combine_mod_r(z_ll[:, k:], z_mid[:, k:], z_hh[:, k:], sp)
     alpha = _mulmod_r(_mod_r(ext_r - rr + _PRF), cn.invMq_pr)
-    corr = _mulmod(
+    corr = mul(
         jnp.broadcast_to(alpha, ext_b.shape),
         jnp.broadcast_to(cn.Mq_mod_b, ext_b.shape),
         cn.ib,
@@ -468,14 +532,18 @@ def _mont_mul(cn, a, b, key):
 
 def _to_residues(cn, digit_halves):
     """(T, 256) 8-bit digit halves → residues over [B | B' | 2^12]."""
-    s_ll, s_mid, s_hh = _matmul_f32(digit_halves, cn.D)
-    k = cn.k
-    xb = _combine_mod(s_ll[:, :k], s_mid[:, :k], s_hh[:, :k], cn.ib, cn.pb)
+    k, sp = cn.k, cn.split
+    s_ll, s_mid, s_hh = _matmul_f32(digit_halves, cn.D, sp)
+    xb = _combine_mod(
+        s_ll[:, :k], s_mid[:, :k], s_hh[:, :k], cn.ib, cn.pb, sp
+    )
     xq = _combine_mod(
         s_ll[:, k : 2 * k], s_mid[:, k : 2 * k], s_hh[:, k : 2 * k],
-        cn.iq, cn.pq,
+        cn.iq, cn.pq, sp,
     )
-    xr = _combine_mod_r(s_ll[:, 2 * k :], s_mid[:, 2 * k :], s_hh[:, 2 * k :])
+    xr = _combine_mod_r(
+        s_ll[:, 2 * k :], s_mid[:, 2 * k :], s_hh[:, 2 * k :], sp
+    )
     return xb, xq, xr
 
 
@@ -612,7 +680,7 @@ def _pow_kernel(cn: _Consts, base_halves, exp_nibbles_t, key):
     vb, _vq, _vr = _mont_mul(cn, acc, ones, key)  # out of Montgomery form
     # CRT coefficients: σ_i = v_i·(M_i⁻¹ mod p_i); host side rebuilds
     # v = Σ σ_i·M_i (< M, no α ambiguity since v < (k+1)·N ≪ M).
-    return _mulmod(vb, cn.invMi_b, cn.ib, cn.pb)
+    return cn.mul(vb, cn.invMi_b, cn.ib, cn.pb)
 
 
 def _pow_name(n_bits: int, exp_bits: int | None) -> str:
@@ -659,14 +727,27 @@ def _jitted_pow(
     return jax.jit(rns_pow, donate_argnums=(0, 1, 2) if donate else ())
 
 
+def _crt_digit_bytes(ctx: RNSContext) -> int:
+    """Bytes of one digit plane of :func:`_crt_matrix`: 4, so that row
+    sums Σ σ_i·M_i stay < k·2^12·2^32 < 2^53 (k < 512) — exact; on the
+    wide chain's 13-bit channels (k·2^13·2^32 passes 2^53 at k = 340)
+    2: sums < k·2^13·2^16."""
+    return 2 if ctx.wide else 4
+
+
 def _crt_matrix(ctx: RNSContext) -> np.ndarray:
-    """(k, D) float64 32-bit digit planes of M_i = M/p_i, cached on ctx.
-    Row sums Σ σ_i·M_i stay < k·2^12·2^32 < 2^53 (k < 512): exact."""
+    """(k, D) float64 digit planes of M_i = M/p_i, cached on ctx.
+    Row sums Σ σ_i·M_i are exact (:func:`_crt_digit_bytes`)."""
     m = getattr(ctx, "_crt_digits", None)
     if m is None:
-        width = (ctx.M.bit_length() + PR_BITS + 31) // 32 + 1
+        b = _crt_digit_bytes(ctx)
+        width = (ctx.M.bit_length() + PR_BITS + 8 * b - 1) // (8 * b) + 1
+        if ctx.wide:
+            width += 1  # σ_i < 2^13: one more bit of carry than 2^12
         m = np.stack([
-            np.frombuffer((ctx.M // p).to_bytes(4 * width, "little"), "<u4")
+            np.frombuffer(
+                (ctx.M // p).to_bytes(b * width, "little"), f"<u{b}"
+            )
             for p in ctx.pb
         ]).astype(np.float64)
         ctx._crt_digits = m
@@ -685,14 +766,15 @@ def _sigma_to_ints(ctx: RNSContext, sigma: np.ndarray) -> list[int]:
     acc = np.einsum(
         "tk,kd->td", sigma.astype(np.float64), _crt_matrix(ctx)
     ).astype(np.int64)
-    out = np.empty(acc.shape, dtype="<u4")
+    b = _crt_digit_bytes(ctx)
+    out = np.empty(acc.shape, dtype=f"<u{b}")
     carry = np.zeros(acc.shape[0], dtype=np.int64)
     for d in range(acc.shape[1]):
         carry += acc[:, d]
-        out[:, d] = carry  # the low 32 bits
-        carry >>= 32
+        out[:, d] = carry  # the low 8·b bits
+        carry >>= 8 * b
     raw = out.tobytes()
-    w = 4 * acc.shape[1]
+    w = b * acc.shape[1]
     big = ctx.M
     return [
         int.from_bytes(raw[o : o + w], "little") % big
@@ -859,7 +941,10 @@ def power_mod_rns(
         if e < 0 or e.bit_length() > exp_bits:
             return None
     ctx = pow_context(n_bits)
-    with trace.leaf("flush.stage", op, items=len(mods), bits=n_bits):
+    long_exp = {} if exp_bits == n_bits else {"exp_bits": exp_bits}
+    with trace.leaf(
+        "flush.stage", op, items=len(mods), bits=n_bits, **long_exp
+    ):
         unique: dict[int, int] = {}
         row_mod = np.fromiter(
             (unique.setdefault(m, len(unique)) for m in mods),

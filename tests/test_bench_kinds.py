@@ -73,12 +73,18 @@ def test_the_committed_mixes_open_nothing_new():
         assert run.plants == plants.PLANTS
         assert run.config["users"] == 1
     cells = {w["name"] for w in runmod.load_manifest()["workloads"]}
-    assert cells == {*LOAD_CELLS, "q10-ca2048.issue"}
+    assert cells == {*LOAD_CELLS, "q10-ca2048.issue", "q10-ca4096.issue"}
     run = opened("q10-ca2048.issue")
     assert list(run.kinds) == ["ca_issue"] and run.config["users"] == 1
     assert run.limits == [("ca_certs_bad", "<=", 0),
                           ("ca_certs_checked", ">=", 1)]
     assert set(run.plants) == {*plants.PLANTS, "ca_bent_signature"}
+    run = opened("q10-ca4096.issue")
+    assert list(run.kinds) == ["ca_issue_w4096"] and run.config["users"] == 1
+    assert run.limits == [("ca4096_certs_bad", "<=", 0),
+                          ("ca4096_certs_checked", ">=", 1),
+                          ("ca4096_certs_resigned", ">=", 1)]
+    assert set(run.plants) == {*plants.PLANTS, "ca_bent_signature_w4096"}
 
 
 class FakeCA(FakeClient):
@@ -162,3 +168,66 @@ def test_the_kind_refuses_a_program_without_the_route(monkeypatch, capsys, stub)
     assert capsys.readouterr().err.startswith("FAILED: kind 'ca_issue' ")
     # the load cells name no kind and ask nothing
     assert opened("q10-rsa2048.load").kinds == {}
+
+
+def test_the_4096_bit_kind_issues_the_calls_of_ca_issue():
+    """``ca_issue_w4096`` draws exactly what ``ca_issue`` draws for a seed
+    — the same TBS, the same stored record — under its own name, and its
+    judge takes every certificate and re-signs a seeded sample."""
+    config = runmod.load_json("benchmarks", "configs", "q10-ca4096.json")
+    assert config["threshold_ca"]["key_bits"] == 4096
+    assert config["environment"]["BFTKV_CA_BITS"] == "4096"
+    logs, calls = {}, {}
+    for traffic, kind in (("ca-issue", "ca_issue"),
+                          ("ca-issue-w4096", "ca_issue_w4096")):
+        mix = mix_of(traffic)
+        loaded = kinds.load(mix["ops"])
+        clients = [FakeCA()]
+        loaded[kind].prepare({"clients": clients, "config": config,
+                              "mix": mix, "seed": SEED, "rehearse": True})
+        callers, _ = drive(mix, SEED, 2, clients=clients, loaded=loaded)
+        logs[kind] = clients[0].log
+        calls[kind] = [(c.idx, x.kind, x.keynums, x.beside)
+                       for c in callers for x in c.calls]
+        judged = [x for c in callers for x in c.calls]
+        numbers = loaded[kind].judge(judged, {})
+    assert logs["ca_issue"] == logs["ca_issue_w4096"]
+    rename = {"ca_issue": "ca_issue_w4096"}
+    assert [(i, rename.get(k, k), n, rename.get(b, b))
+            for i, k, n, b in calls["ca_issue"]] == calls["ca_issue_w4096"]
+    # the fake signs with nothing: every certificate is bad
+    assert numbers == {"ca4096_certs_bad": 32, "ca4096_certs_checked": 32,
+                       "ca4096_certs_resigned": 32}
+
+
+@pytest.mark.parametrize("stub", ["no_function", "no_wide_chain"])
+def test_the_4096_bit_kind_refuses_a_program_without_the_route(
+        monkeypatch, capsys, stub):
+    """The parent of the PR that entered ``q10-ca4096``: its
+    ``remote_route`` holds a 2,048-bit fragment and no 4,096-bit one.  The
+    kind refuses such a program by name when it is loaded: a ``FAILED:``
+    line, exit 2, no child."""
+    from bftkv_tpu.ops import modexp
+
+    if stub == "no_function":
+        monkeypatch.delattr(modexp, "remote_route")
+    else:
+        monkeypatch.setattr(modexp, "remote_route",
+                            lambda bits, exp_bits: bits <= 2048)
+    for name in ("ca_issue", "ca_issue_w4096"):
+        monkeypatch.delitem(sys.modules, "benchmarks.kinds." + name,
+                            raising=False)
+    with pytest.raises(BenchFailure) as refused:
+        kinds.load(["ca_issue_w4096"])
+    assert "kind 'ca_issue_w4096'" in str(refused.value)
+    assert "4,096-bit CA key in the replica" in str(refused.value)
+    started = []
+    monkeypatch.setattr(runmod.harness, "Cluster",
+                        lambda *a, **kw: started.append(a))
+    for name in ("ca_issue", "ca_issue_w4096"):
+        monkeypatch.delitem(sys.modules, "benchmarks.kinds." + name,
+                            raising=False)
+    rc = runmod.main(["--workload", "q10-ca4096.issue", "--seed",
+                      "3000000019", "--rehearse"])
+    assert rc == 2 and started == []
+    assert capsys.readouterr().err.startswith("FAILED: kind 'ca_issue_w4096' ")

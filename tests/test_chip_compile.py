@@ -179,6 +179,27 @@ def test_fused_fragment_chain_compiles_at_both_buckets(topo, rows):
     assert pallas_rns._pow_tile(256) == 128
 
 
+def test_fused_wide_fragment_chain_compiles_at_its_bucket(topo):
+    """What one chip launches for a first-level fragment of an RSA-4096
+    CA key: the wide chain (13-bit channels, kpad 384) fused at 4,096-bit
+    rows and 2,064 windows, its one bucket of 64 rows — one tile."""
+    one = _on(SingleDeviceSharding(topo.devices[0]))
+    rows, digits = 64, 256
+    windows = rns.long_exp_bits(4096) // 4
+    assert (rns.long_exp_rows(4096), windows) == (rows, 2064)
+    fn = pallas_rns.jitted_pow(
+        digits, 4096, windows, rows, rns._pow_name(4096, 4 * windows)
+    )
+    compiled = fn.lower(
+        one((rows, 2 * digits), jnp.uint8),
+        one((windows, rows), jnp.uint8),
+        one((rows,), jnp.int32),
+        _key_shapes(rns.pow_context(4096), one),
+    ).compile()
+    _compiled_ok(compiled, kernel=True)
+    assert pallas_rns._pow_tile(384) == rows
+
+
 @pytest.mark.parametrize(
     "kind,rows", [("verify", 4096), ("pow", 2048)]
 )

@@ -75,23 +75,28 @@ def mixed_items(keys) -> list:
 @pytest.mark.parametrize("bits", [1024, 1536, 2048, 3072])
 def test_the_rule_agrees_with_the_bases(bits):
     """``chains(bits).pow`` is whether ``RNSContext`` can be built at
-    that width; the verify chain takes whole moduli up to 2048 bits."""
+    that width — on 12-bit channels to ~2,130 bits, on the wide chain's
+    13-bit ones past that, to 4,096; the verify chain takes whole
+    moduli up to 2048 bits."""
     try:
-        rns.RNSContext(max(32, bits // 16), bits)
+        ctx = rns.RNSContext(max(32, bits // 16), bits)
         built = True
     except ValueError:
         built = False
     assert rns.chains(bits).pow is built
-    assert built is (bits <= 2048)
+    assert built is (bits <= rns.WIDE_MAX_BITS)
+    assert not built or ctx.wide is (bits > 2048)
     assert rns.chains(bits).verify is (bits <= 2048)
 
 
 def test_the_rule_at_the_edges():
     assert rns.chains(0) == rns.chains(-7) == (False, False)
     # the CRT halves of RSA-3072 and RSA-4096 ride the pow chain, the
-    # moduli themselves do not ride the verify chain
+    # moduli themselves do not ride the verify chain; whole moduli to
+    # 4,096 bits ride the wide pow chain, and nothing wider does
     assert rns.chains(1536).pow and rns.chains(2048).pow
-    assert not rns.chains(2049).verify and not rns.chains(4096).pow
+    assert not rns.chains(2049).verify and not rns.chains(4096).verify
+    assert rns.chains(4096).pow and not rns.chains(4112).pow
     assert rsa.bits_class(1 << 3071) == 3072
     assert rsa.bits_class(1 << 2047) == 2048
     assert rsa.bits_class(1 << 5000) == "other"

@@ -57,7 +57,6 @@ def _service(*, max_batch=4096, verify_crossover=16, sign_threshold=16,
     )
     svc.modexp = _HostDispatcher(
         pow, max_batch, device_threshold=max(16, verify_crossover),
-        LONG_EXP_MAX_ROWS=dispatch.ModexpDispatcher.LONG_EXP_MAX_ROWS,
     )
     return svc
 
@@ -169,6 +168,22 @@ def test_a_declared_ca_builds_its_fragment_class(monkeypatch):
     assert svc.sign.signer.warm_rows == frozenset({1024})
     assert svc.warmup["ca_bits"] == [2048]
     assert svc.warmup["fragment_rows"] == [(2048, 4160)]
+
+
+def test_a_declared_4096_bit_ca_builds_the_wide_fragment_class(monkeypatch):
+    """``BFTKV_CA_BITS=4096``: the wide chain's class (4096, 8256),
+    warmed and checked like the 2,048-bit class's at its one bucket — a
+    launch is one 64-row tile there (``rns.long_exp_rows``)."""
+    monkeypatch.setenv("BFTKV_CA_BITS", "4096")
+    svc = _service(max_batch=64)
+    _counting_device_rows(svc)
+    svc.warmup = svc._warm()
+    assert _warmed(svc, "fragment") == [64]
+    assert {s["bits"] for s in svc.warmup["shapes"]
+            if s["role"] == "fragment"} == {4096}
+    assert svc.modexp.warm_rows == frozenset({1024, (4096, 8256)})
+    assert svc.warmup["ca_bits"] == [4096]
+    assert svc.warmup["fragment_rows"] == [(4096, 8256)]
 
 
 def test_a_fragment_launch_that_misses_the_device_refuses_to_start(
