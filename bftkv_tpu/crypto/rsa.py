@@ -258,10 +258,12 @@ def emsa_pkcs1v15_sha256(message: bytes, em_len: int) -> int:
 # One RSA-2048 sign is two 1024-bit modexps; CPython's pow() runs them
 # at ~4 ms each and holds the GIL throughout, capping a 4-signs-per-
 # write protocol near 25 writes/s/core regardless of round structure.
-# native/montmodexp.c is the same math as fixed-width CIOS Montgomery
-# with a 4-bit window (~5x) and releases the GIL.  pow() stays as the
-# fallback AND the semantics oracle (differential tests in
-# tests/test_rsa.py, tests/test_host_batch.py).  Disable with
+# native/montmodexp.c takes a batch of rows in one call with the GIL
+# released, each row on libcrypto's Montgomery exponentiation (the
+# constant-time routine for a private exponent), or on its own CIOS
+# loop where libcrypto is absent; ``_MM.engine`` says which.  pow()
+# stays as the fallback AND the semantics oracle (differential tests
+# in tests/test_rsa.py, tests/test_host_batch.py).  Disable with
 # BFTKV_NATIVE_MODEXP=off.
 
 _NATIVE_DIR = os.path.abspath(
@@ -291,7 +293,7 @@ def _load_native_modexp(nd: str = _NATIVE_DIR):
             fcntl.flock(lk, fcntl.LOCK_EX)
             if _stale_native(so_path, src):
                 # -B: make's own mtime rule would keep a .so that is
-                # newer than the source and still lacks the batch entry
+                # newer than the source and still lacks the newest entry
                 subprocess.run(
                     [
                         "make", "-s", "-B", "mont",
@@ -304,13 +306,37 @@ def _load_native_modexp(nd: str = _NATIVE_DIR):
             )
             mod = importlib.util.module_from_spec(spec)
             spec.loader.exec_module(mod)
-        # Self-check against the oracle before trusting it for real
-        # signatures: a miscompiled extension must fall back, not
-        # corrupt the crypto plane.  Both of the scan's forms: the
-        # bit-by-bit one a public exponent takes and the windowed one.
-        m_ = (1 << 127) - 1
-        key_row, width = _mont_params(m_)
-        rows = [(0xABCDEF123456789, F4), (m_ - 2, m_ - 2), (1, 0)]
+        return mod if _self_check(mod) else None
+    except Exception:
+        return None
+
+
+#: The self-check's wide rows: a 1,024-bit odd modulus and a full-length
+#: exponent, fixed bytes (any odd modulus will do for a modexp).
+_CHECK_MOD = (
+    int.from_bytes(hashlib.shake_256(b"montmodexp check m").digest(128), "big")
+    | (1 << 1023) | 1
+)
+_CHECK_EXP = int.from_bytes(
+    hashlib.shake_256(b"montmodexp check e").digest(128), "big"
+) | (1 << 1023)
+
+
+def _self_check(mod) -> bool:
+    """Trust an extension for real signatures only where its rows equal
+    ``pow``: a miscompiled or misbehaving engine must fall back, not
+    corrupt the crypto plane.  Both routes an engine has — a public
+    exponent and a full-length one — at 127 bits and at 1,024, on the
+    edge bases, in one call a width."""
+    if getattr(mod, "engine", None) not in _ENGINES:
+        return False
+    m127, m = (1 << 127) - 1, _CHECK_MOD
+    bases = (0, 1, m - 1, _CHECK_EXP % m)
+    for m, rows in (
+        (m127, [(0xABCDEF123456789, F4), (m127 - 2, m127 - 2), (1, 0)]),
+        (m, [(x, y) for x in bases for y in (F4, _CHECK_EXP)]),
+    ):
+        key_row, width = _mont_params(m)
         got = mod.powmod_many(
             width,
             width,
@@ -319,25 +345,24 @@ def _load_native_modexp(nd: str = _NATIVE_DIR):
             key_row * len(rows),
         )
         if got != b"".join(
-            pow(x, y, m_).to_bytes(width, "big") for x, y in rows
+            pow(x, y, m).to_bytes(width, "big") for x, y in rows
         ):
-            return None
-        return mod
-    except Exception:
-        return None
+            return False
+    return True
 
 
 def _stale_native(so_path: str, src: str) -> bool:
     """Rebuild where the extension is missing, older than its source,
-    or built from a source that had no batch entry (a ``.so`` carried
-    over from an older tree): decided from the file, before anything
+    or built from an older source, without the CIOS-only entry that
+    came with the libcrypto engine (a ``.so`` carried over from an
+    older tree): decided from the file, before anything
     is loaded — an extension module cannot be loaded twice."""
     if not os.path.exists(so_path) or (
         os.path.getmtime(so_path) < os.path.getmtime(src)
     ):
         return True
     with open(so_path, "rb") as f:
-        return b"powmod_many" not in f.read()
+        return b"powmod_many_cios" not in f.read()
 
 
 def _mont_params(mod: int) -> tuple:
@@ -355,7 +380,14 @@ def _mont_params(mod: int) -> tuple:
     )
 
 
+#: What ``_MM.engine`` may name: the rows' engine, and the name of the
+#: counter of rows it ran (``host.modexp.<engine>``).
+_ENGINES = ("libcrypto", "cios")
+
 _MM = _load_native_modexp()
+if _MM is not None:
+    for _engine in _ENGINES:
+        metrics.incr("host.modexp." + _engine, 0)  # a ratio has its denominator
 
 #: Widest modulus the extension takes (native/montmodexp.c MAX_LIMBS).
 _NATIVE_MAX_BITS = 4096
@@ -438,6 +470,8 @@ def _powmod_rows(rows: list) -> list[int]:
         width, idx = job
         return _powmod_chunk(width, [rows[i] for i in idx])
 
+    if rows:
+        metrics.incr("host.modexp." + _MM.engine, len(rows))
     futures = [_host_pool().submit(run, job) for job in jobs[1:]]
     results = [run(jobs[0])] if jobs else []
     results += [f.result() for f in futures]

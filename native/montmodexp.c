@@ -1,15 +1,27 @@
-/* Montgomery modular exponentiation for the RSA hot path.
+/* Modular exponentiation for the RSA hot path: the host tier's rows.
  *
  * CPython's big-int pow() is the write path's floor: one RSA-2048
  * CRT sign is two 1024-bit modexps at ~4 ms each, it holds the GIL
  * for the duration, and a 4-signs-per-write protocol tops out around
  * 25 writes/s/core no matter how few round trips the transport pays
- * (docs/PERFORMANCE.md "RSA floor").  This extension implements the
- * same modexp as fixed-width CIOS Montgomery multiplication with a
- * 4-bit window, releases the GIL while computing, and is loaded
+ * (docs/PERFORMANCE.md "RSA floor").  This extension takes a batch of
+ * rows in one call, releases the GIL while computing, and is loaded
  * opportunistically by bftkv_tpu/crypto/rsa.py (BFTKV_NATIVE_MODEXP=off
  * disables; the pure pow() path remains the semantics oracle, pinned
- * by differential tests in tests/test_rsa.py).
+ * by differential tests in tests/test_rsa.py and test_host_batch.py).
+ *
+ * Engine: each row runs on libcrypto's Montgomery exponentiation
+ * (its assembly Montgomery products), found at module init in the
+ * libcrypto this process already has (CPython's _hashlib links it) and
+ * bound with dlsym, so nothing links against it at build time.  A row
+ * whose exponent is longer than 4 bytes (leading zero bytes aside) is
+ * private material -- a CRT half, a CA fragment -- and takes
+ * BN_mod_exp_mont_consttime; a public exponent takes BN_mod_exp_mont.
+ * One Montgomery context per distinct modulus of a call.  Without the
+ * library or a symbol, or for a row whose libcrypto call fails, the
+ * row takes the fixed-width CIOS Montgomery loop below (4-bit window),
+ * which gives the same answer.  The module attribute `engine` names
+ * what the rows take ("libcrypto" or "cios").
  *
  * API:  powmod_many(width, ewidth, bases, exps, keys) -> bytes
  *   N rows, each with its own modulus, all big-endian: bases is
@@ -20,10 +32,13 @@
  *   The GIL is released once for the whole call: the host tier's batch
  *   entry (crypto/rsa.py sign_many / verify_host_many; one row is the
  *   one-item form).
+ *       powmod_many_cios(...) is the same call on the CIOS loop alone,
+ *   for the tests that pin it; the loader never calls it.
  */
 
 #define PY_SSIZE_T_CLEAN
 #include <Python.h>
+#include <dlfcn.h>
 #include <stdint.h>
 #include <string.h>
 
@@ -179,7 +194,131 @@ static void powmod_core(const u64 *x, const unsigned char *e,
     memcpy(acc, t, bytes);
 }
 
-static PyObject *py_powmod_many(PyObject *self, PyObject *args) {
+/* -- the libcrypto engine --------------------------------------------------
+ * Opaque types and the prototypes of OpenSSL's BN API (stable across
+ * 1.1 and 3.x); no header is needed. */
+
+typedef struct bignum_st BIGNUM;
+typedef struct bignum_ctx BN_CTX;
+typedef struct bn_mont_ctx_st BN_MONT_CTX;
+typedef int (*bn_exp_fn)(BIGNUM *r, const BIGNUM *a, const BIGNUM *p,
+                         const BIGNUM *m, BN_CTX *ctx, BN_MONT_CTX *mont);
+
+static struct {
+    BN_CTX *(*ctx_new)(void);
+    void (*ctx_free)(BN_CTX *);
+    BIGNUM *(*bn_new)(void);
+    void (*bn_free)(BIGNUM *);
+    BIGNUM *(*bin2bn)(const unsigned char *, int, BIGNUM *);
+    int (*bn2binpad)(const BIGNUM *, unsigned char *, int);
+    BN_MONT_CTX *(*mont_new)(void);
+    int (*mont_set)(BN_MONT_CTX *, const BIGNUM *, BN_CTX *);
+    void (*mont_free)(BN_MONT_CTX *);
+    bn_exp_fn exp_public, exp_secret;
+} lc;
+static int lc_ready;
+
+static int lc_sym(void *h, const char *name, void *slot) {
+    void *p = dlsym(h, name);
+    if (p == NULL) return 0;
+    memcpy(slot, &p, sizeof p); /* object pointer -> function pointer */
+    return 1;
+}
+
+/* 1 when every symbol is bound.  The handle is never closed: the
+ * library outlives the module. */
+static int lc_load(void) {
+    void *h = dlopen("libcrypto.so.3", RTLD_NOW | RTLD_NOLOAD);
+    if (h == NULL) h = dlopen("libcrypto.so.3", RTLD_NOW);
+    if (h == NULL) return 0;
+    return lc_sym(h, "BN_CTX_new", &lc.ctx_new) &&
+           lc_sym(h, "BN_CTX_free", &lc.ctx_free) &&
+           lc_sym(h, "BN_new", &lc.bn_new) &&
+           lc_sym(h, "BN_free", &lc.bn_free) &&
+           lc_sym(h, "BN_bin2bn", &lc.bin2bn) &&
+           lc_sym(h, "BN_bn2binpad", &lc.bn2binpad) &&
+           lc_sym(h, "BN_MONT_CTX_new", &lc.mont_new) &&
+           lc_sym(h, "BN_MONT_CTX_set", &lc.mont_set) &&
+           lc_sym(h, "BN_MONT_CTX_free", &lc.mont_free) &&
+           lc_sym(h, "BN_mod_exp_mont", &lc.exp_public) &&
+           lc_sym(h, "BN_mod_exp_mont_consttime", &lc.exp_secret);
+}
+
+/* A call's Montgomery contexts, one per distinct modulus, replaced
+ * round-robin past MONT_SLOTS.  Rows of one modulus need not be
+ * neighbours: a client's CRT halves alternate p, q, p, q. */
+#define MONT_SLOTS 16
+
+typedef struct {
+    const unsigned char *mod; /* the row's modulus bytes in `keys` */
+    BIGNUM *n;
+    BN_MONT_CTX *mont;
+} mont_slot;
+
+static mont_slot *mont_for(mont_slot *slots, int *used, int *next,
+                           const unsigned char *mod, Py_ssize_t width,
+                           BN_CTX *ctx) {
+    for (int i = 0; i < *used; i++)
+        if (slots[i].mod != NULL &&
+            memcmp(slots[i].mod, mod, (size_t)width) == 0)
+            return &slots[i];
+    mont_slot *s;
+    if (*used < MONT_SLOTS) {
+        s = &slots[(*used)++];
+        s->n = lc.bn_new();
+        s->mont = lc.mont_new();
+    } else {
+        s = &slots[*next];
+        *next = (*next + 1) % MONT_SLOTS;
+    }
+    s->mod = NULL;
+    if (s->n == NULL || s->mont == NULL ||
+        lc.bin2bn(mod, (int)width, s->n) == NULL ||
+        !lc.mont_set(s->mont, s->n, ctx))
+        return NULL;
+    s->mod = mod;
+    return s;
+}
+
+/* Rows through libcrypto; done[r] = 1 for each row it finished, the
+ * rest are the caller's.  Touches no Python object. */
+static void lc_rows(Py_ssize_t rows, Py_ssize_t width, Py_ssize_t ewidth,
+                    const unsigned char *bases, const unsigned char *exps,
+                    const unsigned char *keys, unsigned char *out,
+                    unsigned char *done) {
+    Py_ssize_t kw = 2 * width + 8;
+    mont_slot slots[MONT_SLOTS];
+    int used = 0, next = 0;
+    BN_CTX *ctx = lc.ctx_new();
+    BIGNUM *x = lc.bn_new(), *p = lc.bn_new(), *r = lc.bn_new();
+    if (ctx != NULL && x != NULL && p != NULL && r != NULL) {
+        for (Py_ssize_t i = 0; i < rows; i++) {
+            const unsigned char *e = exps + i * ewidth;
+            Py_ssize_t elen = ewidth;
+            while (elen > 0 && e[0] == 0) { e++; elen--; }
+            mont_slot *s = mont_for(slots, &used, &next, keys + i * kw,
+                                    width, ctx);
+            bn_exp_fn f = elen > 4 ? lc.exp_secret : lc.exp_public;
+            done[i] = s != NULL &&
+                      lc.bin2bn(bases + i * width, (int)width, x) != NULL &&
+                      lc.bin2bn(e, (int)elen, p) != NULL &&
+                      f(r, x, p, s->n, ctx, s->mont) &&
+                      lc.bn2binpad(r, out + i * width, (int)width) == width;
+        }
+    }
+    for (int i = 0; i < used; i++) {
+        lc.bn_free(slots[i].n); /* both take NULL */
+        lc.mont_free(slots[i].mont);
+    }
+    lc.bn_free(x);
+    lc.bn_free(p);
+    lc.bn_free(r);
+    lc.ctx_free(ctx);
+}
+
+/* -- the batch entries ----------------------------------------------------- */
+
+static PyObject *powmod_batch(PyObject *args, int use_lc) {
     Py_buffer base_b, exp_b, key_b;
     Py_ssize_t width, ewidth;
     if (!PyArg_ParseTuple(args, "nny*y*y*", &width, &ewidth, &base_b, &exp_b,
@@ -187,6 +326,7 @@ static PyObject *py_powmod_many(PyObject *self, PyObject *args) {
         return NULL;
 
     PyObject *ret = NULL;
+    unsigned char *done = NULL; /* rows the libcrypto engine finished */
     int L = (int)(width / 8);
     Py_ssize_t rows = width > 0 ? base_b.len / width : 0;
     Py_ssize_t kw = 2 * width + 8;
@@ -205,7 +345,9 @@ static PyObject *py_powmod_many(PyObject *self, PyObject *args) {
             }
         }
     }
-    ret = PyBytes_FromStringAndSize(NULL, rows * width);
+    done = PyMem_Calloc(rows > 0 ? (size_t)rows : 1, 1);
+    ret = done != NULL ? PyBytes_FromStringAndSize(NULL, rows * width)
+                       : PyErr_NoMemory();
     if (ret == NULL) goto done;
     {
         const unsigned char *bases = (const unsigned char *)base_b.buf;
@@ -214,7 +356,10 @@ static PyObject *py_powmod_many(PyObject *self, PyObject *args) {
         unsigned char *out = (unsigned char *)PyBytes_AS_STRING(ret);
 
         Py_BEGIN_ALLOW_THREADS;
+        if (use_lc)
+            lc_rows(rows, width, ewidth, bases, exps, keys, out, done);
         for (Py_ssize_t r = 0; r < rows; r++) {
+            if (done[r]) continue;
             u64 n[MAX_LIMBS], x[MAX_LIMBS], r2[MAX_LIMBS], acc[MAX_LIMBS];
             u64 n0inv[1];
             const unsigned char *k = keys + r * kw;
@@ -230,22 +375,43 @@ static PyObject *py_powmod_many(PyObject *self, PyObject *args) {
     }
 
 done:
+    PyMem_Free(done);
     PyBuffer_Release(&base_b);
     PyBuffer_Release(&exp_b);
     PyBuffer_Release(&key_b);
     return ret;
 }
 
+static PyObject *py_powmod_many(PyObject *self, PyObject *args) {
+    return powmod_batch(args, lc_ready);
+}
+
+static PyObject *py_powmod_many_cios(PyObject *self, PyObject *args) {
+    return powmod_batch(args, 0);
+}
+
 static PyMethodDef Methods[] = {
     {"powmod_many", py_powmod_many, METH_VARARGS,
      "powmod_many(width, ewidth, bases, exps, keys) -> bytes (N rows packed "
      "big-endian; keys rows are mod || r2 || n0inv(8); one GIL release)"},
+    {"powmod_many_cios", py_powmod_many_cios, METH_VARARGS,
+     "powmod_many on the CIOS loop alone, whatever `engine` says"},
     {NULL, NULL, 0, NULL},
 };
 
 static struct PyModuleDef moduledef = {
     PyModuleDef_HEAD_INIT, "_montmodexp",
-    "fixed-width Montgomery modexp (GIL-releasing)", -1, Methods,
+    "batched Montgomery modexp (GIL-releasing)", -1, Methods,
 };
 
-PyMODINIT_FUNC PyInit__montmodexp(void) { return PyModule_Create(&moduledef); }
+PyMODINIT_FUNC PyInit__montmodexp(void) {
+    PyObject *m = PyModule_Create(&moduledef);
+    if (m == NULL) return NULL;
+    lc_ready = lc_load();
+    if (PyModule_AddStringConstant(m, "engine",
+                                   lc_ready ? "libcrypto" : "cios") < 0) {
+        Py_DECREF(m);
+        return NULL;
+    }
+    return m;
+}

@@ -295,6 +295,148 @@ def test_a_replaced_one_item_oracle_governs_the_batch_form(keys, monkeypatch):
     assert rsa.verify_host_many(items) == [True] * 3
 
 
+# -- the engine ------------------------------------------------------------
+
+
+def operands(rows: list) -> tuple:
+    """``powmod_many``'s operands for ``[(base, exp, mod)]``, one width."""
+    width = rsa._mont_params(rows[0][2])[1]
+    ewidth = max(1, max((e.bit_length() + 7) // 8 for _b, e, _m in rows))
+    return (
+        width,
+        ewidth,
+        b"".join(b.to_bytes(width, "big") for b, _e, _m in rows),
+        b"".join(e.to_bytes(ewidth, "big") for _b, e, _m in rows),
+        b"".join(rsa._mont_params(m)[0] for _b, _e, m in rows),
+    )
+
+
+@needs_native
+@pytest.mark.parametrize("entry", ["powmod_many", "powmod_many_cios"])
+def test_one_call_of_many_moduli_in_any_order_matches_pow(entry):
+    """More distinct moduli than the engine keeps Montgomery contexts
+    for, each met again after others (a client's CRT halves alternate
+    p, q, p, q), at both exponent forms: every row is ``pow``'s."""
+    import random
+
+    rng = random.Random(20)
+    mods = [rng.getrandbits(1024) | (1 << 1023) | 1 for _ in range(20)]
+    rows = []
+    for i in range(60):
+        m = mods[(i * 7) % len(mods)] if i % 3 else mods[i % 2]
+        e = rsa.F4 if i % 2 else rng.getrandbits(1024)
+        rows.append((rng.getrandbits(1024) % m, e, m))
+    width = 128
+    got = getattr(rsa._MM, entry)(*operands(rows))
+    assert got == b"".join(
+        pow(b, e, m).to_bytes(width, "big") for b, e, m in rows
+    )
+
+
+@needs_native
+def test_mixed_widths_across_pool_chunks_match_pow():
+    """One host-tier batch of three widths and both exponent forms,
+    long enough to be cut into several chunks a width."""
+    import random
+
+    rng = random.Random(21)
+    rows, want = [], []
+    for bits in (1024, 1536, 2048):
+        mods = [rng.getrandbits(bits) | (1 << (bits - 1)) | 1 for _ in range(3)]
+        for i in range(24):
+            m = mods[i % 3]
+            e = rng.getrandbits(bits // 2) if i % 2 else rsa.F4
+            b = rng.getrandbits(bits) % m
+            rows.append((b, e, rsa._mont_params(m)))
+            want.append(pow(b, e, m))
+    order = list(range(len(rows)))
+    rng.shuffle(order)
+    chunks = []
+    orig = rsa._powmod_chunk
+
+    def spy(width, chunk):
+        chunks.append(width)
+        return orig(width, chunk)
+
+    rsa._powmod_chunk = spy
+    try:
+        got = rsa._powmod_rows([rows[i] for i in order])
+    finally:
+        rsa._powmod_chunk = orig
+    assert got == [want[i] for i in order]
+    assert set(chunks) == {128, 192, 256} and len(chunks) > 3
+
+
+@needs_native
+def test_sign_many_of_rsa3072_keys_is_the_pure_paths(keys, monkeypatch):
+    """The engine's CRT halves at 1,536 bits give the bytes the ``pow``
+    path gives, key by key in one batch."""
+    k3072 = keys["k3072"]
+    items = [(b"w3072-%d" % i, k3072) for i in range(12)]
+    items += [(b"w2048-%d" % i, keys["k2048"]) for i in range(4)]
+    native = rsa.sign_many(items)
+    monkeypatch.setattr(rsa, "_MM", None)
+    assert rsa.sign_many(items) == native
+
+
+@needs_native
+def test_the_engine_counters_count_rows_under_the_reported_engine(keys):
+    """``host.modexp.<engine>`` grows by rows — two a CRT sign, one a
+    verify — under the name the extension reports; the other engine's
+    counter does not move."""
+    engine = rsa._MM.engine
+    assert engine in rsa._ENGINES
+    name = "host.modexp." + engine
+    other = ["host.modexp." + e for e in rsa._ENGINES if e != engine][0]
+    key = keys["k2048"]
+    before = metrics.snapshot().get(name, 0)
+    sigs = rsa.sign_many([(b"n-%d" % i, key) for i in range(5)])
+    mid = metrics.snapshot().get(name, 0)
+    assert mid - before >= 10
+    assert rsa.verify_host_many(
+        [(b"n-%d" % i, s, key.public) for i, s in enumerate(sigs)]
+    ) == [True] * 5
+    assert metrics.snapshot().get(name, 0) - mid >= 5
+    assert metrics.snapshot().get(other, 0) == 0
+
+
+class WrongRow:
+    """The real extension, with one row of one route made wrong: the
+    first row of ``width`` bytes whose exponent is public (``route``
+    "public") or longer than 4 bytes ("secret")."""
+
+    def __init__(self, route: str, width: int):
+        self.engine = rsa._MM.engine
+        self.route, self.width = route, width
+
+    def powmod_many(self, width, ewidth, bases, exps, keys):
+        out = bytearray(rsa._MM.powmod_many(width, ewidth, bases, exps, keys))
+        if width != self.width:
+            return bytes(out)
+        for r in range(len(bases) // width):
+            e = exps[r * ewidth : (r + 1) * ewidth].lstrip(b"\0")
+            if (len(e) > 4) == (self.route == "secret"):
+                out[(r + 1) * width - 1] ^= 1
+                break
+        return bytes(out)
+
+
+@needs_native
+@pytest.mark.parametrize("width", [16, 128])
+@pytest.mark.parametrize("route", ["public", "secret"])
+def test_the_self_check_refuses_an_engine_with_one_wrong_row(route, width):
+    assert rsa._self_check(rsa._MM)
+    assert not rsa._self_check(WrongRow(route, width))
+
+
+@needs_native
+def test_the_self_check_refuses_an_engine_it_does_not_know():
+    fake = WrongRow("public", 0)  # every row right
+    assert rsa._self_check(fake)
+    fake.engine = "gmp"
+    assert not rsa._self_check(fake)
+
+
 # -- the call sites ---------------------------------------------------------
 
 

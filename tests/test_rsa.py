@@ -248,6 +248,46 @@ def test_native_modexp_matches_pow_oracle():
                     ), (bits, base, exp)
 
 
+def _rows(width, rows):
+    """``powmod_many``'s operands for ``[(base, exp, mod)]`` at one width."""
+    ewidth = max(1, max((e.bit_length() + 7) // 8 for _b, e, _m in rows))
+    return (
+        width,
+        ewidth,
+        b"".join(b.to_bytes(width, "big") for b, _e, _m in rows),
+        b"".join(e.to_bytes(ewidth, "big") for _b, e, _m in rows),
+        b"".join(rsa._mont_params(m)[0] for _b, _e, m in rows),
+    )
+
+
+@pytest.mark.parametrize("entry", ["powmod_many", "powmod_many_cios"])
+@pytest.mark.parametrize("bits", [1024, 1536, 2048, 3072, 4096])
+def test_both_engines_match_pow_at_every_identity_width(bits, entry):
+    """Every width an identity, a CRT half or a CA fragment has, through
+    the batch entry the loader trusts (libcrypto where the process has
+    it) and through the CIOS loop it falls back to: the exponents a
+    row can carry (0, 1, a public one, full length, and a CA fragment's,
+    twice the modulus and more) on the edge bases, in one call."""
+    import random
+
+    if rsa._MM is None:
+        pytest.skip("native modexp not built")
+    rng = random.Random(bits)
+    mod = rng.getrandbits(bits) | (1 << (bits - 1)) | 1
+    full = rng.getrandbits(bits) | (1 << (bits - 1))
+    fragment = rng.getrandbits(2 * bits + 70) | (1 << (2 * bits + 69))
+    rows = [
+        (base, exp, mod)
+        for base in (0, 1, mod - 1, rng.getrandbits(bits) % mod)
+        for exp in (0, 1, rsa.F4, full, fragment)
+    ]
+    width = rsa._mont_params(mod)[1]
+    got = getattr(rsa._MM, entry)(*_rows(width, rows))
+    assert got == b"".join(
+        pow(b, e, m).to_bytes(width, "big") for b, e, m in rows
+    )
+
+
 def test_native_sign_matches_pure_python(keys, monkeypatch):
     """One signature, both engines, byte-identical — so an engine flip
     (or BFTKV_NATIVE_MODEXP=off) can never change the wire."""
