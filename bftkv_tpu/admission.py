@@ -16,6 +16,8 @@ The sidecar's instance also keeps the one process-wide waiting
 interval the device trace may show: ``sidecar.empty``, the time during
 which no request is admitted or waiting — the chip then waits for work
 that has not arrived (counter ``sidecar.empty.seconds``; DESIGN.md §7).
+Beside it, ``sidecar.backlog.seconds`` counts the time during which at
+least one request waits for a slot: admission then holds work out.
 """
 
 from __future__ import annotations
@@ -68,6 +70,9 @@ class AdmissionQueue:
         #: says nothing about a device.
         self._empty_since: float | None = time.monotonic()
         self._empty_ann = None
+        #: Since when some request waits for a slot (None while none
+        #: does); tier ``sidecar`` only, as above.
+        self._backlog_since: float | None = None
 
     def _publish(self) -> None:
         """Capacity-plane gauges (caller holds ``_cv``; the metrics
@@ -79,12 +84,16 @@ class AdmissionQueue:
         metrics.gauge("admission.queue_limit", float(self.max_queue), labels=lab)
         if self.tier == "sidecar":
             self._note_empty()
+            self._note_backlog()
 
     def _note_empty(self) -> None:
         """The 0 → 1 and 1 → 0 transitions of in-flight + waiting
         (caller holds ``_cv``).  The interval may begin on one handler
         thread and end on another, so it is a profiler annotation and a
-        counter, not a span."""
+        counter, not a span.  Its sibling :meth:`_note_backlog` has no
+        annotation: while a request waits, the admitted ones are
+        working, and a gap between kernels then belongs to their
+        phases, not to the queue."""
         empty = self._inflight + self._waiting == 0
         if empty == (self._empty_since is not None):
             return
@@ -98,6 +107,20 @@ class AdmissionQueue:
         ann, self._empty_ann = self._empty_ann, None
         if ann is not None:
             ann.__exit__(None, None, None)
+
+    def _note_backlog(self) -> None:
+        """The 0 → 1 and 1 → 0 transitions of waiting (caller holds
+        ``_cv``): ``sidecar.backlog.seconds`` grows by each interval in
+        which admission holds some request out."""
+        waiting = self._waiting > 0
+        if waiting == (self._backlog_since is not None):
+            return
+        now = time.monotonic()
+        if waiting:
+            self._backlog_since = now
+            return
+        metrics.incr("sidecar.backlog.seconds", now - self._backlog_since)
+        self._backlog_since = None
 
     def acquire(self, op: str) -> bool:
         """True = admitted (caller MUST release); False = shed."""

@@ -82,6 +82,7 @@ boots one beside the whole fleet and the FleetCollector scrapes the
 from __future__ import annotations
 
 import argparse
+import contextlib
 import hashlib
 import hmac
 import io
@@ -463,7 +464,7 @@ class SidecarService:
         # Exist from the start, so that 0 reads as 0 and not as a
         # program without the counter (the warm-up ended on a reset).
         for name in ("sidecar.unwarmed_width", "modexp.device",
-                     "modexp.host"):
+                     "modexp.host", "sidecar.backlog.seconds"):
             metrics.incr(name, 0)
         # After the warm-up, which passes no admission: the queue's
         # "empty since" is then the moment the service can first serve.
@@ -938,20 +939,28 @@ class _Handler(socketserver.BaseRequestHandler):
                     body = body[:-TAG_LEN]
                 opname = None
                 if body[:4] == MAGIC and len(body) >= 5:
-                    status, payload = self._handle_v2(
-                        body[4], body[5:], conn_keys, next_handle
-                    )
-                    out = bytes([status]) + payload
                     opname = _OP_NAMES.get(body[4])
-                else:
-                    out = self._handle_v1(body)
-                if opname is None:  # control frames, v1: no phase
-                    self._send(sock, secret, body, out)
-                else:
-                    # second interval of sidecar.reply: authenticate
-                    # and send (the first, encode, is in _handle_v2)
-                    with trace.leaf("sidecar.reply", opname, bytes=len(out)):
+                # A VERIFY / SIGN / MODEXP frame's whole service time,
+                # sheds included: admission, decode, dispatch and both
+                # intervals of the reply.  Control frames, v1: no phase.
+                with (trace.leaf("sidecar.serve", opname) if opname
+                      else contextlib.nullcontext()):
+                    if body[:4] == MAGIC and len(body) >= 5:
+                        status, payload = self._handle_v2(
+                            body[4], body[5:], conn_keys, next_handle
+                        )
+                        out = bytes([status]) + payload
+                    else:
+                        out = self._handle_v1(body)
+                    if opname is None:
                         self._send(sock, secret, body, out)
+                    else:
+                        # second interval of sidecar.reply: authenticate
+                        # and send (the first, encode, is in _handle_v2)
+                        with trace.leaf(
+                            "sidecar.reply", opname, bytes=len(out)
+                        ):
+                            self._send(sock, secret, body, out)
         except (ConnectionError, OSError):
             return
 
@@ -1030,7 +1039,6 @@ class _Handler(socketserver.BaseRequestHandler):
         if not svc.admission.acquire(opname):
             return ST_SHED, b""
         try:
-            metrics.incr("sidecar.ops", labels={"op": opname})
             # sidecar.decode runs inside the admission slot: where it
             # sits in a capture shows what that costs the queue.
             decode = trace.leaf("sidecar.decode", opname, bytes=len(payload))

@@ -152,6 +152,9 @@ class SidecarChannel:
         if self.tripped():
             return None
         t0 = time.perf_counter()
+        # the sidecar's own names; REGISTER and STATS frames pass no
+        # admission there and are "control" here
+        opname = _OP_NAMES.get(op, "control")
         try:
             if trace.capture() is not None:
                 # Inside a request trace, the shared-service round trip
@@ -162,8 +165,8 @@ class SidecarChannel:
                     "sidecar.call",
                     attrs={"op": op, "bytes": len(payload)},
                 ):
-                    return self._request(op, payload)
-            return self._request(op, payload)
+                    return self._request(op, payload, opname)
+            return self._request(op, payload, opname)
         finally:
             # The tenant's side of the round trip, wait for the channel
             # lock included, trace or no trace: the same interval the
@@ -171,17 +174,22 @@ class SidecarChannel:
             metrics.observe(
                 "sidecar.call",
                 time.perf_counter() - t0,
-                # the sidecar's own names; REGISTER and STATS frames
-                # pass no admission there and are "control" here
-                labels={"op": _OP_NAMES.get(op, "control")},
+                labels={"op": opname},
             )
 
-    def _request(self, op: int, payload: bytes) -> tuple[int, bytes] | None:
+    def _request(
+        self, op: int, payload: bytes, opname: str
+    ) -> tuple[int, bytes] | None:
         body = encode_op(op, payload)
         if self._secret is not None:
             body += request_tag(self._secret, body)
         frame = struct.pack(">I", len(body)) + body
-        with self._lock:
+        # The wait for another of this process's pools to finish its
+        # round trip on the one connection (the framing above is not in
+        # it: that is the wire's share of ``sidecar.call``).
+        with trace.leaf("sidecar.channel_wait", opname):
+            self._lock.acquire()
+        try:
             for _attempt in range(2):
                 try:
                     if self._sock is None:
@@ -197,6 +205,8 @@ class SidecarChannel:
                 # and retry once on a fresh one before giving up.
                 self._close_locked()
             self.trip()
+        finally:
+            self._lock.release()
         return None
 
     def _read_response(self, req_body: bytes) -> tuple[int, bytes] | None:

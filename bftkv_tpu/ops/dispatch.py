@@ -224,13 +224,17 @@ def calibration(force: bool = False) -> dict:
 
 
 class _Pending:
-    __slots__ = ("items", "event", "result", "error")
+    __slots__ = ("items", "event", "result", "error", "flushed")
 
     def __init__(self, items):
         self.items = items
         self.event = threading.Event()
         self.result = None
         self.error: Exception | None = None
+        #: ``perf_counter`` when the flush that carries this entry began
+        #: (stamped by :meth:`_BatchDispatcher._flush`, on the flushing
+        #: thread): the end of its ``dispatch.queue`` interval.
+        self.flushed: float | None = None
 
 
 class _BatchDispatcher:
@@ -461,6 +465,13 @@ class _BatchDispatcher:
         else:
             p.event.wait()
         metrics.observe(f"{self.name}.wait", time.perf_counter() - t0)
+        if p.flushed is not None:
+            # The queued part of ``.wait`` (linger, a pipeline permit,
+            # batches ahead): one observation a submit, on this thread.
+            # The interval ends on the flushing thread, so no ring span.
+            metrics.observe(
+                "dispatch.queue", p.flushed - t0, labels={"op": self.op}
+            )
         if p.error is not None:
             raise p.error
         return p.result
@@ -536,6 +547,9 @@ class _BatchDispatcher:
                     work.put(batch)
 
     def _flush(self, batch: list[_Pending]) -> None:
+        began = time.perf_counter()
+        for p in batch:
+            p.flushed = began
         if fp.ARMED:
             # ``dispatch.flush`` failpoint: a stalled device launch —
             # every caller blocked on this flush waits it out, which is
@@ -546,13 +560,13 @@ class _BatchDispatcher:
         flat = [it for p in batch for it in p.items]
         occupancy = len(flat) / self.max_batch
         metrics.observe(f"{self.name}.batch", len(flat))
-        metrics.gauge(f"{self.name}.occupancy", occupancy)
         metrics.incr(f"{self.name}.flushes")
         metrics.incr(f"{self.name}.items", len(flat))
         # Device-occupancy: items-per-LAUNCH vs the calibrated max batch.
-        # Distinct from ``.occupancy`` when an oversized flush chunks
-        # into several launches — each launch is then near-full even
-        # though flat/max_batch > 1 (capacity plane reads this gauge).
+        # Distinct from the flush span's ``occupancy`` when an oversized
+        # flush chunks into several launches — each launch is then
+        # near-full even though flat/max_batch > 1 (capacity plane reads
+        # this gauge).
         launches = max(1, -(-len(flat) // self.max_batch))
         metrics.incr(f"{self.name}.launches", launches)
         metrics.gauge(

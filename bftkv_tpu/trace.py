@@ -153,6 +153,13 @@ SPAN_PHASES: dict[str, str] = {
     "sign.flush": "dispatch",
     "modexp.flush": "dispatch",
     "sidecar.call": "sidecar",
+    # the waits inside a round trip: a daemon's pool queue (histogram
+    # only: the interval ends on the flushing thread), the channel
+    # lock, the sidecar's service time.  Waits and wrappers, so none is
+    # bridged
+    "dispatch.queue": "dispatch",
+    "sidecar.channel_wait": "sidecar",
+    "sidecar.serve": "sidecar",
     # the phases of one device launch and the two ends of a sidecar
     # request: leaves, bridged to the profiler (BRIDGED below)
     "dispatch.linger": "dispatch",

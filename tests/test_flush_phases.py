@@ -158,7 +158,7 @@ def test_histogram_counts_follow_launches_and_requests(capture):
     assert counts["flush.unpack", "sign"] == 2 * s
     # the sidecar's two ends, per request; the reply is two intervals
     # (encode inside the admission slot, authenticate + send after it)
-    requests = snap["sidecar.ops{op=verify}"]
+    requests = snap["sidecar.serve.count{op=verify}"]
     assert requests == 2
     assert counts["sidecar.decode", "verify"] == requests
     assert counts["sidecar.reply", "verify"] == 2 * requests

@@ -56,7 +56,7 @@ def test_prometheus_exposition_is_scrapable():
     m = Metrics()
     m.incr("server.write.ok", 3)
     m.incr("transport.rpcs", 5, labels={"cmd": "sign", "transport": "loop"})
-    m.gauge("dispatch.occupancy", 0.5)
+    m.gauge("dispatch.throughput", 0.5)
     m.observe("client.write.latency", 0.01)
     m.observe("client.write.latency", 0.02)
     text = m.prometheus()
@@ -92,7 +92,7 @@ def test_prometheus_exposition_is_scrapable():
     assert "bftkv_client_write_latency_sum" in text
     assert "bftkv_client_write_latency_count 2" in text
     # gauges typed as gauge
-    assert "# TYPE bftkv_dispatch_occupancy gauge" in text
+    assert "# TYPE bftkv_dispatch_throughput gauge" in text
 
 
 def test_prometheus_label_escaping():
